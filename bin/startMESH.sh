@@ -2,6 +2,9 @@
 # One-command multi-process SPMD mesh bring-up (ISSUE 12).
 # Spawns N OS processes as ONE logical jax.distributed mesh, serves a
 # smoke query over the HTTP wire, and keeps serving until Ctrl-C.
+# CPU harness for the multi-process protocol: every child runs on
+# virtual CPU devices and holds no chip. On a TPU host, bin/startYACY.sh
+# serves from all chips in ONE process (index.device.mesh=auto).
 #
 #   bin/startMESH.sh [procs] [local_devices] [extra launcher args...]
 #

@@ -163,38 +163,6 @@ def test_ann_metric_series_resolve(tmp_path):
             assert name in text, f"fleet digest series {v} unresolved"
 
 
-# a --capacity artifact that omits these is not reviewable: the
-# compression claim and the paging behavior must be in the record
-CAPACITY_ROW_KEYS = (
-    "postings", "p50_ms", "p95_ms", "qps", "compression_ratio",
-    "bytes_per_posting_packed", "bytes_per_posting_int16",
-    "achieved_gbps", "util_pct", "tier_counters",
-)
-
-
-def test_committed_capacity_artifact_carries_required_fields():
-    """The committed BENCH_r07.json capacity block must carry the
-    compression ratio and per-tier counters on every row (ISSUE 8
-    hygiene satellite: --capacity artifacts are gated on completeness)."""
-    import json
-    art = PKG.parent / "BENCH_r07.json"
-    assert art.exists(), "BENCH_r07.json missing (run bench.py --capacity)"
-    obj = json.loads(art.read_text())
-    cap = obj.get("capacity")
-    assert cap, "BENCH_r07.json has no capacity block"
-    rows = cap.get("rows")
-    assert rows and len(rows) >= 2, "capacity needs a 10M and a >=50M row"
-    for row in rows:
-        missing = [k for k in CAPACITY_ROW_KEYS if k not in row]
-        assert not missing, f"capacity row missing {missing}"
-        tc = row["tier_counters"]
-        for k in ("tier_hot_hits", "tier_warm_hits", "tier_cold_hits",
-                  "tier_promotions_warm_hot", "tier_promotions_cold_hot"):
-            assert k in tc, k
-    assert max(r["postings"] for r in rows) >= 50_000_000
-    assert "p95_ratio_vs_10m" in cap and "gate_p95_2x" in cap
-
-
 # -- streaming-ingest hygiene (ISSUE 13) -------------------------------------
 
 INGEST_KERNELS = ("_pack_block_batch_kernel",)

@@ -111,7 +111,7 @@ def _gather_fns(mesh, k):
     from jax.sharding import PartitionSpec as PS
 
     from yacy_search_server_tpu.parallel.mesh import (all_gather_topk,
-                                                      shard_map, tie_topk)
+                                                      tie_topk)
 
     def legacy(s, d):
         ls, li = lax.top_k(s, min(k, s.shape[0]))
@@ -124,7 +124,7 @@ def _gather_fns(mesh, k):
         ls, ld = tie_topk(s, d, min(k, s.shape[0]))
         return all_gather_topk(ls, ld, "doc", k)
 
-    mk = lambda body: jax.jit(shard_map(     # noqa: E731
+    mk = lambda body: jax.jit(jax.shard_map(     # noqa: E731
         body, mesh=mesh, in_specs=(PS("doc"), PS("doc")),
         out_specs=(PS(), PS()), check_vma=False))
     return mk(legacy), mk(fused)

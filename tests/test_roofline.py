@@ -91,7 +91,6 @@ def test_xla_all_gather_topk(ndev, k, rows):
     from jax.sharding import PartitionSpec as PS
 
     from yacy_search_server_tpu.parallel.mesh import (all_gather_topk,
-                                                      shard_map,
                                                       tie_topk)
     devs = jax.devices("cpu")
     if len(devs) < ndev:
@@ -101,9 +100,9 @@ def test_xla_all_gather_topk(ndev, k, rows):
     def body(s, d):
         ls, ld = tie_topk(s, d, k)
         return all_gather_topk(ls, ld, "doc", k)
-    fn = jax.jit(shard_map(body, mesh=mesh,
-                           in_specs=(PS("doc"), PS("doc")),
-                           out_specs=(PS(), PS()), check_vma=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                               in_specs=(PS("doc"), PS("doc")),
+                               out_specs=(PS(), PS()), check_vma=False))
     n = ndev * rows
     sa = jax.device_put(jnp.arange(n, dtype=jnp.int32),
                         NamedSharding(mesh, PS("doc")))

@@ -1,7 +1,6 @@
 """Streaming ingest — the write path as a first-class subsystem (ISSUE 13).
 
-Every headline so far (BENCH_r06/r07, MULTICHIP_r06, CHAOS_r01) measured
-a FROZEN index; the paper's system is a crawler-indexer first: every
+Every headline before this package measured a FROZEN index; the paper's system is a crawler-indexer first: every
 node crawls, parses, flushes, merges and tier-promotes *while* serving.
 This package gives that write path the same production discipline the
 read path earned over rounds 6–16:

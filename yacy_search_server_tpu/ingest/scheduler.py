@@ -2,8 +2,8 @@
 
 Compactions (RWI run merges) and tier promotions are the write path's
 two heavy background moves: a full merge rewrites the run set, and a
-promotion ships packed blocks through the same tunnel the query waves
-ride.  Until now their timing was ad hoc (the cleanup busy thread
+promotion ships packed blocks over the same host-to-device link the
+query waves ride.  Until now their timing was ad hoc (the cleanup busy thread
 merged whenever a device join flagged a hot term; promotions fired on
 every tier miss) — under a serving burn they pile exactly the work the
 node can least afford.

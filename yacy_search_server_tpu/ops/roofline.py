@@ -102,25 +102,26 @@ PEAKS: dict[str, DevicePeak] = {
 
 
 def device_peak(device=None) -> DevicePeak:
-    """The peak table entry for a jax device (env/config overridable:
-    YACY_ROOFLINE_PEAK_FLOPS / YACY_ROOFLINE_PEAK_GBPS take precedence —
-    deployments on unlisted silicon declare their own ceiling)."""
-    kind = "cpu"
-    if device is not None:
-        kind = getattr(device, "device_kind", "cpu").lower()
-    else:
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind.lower()
-        except Exception:   # no backend at all: the CPU envelope stands
-            kind = "cpu"
+    """The peak table entry for a jax device (default: the first device
+    of the default backend). A `device_kind` that is not in PEAKS is an
+    error, not a default: a deployment on unlisted silicon DECLARES its
+    ceilings with YACY_ROOFLINE_PEAK_FLOPS / YACY_ROOFLINE_PEAK_GBPS
+    (both, for an unknown kind; either overrides a listed one)."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    kind = device.device_kind.lower()
     peak = PEAKS.get(kind)
-    if peak is None:
-        # unknown accelerator: fall back by family, never crash serving
-        peak = next((p for k, p in PEAKS.items()
-                     if k != "cpu" and k in kind), PEAKS["cpu"])
     env_f = os.environ.get("YACY_ROOFLINE_PEAK_FLOPS")
     env_b = os.environ.get("YACY_ROOFLINE_PEAK_GBPS")
+    if peak is None:
+        if not (env_f and env_b):
+            raise KeyError(
+                f"no roofline peak for device_kind {kind!r}: add it to "
+                f"ops/roofline.PEAKS (have: {sorted(PEAKS)}) or declare "
+                f"YACY_ROOFLINE_PEAK_FLOPS and YACY_ROOFLINE_PEAK_GBPS")
+        return DevicePeak(f"{device.device_kind} (declared)",
+                          float(env_f), float(env_b) * 1e9)
     if env_f or env_b:
         peak = DevicePeak(
             peak.name + " (overridden)",
@@ -526,18 +527,6 @@ def _c_all_gather_topk(k: int, ndev: int, rows: int = 256) -> Cost:
                 xla_bytes=24.0 * rows + 32.0 * g + 40.0 * k + 80.0)
 
 
-def _c_all_gather_topk_pallas(k: int, ndev: int, rows: int = 256) -> Cost:
-    """Ring remote-DMA variant: per device the ring moves (ndev-1)
-    hops x 8 B x k — same k-scaling payload, expressed as ICI traffic
-    instead of a gather buffer; the merge epilogue is shared with the
-    lax variant so its sort terms are identical."""
-    g = ndev * k
-    return Cost(flops=1.08 * rows * _log2(rows) + 1.1 * g * _log2(g)
-                + 120.0,
-                bytes=8.0 * rows + 8.0 * k * (ndev - 1) + 8.0 * k,
-                xla_bytes=24.0 * rows + 32.0 * g + 40.0 * k + 80.0)
-
-
 def _c_power_iterate(n: int, edges: int, iters: int = 1) -> Cost:
     """BlockRank power iteration (ops/blockrank._power_iterate_sparse):
     per-iteration segment-sum over the edge list, × the trip count (the
@@ -594,12 +583,11 @@ KERNELS: dict[str, object] = {
     # bit-pack — fresh runs land pre-packed, parity-pinned bit-identical
     # to ops/packed.pack_block (tests/test_ingest.py)
     "_pack_block_batch_kernel": _c_pack_block_batch,
-    # fused all-gather+top-k fusion collective (ISSUE 12b): the lax
-    # implementation every mesh fusion site shares, and the Pallas
-    # remote-DMA ring variant for TPU ICI — gathered bytes scale with
-    # k, not corpus rows (the r5 motivation: full score rows shipped)
+    # fused all-gather+top-k fusion collective (ISSUE 12b), the one
+    # implementation every mesh fusion site shares — gathered bytes
+    # scale with k, not corpus rows (the r5 motivation: full score rows
+    # shipped)
     "all_gather_topk": _c_all_gather_topk,
-    "_all_gather_topk_pallas": _c_all_gather_topk_pallas,
 }
 
 # jit-compiled functions that are NOT serving kernels used to be

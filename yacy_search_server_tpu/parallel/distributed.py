@@ -88,15 +88,14 @@ def bootstrap_from_env():
     Must run before any other jax API touches the backend.  Returns
     (process_id, num_processes)."""
     import jax
+
+    from ..utils import compilecache
+    compilecache.ensure()
     coord = os.environ[ENV_COORDINATOR]
     nprocs = int(os.environ[ENV_NPROCS])
     pid = int(os.environ[ENV_PROC_ID])
-    try:
-        # gloo is the CPU cross-process collective fabric; newer jax
-        # defaults to it once distributed-initialized, older spells it
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as e:
-        log.debug("gloo collectives config not available: %r", e)
+    # gloo is the CPU cross-process collective fabric
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coord,
                                num_processes=nprocs, process_id=pid)
     want = int(os.environ.get(ENV_LOCAL_DEVICES, "0"))

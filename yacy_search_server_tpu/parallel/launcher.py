@@ -23,6 +23,13 @@ face to answer, and supervises:
 The fleet object is also the client: `search()` POSTs to the
 coordinator's ``/yacy/meshsearch.html`` wire servlet (the same JSON
 wire every peer RPC uses), `info()`/`fault()` hit the members directly.
+
+This is a CPU harness for the multi-process PROTOCOL: every child is
+pinned to ``JAX_PLATFORMS=cpu`` with virtual devices and cannot hold a
+chip (a chip belongs to one process). On a TPU host the several-chip
+serving path is the single-process ``MeshSegmentStore`` that
+``python -m yacy_search_server_tpu.yacy -start`` builds by itself when
+it finds more than one device (``index.device.mesh=auto``).
 """
 
 from __future__ import annotations

@@ -43,25 +43,16 @@ from ..ops import ranking as R
 NEG_INF_I32 = -(2**31 - 1)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable shard_map: `jax.shard_map` (jax >= 0.5, `check_vma`
-    kwarg) with a fallback to `jax.experimental.shard_map` (0.4.x, where
-    the same knob is spelled `check_rep`)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def best_devices(need: int | None = None, prefer_cpu: bool = False):
-    """Device pool for an n-way mesh.
+    """Device pool for an n-way mesh — for TESTS and
+    ``__graft_entry__.dryrun_multichip`` only. No serving path calls
+    this: ``MeshSegmentStore`` takes ``jax.devices()``, so a node on a
+    chip never lands on the virtual CPU pool.
 
     Default policy: the default backend, falling back to the virtual CPU
     pool when the default backend has fewer devices than requested
-    (single-chip dev box with xla_force_host_platform_device_count set —
-    the documented test pattern for multi-chip shardings).
+    (xla_force_host_platform_device_count — the documented test pattern
+    for multi-chip shardings).
 
     prefer_cpu=True inverts the preference: take the CPU pool whenever it
     satisfies `need` (the driver's multichip dryrun contract — CPU
@@ -125,9 +116,12 @@ def tie_topk(scores, docids, k: int):
 
 
 def all_gather_topk(local_s, local_d, axes, k: int):
-    """Fused candidate-fusion collective, `lax` implementation: gather
-    each shard's (already exact, already tie-ordered) local top-k along
-    `axes` and merge under the pinned tie discipline.  Gathered bytes
+    """THE candidate-fusion collective (every fusion site calls it;
+    there is no second implementation — the Pallas remote-DMA ring that
+    used to sit beside it was refused by jax 0.9.0 at trace time on four
+    v5e chips and was deleted in PR 21): gather each shard's (already
+    exact, already tie-ordered) local top-k along `axes` and merge
+    under the pinned tie discipline.  Gathered bytes
     scale with k·n_shards (8 B per candidate), not with corpus rows —
     the cost model in ops/roofline.KERNELS counts exactly that."""
     gs = lax.all_gather(local_s, axes, tiled=True)
@@ -144,98 +138,6 @@ def all_gather_topk_full(local_s, local_d, axes):
     return tie_topk(gs, gd, gs.shape[0])
 
 
-def _all_gather_topk_pallas(local_s, local_d, axis, k: int, ndev: int,
-                            axis_names: tuple = ()):
-    """Pallas remote-DMA variant of the fusion collective for TPU ICI
-    (SNIPPETS [1] / pallas guide "Ring All-Gather"): each device's
-    (k, 2) candidate block rides `make_async_remote_copy` around the
-    ring — double-buffered send/recv slots, DMA semaphores in scratch —
-    and the merge reuses the SAME tie_topk epilogue, so the two
-    implementations cannot diverge on discipline.  Only reachable when
-    the mesh devices are TPU (gate in fused_gather_topk); elsewhere the
-    lax path above is the product path."""
-    import functools
-
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def ring_kernel(block_ref, out_ref, comm_ref, send_sem, recv_sem,
-                    *, ndev: int):
-        my_id = lax.axis_index(axis)
-        out_ref[pl.ds(my_id * block_ref.shape[0], block_ref.shape[0])] \
-            = block_ref[:]
-        comm_ref[0] = block_ref[:]
-        for step in range(ndev - 1):
-            src_device = (my_id - step - 1) % ndev
-            dst_device = (my_id + 1) % ndev
-            send_slot = step % 2
-            recv_slot = (step + 1) % 2
-            # full logical mesh coordinates: the fusion axis carries the
-            # ring neighbor, every other axis is size 1 (the dispatch
-            # gate guarantees it), so its coordinate is 0
-            coords = tuple(dst_device if n == axis else 0
-                           for n in (axis_names or (axis,)))
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_ref.at[send_slot],
-                dst_ref=comm_ref.at[recv_slot],
-                send_sem=send_sem.at[send_slot],
-                recv_sem=recv_sem.at[recv_slot],
-                device_id=coords,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            )
-            rdma.start()
-            rdma.wait()
-            out_ref[pl.ds(src_device * block_ref.shape[0],
-                          block_ref.shape[0])] = comm_ref[recv_slot]
-
-    kk = local_s.shape[0]
-    # scores bit-cast next to docids: ONE (k, 2) int32 block per hop
-    block = jnp.stack(
-        [lax.bitcast_convert_type(local_s.astype(jnp.float32), jnp.int32)
-         if local_s.dtype != jnp.int32 else local_s,
-         local_d], axis=1)
-    gathered = pl.pallas_call(
-        functools.partial(ring_kernel, ndev=ndev),
-        out_shape=jax.ShapeDtypeStruct((ndev * kk, 2), jnp.int32),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2, kk, 2), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )(block)
-    gs = gathered[:, 0] if local_s.dtype == jnp.int32 else \
-        lax.bitcast_convert_type(gathered[:, 0], jnp.float32)
-    return tie_topk(gs, gathered[:, 1], k)
-
-
-def fused_gather_topk(local_s, local_d, axes, k: int,
-                      mesh: Mesh | None = None):
-    """Dispatch the fusion collective — the PRODUCT entry point of the
-    single-axis shard bodies (`_cardinal_shard`, `_bm25_shard`): the
-    Pallas remote-DMA ring when the fusion axis spans a TPU ICI mesh
-    (every other axis size 1, so the ring IS the device ring), the
-    `lax` all-gather everywhere else — CPU meshes, multi-process
-    DCN-backed meshes, and the meshstore's two-axis ('term','doc')
-    fusions, which are lax-by-design (a ring is a one-axis
-    collective)."""
-    use_pallas = (mesh is not None and isinstance(axes, str)
-                  and all(d.platform == "tpu"
-                          for d in mesh.devices.flat)
-                  and mesh.shape[axes] == mesh.devices.size)
-    if use_pallas:
-        try:
-            return _all_gather_topk_pallas(local_s, local_d, axes, k,
-                                           mesh.shape[axes],
-                                           tuple(mesh.axis_names))
-        except Exception:   # pragma: no cover - TPU-only path
-            import logging
-            logging.getLogger("parallel.mesh").exception(
-                "pallas fusion collective failed; lax fallback")
-    return all_gather_topk(local_s, local_d, axes, k)
-
-
 # ---------------------------------------------------------------------------
 # Sharded cardinal ranking (ReferenceOrder.cardinal over the doc axis)
 # ---------------------------------------------------------------------------
@@ -243,7 +145,7 @@ def fused_gather_topk(local_s, local_d, axes, k: int,
 def _cardinal_shard(feats, docids, valid, hostids, norm_coeffs, flag_bits,
                     flag_shifts, domlength_coeff, tf_coeff, language_coeff,
                     authority_coeff, language_pref, *, k: int,
-                    num_hosts: int, mesh: Mesh | None = None):
+                    num_hosts: int):
     st = R.local_stats(feats, valid, hostids, num_hosts=num_hosts)
     st = {
         "col_min": lax.pmin(st["col_min"], "doc"),
@@ -261,13 +163,13 @@ def _cardinal_shard(feats, docids, valid, hostids, norm_coeffs, flag_bits,
     # interconnect, the TPU replacement of the reference's per-peer
     # heap-insert merge (heap semantics: only each peer's best k travel)
     local_s, local_d = tie_topk(scores, docids, min(k, scores.shape[0]))
-    return fused_gather_topk(local_s, local_d, "doc", k, mesh=mesh)
+    return all_gather_topk(local_s, local_d, "doc", k)
 
 
 def build_sharded_cardinal(mesh: Mesh, k: int, num_hosts: int):
     """jit-compiled sharded cardinal+top-k over `mesh` ('doc' axis)."""
-    fn = shard_map(
-        partial(_cardinal_shard, k=k, num_hosts=num_hosts, mesh=mesh),
+    fn = jax.shard_map(
+        partial(_cardinal_shard, k=k, num_hosts=num_hosts),
         mesh=mesh,
         in_specs=(PS("doc"), PS("doc"), PS("doc"), PS("doc"),
                   PS(), PS(), PS(), PS(), PS(), PS(), PS(), PS()),
@@ -282,7 +184,7 @@ def build_sharded_cardinal(mesh: Mesh, k: int, num_hosts: int):
 # ---------------------------------------------------------------------------
 
 def _bm25_shard(tf, doclen, df, ndocs, valid, docids, *, k: int,
-                k1: float, b: float, mesh: Mesh | None = None):
+                k1: float, b: float):
     tf = tf.astype(jnp.float32)
     dl = doclen.astype(jnp.float32)
     sum_dl = lax.psum(jnp.sum(jnp.where(valid, dl, 0.0)), "doc")
@@ -295,13 +197,13 @@ def _bm25_shard(tf, doclen, df, ndocs, valid, docids, *, k: int,
     score = lax.psum(partial_score, "term")
     score = jnp.where(valid, score, -jnp.inf)
     local_s, local_d = tie_topk(score, docids, min(k, score.shape[0]))
-    return fused_gather_topk(local_s, local_d, "doc", k, mesh=mesh)
+    return all_gather_topk(local_s, local_d, "doc", k)
 
 
 def build_sharded_bm25(mesh: Mesh, k: int, k1: float = 1.2, b: float = 0.75):
     """jit-compiled sharded BM25+top-k over the ('term','doc') mesh."""
-    fn = shard_map(
-        partial(_bm25_shard, k=k, k1=k1, b=b, mesh=mesh),
+    fn = jax.shard_map(
+        partial(_bm25_shard, k=k, k1=k1, b=b),
         mesh=mesh,
         in_specs=(PS("doc", "term"), PS("doc"), PS("term"), PS(),
                   PS("doc"), PS("doc")),

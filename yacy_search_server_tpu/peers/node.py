@@ -44,8 +44,12 @@ class P2PNode:
                  peer_type: str = PeerType.SENIOR,
                  accept_remote_index: bool = True,
                  accept_remote_crawl: bool = False,
-                 cluster_peers: list[str] | None = None):
-        self.sb = Switchboard(data_dir=data_dir, transport=crawl_transport)
+                 cluster_peers: list[str] | None = None,
+                 config=None):
+        # `config` (utils/config.Config) must reach the Switchboard's
+        # constructor: the index.device.* keys are read there, once
+        self.sb = Switchboard(data_dir=data_dir, config=config,
+                              transport=crawl_transport)
         self.seed = Seed(make_seed_hash(name, "127.0.0.1", port), name=name,
                          port=port, peer_type=peer_type)
         self.seed.flags_accept_remote_index = accept_remote_index

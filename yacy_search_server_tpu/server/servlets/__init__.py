@@ -10,6 +10,7 @@ fills the `<Name>.<ext>` template.
 
 from __future__ import annotations
 
+import threading
 from typing import Callable
 
 from ..objects import ServerObjects
@@ -37,13 +38,21 @@ def names() -> list[str]:
 
 
 _loaded = False
+_load_lock = threading.RLock()
 
 
 def _ensure_loaded() -> None:
+    """Import every servlet module once. `_loaded` flips only AFTER the
+    imports, under a lock: the first requests after a start arrive
+    concurrently, and a request that saw the flag before the registry
+    filled was answered with the raw template file (200, static)."""
     global _loaded
     if _loaded:
         return
-    _loaded = True
-    from . import (yacysearch, status, admin, api, boards,  # noqa: F401
-                   breadth, federate, gameday, graphics, health, ingest,
-                   operator, proxy, monitoring, tail)
+    with _load_lock:
+        if _loaded:
+            return
+        from . import (yacysearch, status, admin, api,  # noqa: F401
+                       boards, breadth, federate, gameday, graphics,
+                       health, ingest, operator, proxy, monitoring, tail)
+        _loaded = True

@@ -477,6 +477,9 @@ def prometheus_text(sb, include_buckets: bool = True,
                 "filtered_served", "join_served", "join_fallbacks",
                 "batch_dispatches", "batch_exceptions",
                 "batch_ineligible", "prune_rounds",
+                # kernel shapes the start-up prewarm could not compile
+                # (must stay 0: a refused shape fails at first live use)
+                "prewarm_failures",
                 # versioned top-k result cache (hits serve with zero
                 # device work; stale = correct epoch invalidations)
                 "rank_cache_hits", "rank_cache_stale",
@@ -632,7 +635,7 @@ def prometheus_text(sb, include_buckets: bool = True,
         p.family("yacy_device_latency_ms", "gauge",
                  "per-query dispatch/kernel wall percentiles")
         for key in ("dispatch_ms_p50", "dispatch_ms_p95",
-                    "kernel_ms_p50", "kernel_ms_p95", "tunnel_rt_ms"):
+                    "kernel_ms_p50", "kernel_ms_p95", "dispatch_rt_ms"):
             if key in c:
                 p.sample("yacy_device_latency_ms", c[key], {"stat": key})
 

@@ -40,7 +40,7 @@ fsync comments):
 - ``device.transfer_fail``: a COUNT of device transfers to fail.  Each
   guarded fetch/upload consumes one charge and raises; at zero the
   device "comes back" — which is how the device-loss tests hold the
-  tunnel down across the retry ladder and then let the background
+  device down across the retry ladder and then let the background
   rebuild succeed (index/devstore.py).  In a multi-process mesh
   (ISSUE 12) the same point armed INSIDE one member process — via the
   ``YACY_FAULTS`` env at spawn or the test-fleet-gated ``meshfault``

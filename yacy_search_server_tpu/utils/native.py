@@ -7,15 +7,18 @@ Word.java:113-130; posting-row sorts and hash-probe joins,
 ReferenceContainer.java:397-489). Loading is best-effort:
 
 - `YACYTPU_NATIVE=0` disables the native path entirely;
-- if `native/libyacytpu.so` is missing, it is compiled once with g++;
-- on any failure `LIB` stays None and callers fall back to numpy — the
-  native path and the fallback are interchangeable call-for-call (parity
-  is enforced by tests/test_native.py).
+- if `native/libyacytpu.so` is missing, it is compiled once with g++
+  (the `.so` is a build product: git-ignored, never shipped);
+- on any failure `LIB` stays None, a WARNING names the reason once, and
+  callers fall back to numpy — the native path and the fallback are
+  interchangeable call-for-call (parity is enforced by
+  tests/test_native.py).
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -32,6 +35,8 @@ _i64p = ctypes.POINTER(ctypes.c_int64)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _u8p = ctypes.POINTER(ctypes.c_uint8)
 
+log = logging.getLogger("yacy.native")
+
 _load_lock = threading.Lock()
 _loaded = False
 LIB: ctypes.CDLL | None = None
@@ -43,7 +48,8 @@ MIN_BATCH = 64
 MIN_HASH_BATCH = 16
 
 
-def _build() -> bool:
+def _build() -> None:
+    """Compile the library; raises OSError naming the reason."""
     # compile to a temp path + atomic rename: another process scanning the
     # directory must never dlopen a half-written ELF
     tmp = f"{_SO_PATH}.tmp.{os.getpid()}"
@@ -53,11 +59,12 @@ def _build() -> bool:
              "-o", tmp, _SRC_PATH],
             capture_output=True, timeout=120)
         if res.returncode != 0 or not os.path.exists(tmp):
-            return False
+            raise OSError(
+                f"g++ rc {res.returncode}: "
+                f"{res.stderr.decode('utf-8', 'replace')[-400:]}")
         os.replace(tmp, _SO_PATH)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+    except subprocess.SubprocessError as e:
+        raise OSError(f"g++: {e!r}") from e
     finally:
         if os.path.exists(tmp):
             try:
@@ -95,15 +102,17 @@ def load() -> ctypes.CDLL | None:
             if not os.path.exists(_SO_PATH) or (
                     os.path.exists(_SRC_PATH)
                     and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_SO_PATH)):
-                if not os.path.exists(_SRC_PATH) or not _build():
-                    _loaded = True
-                    return None
+                if not os.path.exists(_SRC_PATH):
+                    raise OSError(f"{_SRC_PATH} missing")
+                _build()
             lib = ctypes.CDLL(_SO_PATH)
             _bind(lib)
             if lib.ytn_abi_version() != 1:
                 raise OSError("abi mismatch")
             LIB = lib
-        except (OSError, AttributeError):  # AttributeError: missing symbol
+        except (OSError, AttributeError) as e:  # AttributeError: missing symbol
+            log.warning("native library unavailable (%s): the numpy "
+                        "fallbacks serve hashing/sort/join", e)
             LIB = None
         _loaded = True
         return LIB

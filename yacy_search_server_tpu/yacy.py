@@ -106,23 +106,28 @@ def startup(data_dir: str, port: int = DEFAULT_PORT, host: str = "127.0.0.1",
             logging.getLogger("yacy.upnp").debug(
                 "UPnP port mapping unavailable", exc_info=True)
 
-    if p2p:
-        from .peers.node import P2PNode
-        from .peers.transport import HttpTransport
-        node = P2PNode(peer_name, HttpTransport(), data_dir=data_dir,
-                       port=port)
-        node.sb.config = config
-        http = node.serve_http(host=host, port=port)
-        node.deploy_threads()
-        _upnp_map(node.sb)
-        return node, http, lock
-    from .server.httpd import YaCyHttpServer
-    from .switchboard import Switchboard
-    sb = Switchboard(data_dir=data_dir, config=config)
-    http = YaCyHttpServer(sb, port=port, host=host).start()
-    sb.deploy_threads()
-    _upnp_map(sb)
-    return sb, http, lock
+    try:
+        if p2p:
+            from .peers.node import P2PNode
+            from .peers.transport import HttpTransport
+            node = P2PNode(peer_name, HttpTransport(), data_dir=data_dir,
+                           port=port, config=config)
+            http = node.serve_http(host=host, port=port)
+            node.deploy_threads()
+            _upnp_map(node.sb)
+            return node, http, lock
+        from .server.httpd import YaCyHttpServer
+        from .switchboard import Switchboard
+        sb = Switchboard(data_dir=data_dir, config=config)
+        http = YaCyHttpServer(sb, port=port, host=host).start()
+        sb.deploy_threads()
+        _upnp_map(sb)
+        return sb, http, lock
+    except BaseException:
+        # a start that failed (device serving could not come up, port
+        # taken) holds no lock: the next attempt is not a "stale" one
+        release_lock(lock)
+        raise
 
 
 def wait_for_shutdown(sb) -> None:
