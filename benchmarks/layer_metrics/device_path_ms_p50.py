@@ -1,0 +1,8 @@
+"""Median latency, client clock, of the requests that passed the host
+gate (caches, batcher and kernels answered them)."""
+
+from ._shared import latency_ms
+
+
+def read(ctx):
+    return latency_ms(ctx, 0.50, on_device=True)

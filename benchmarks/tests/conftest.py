@@ -1,0 +1,12 @@
+"""Tests of the benchmark's own yardstick. Run by hand, on the CPU:
+
+    python -m pytest benchmarks/tests -q
+"""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
