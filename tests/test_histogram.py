@@ -198,3 +198,27 @@ def test_stage_table_excludes_wrappers_and_roots_from_dominance():
     assert "servlet.yacysearch" in t["stages"]   # listed, never dominant
     t_all = hg.stage_table(exclude_prefixes=())
     assert t_all["tail_dominant_stage"] == "index.parsedocument"
+
+
+def test_windowed_sum_and_covered_seconds_follow_the_ring():
+    """Beside the windowed counts: the sum of the retained values (a
+    mean over windowed_count(), a rate over windowed_span_s()) and the
+    seconds the retained windows cover."""
+    import time
+    h = hg.histogram("sum.test")
+    t0 = time.monotonic()
+    h.record(3.0)
+    h.record(5.0)
+    assert h.windowed_sum() == 8.0
+    assert 0.0 <= h.windowed_span_s() <= time.monotonic() - t0 + 0.5
+    h.rotate()
+    h.record(2.0)
+    assert h.windowed_sum() == 10.0 and h.windowed_count() == 3
+    for _ in range(hg.WINDOWS - 1):
+        h.rotate()                  # the first window falls off the ring
+    assert h.windowed_sum() == 2.0 and h.sum_ms == 10.0
+    time.sleep(0.05)
+    covered = h.windowed_span_s()
+    h.reset_window()                # the harness's boundary: from now
+    assert h.windowed_sum() == 0.0
+    assert h.windowed_span_s() < min(covered, 0.05)

@@ -456,3 +456,392 @@ def test_trace_header_propagates_over_http(tmp_path):
     finally:
         for n in nodes:
             n.close()
+
+
+# -- one clock: the profiler-annotation bridge (ISSUE 25) -------------------
+
+def test_annotation_bridge_without_a_profiler_session_is_free():
+    """No session records: a span opens no annotation (nothing is
+    allocated for it), `annotation()` is the shared no-op, nothing
+    raises; disabled, `span()` is the shared no-op too."""
+    import jax  # noqa: F401  (the bridge binds once JAX is loaded)
+    assert tracing._recording() is None and tracing._annotate("x") is None
+    assert tracing.annotation("batcher.form") is tracing.span("nothing")
+    with tracing.annotation("kernel.issue", kernel="k", batch_n=2):
+        pass
+    with tracing.trace("root") as r:
+        assert r._ann is None
+        with tracing.span("child") as c:
+            assert c._ann is None
+    tracing.set_enabled(False)
+    assert tracing.span("x") is tracing.trace("y") \
+        is tracing.span_in(("t", "s"), "z")
+
+
+def test_spans_land_on_the_profilers_timeline_from_their_own_thread(
+        tmp_path):
+    """With a session recording (the options benchmarks/run.py sets),
+    every live span, `timed()` wall and bare `annotation()` is an event
+    of the host plane — the worker thread's on a line of its own — and
+    an annotation's attributes are the event's stats."""
+    import jax
+    from jax.profiler import ProfileData
+    po = jax.profiler.ProfileOptions()
+    po.python_tracer_level = 0
+    po.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=po)
+    try:
+        def worker():
+            with tracing.annotation("kernel.issue", kernel="_k",
+                                    batch_n=3):
+                pass
+            with tracing.timed("batcher.untraced_wall"):
+                pass
+        with tracing.trace("servlet.fake"):
+            with tracing.span("search.fake_stage"):
+                th = threading.Thread(target=worker)
+                th.start()
+                th.join()
+    finally:
+        jax.profiler.stop_trace()
+    import glob
+    path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[-1]
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                for ev in line.events:
+                    if ev.name.split(".")[0] in ("servlet", "search",
+                                                 "kernel", "batcher"):
+                        lines.setdefault(i, {})[ev.name] = dict(ev.stats)
+    by_line = sorted(lines.values(), key=sorted)
+    assert [sorted(names) for names in by_line] == [
+        ["batcher.untraced_wall", "kernel.issue"],
+        ["search.fake_stage", "servlet.fake"]], lines
+    assert by_line[0]["kernel.issue"] == {"kernel": "_k", "batch_n": 3}
+
+
+def test_timed_and_record_always_reach_the_family():
+    from yacy_search_server_tpu.utils import histogram
+    histogram.reset()
+    # outside a trace: the family alone, no ring, no context
+    with tracing.timed("stage.always") as sp:
+        assert tracing.current() is None
+        sp.set(ignored=1)
+    tracing.record("stage.after", 2.0, why="x")
+    assert tracing.traces(10) == []
+    assert histogram.get("stage.always").count == 1
+    assert histogram.get("stage.after").sum_ms == 2.0
+    # under a trace: spans (which feed the family), parented on the
+    # active context; emit stays a no-op outside one
+    tracing.emit("stage.marker", 0.0)
+    assert histogram.get("stage.marker") is None
+    with tracing.trace("root") as r:
+        with tracing.timed("stage.always"):
+            tracing.record("stage.after", 3.0, ts=123.0, sid="fixed")
+    by = {s.name: s for s in tracing.get_trace(r.ctx[0]).spans}
+    assert by["stage.always"].parent == by["root"].sid
+    assert by["stage.after"].parent == by["stage.always"].sid
+    assert (by["stage.after"].sid, by["stage.after"].ts) == ("fixed", 123.0)
+    assert histogram.get("stage.always").count == 2
+    assert histogram.get("stage.after").sum_ms == 5.0
+    # disabled: still measured, nothing traced
+    tracing.set_enabled(False)
+    with tracing.timed("stage.always"):
+        pass
+    tracing.record("stage.after", 1.0, ctx=r.ctx)
+    assert histogram.get("stage.always").count == 3
+    assert histogram.get("stage.after").count == 3
+    assert len(tracing.get_trace(r.ctx[0]).spans) == 3
+
+
+def test_a_span_renamed_inside_closes_under_its_outcome():
+    from yacy_search_server_tpu.utils import histogram
+    histogram.reset()
+    with tracing.trace("root") as r:
+        with tracing.timed("search.route") as rt:
+            rt.rename("search.route.host_gate")
+    with tracing.timed("search.route") as rt:       # untraced twin
+        rt.rename("search.route.device")
+    assert "search.route.host_gate" in {
+        s.name for s in tracing.get_trace(r.ctx[0]).spans}
+    assert histogram.get("search.route") is None
+    assert histogram.get("search.route.host_gate").count == 1
+    assert histogram.get("search.route.device").count == 1
+
+
+def test_envelope_joins_the_trace_rooted_beneath_it():
+    """httpd's wall around any servlet: always the two families; the
+    callee's trace, when it rooted one, gets the wall as a parentless
+    span and lends its id as the exemplar and as the parent context of
+    what runs after it closed."""
+    import time
+    from yacy_search_server_tpu.utils import histogram
+    histogram.reset()
+    with tracing.envelope("servlet.serving", "servlet.cpu") as sv:
+        assert sv.ctx is None
+    assert tracing.traces(10) == []                 # no root: no span
+    with tracing.envelope("servlet.serving", "servlet.cpu") as sv:
+        with tracing.trace("servlet.fake") as r:
+            t_end = time.thread_time() + 0.01
+            while time.thread_time() < t_end:       # 10 ms of CPU
+                pass
+        time.sleep(0.02)                            # 20 ms of waiting
+        assert sv.ctx[0] == r.ctx[0]
+        with tracing.timed("servlet.render", sv.ctx):
+            pass
+    by = {s.name: s for s in tracing.get_trace(r.ctx[0]).spans}
+    serving = by["servlet.serving"]
+    assert serving.parent == "" and by["servlet.fake"].parent == ""
+    assert by["servlet.render"].parent == serving.sid
+    assert 9.0 <= serving.attrs["cpu_ms"] < serving.dur_ms - 15.0
+    assert histogram.get("servlet.serving").count == 2
+    assert histogram.get("servlet.cpu").count == 2
+    ex = [e for e in histogram.get("servlet.serving").exemplars if e]
+    assert ex and ex[-1][0] == r.ctx[0]
+
+
+def test_collector_hook_takes_no_lock_and_files_later(monkeypatch):
+    import gc
+    from yacy_search_server_tpu.utils import histogram
+    histogram.reset()
+    tracing.watch_gc(False)
+    tracing.watch_gc()
+    tracing.watch_gc()                              # idempotent
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    monkeypatch.setattr(tracing, "GC_SPAN_MIN_MS", 0.0)
+    tracing._gc_pending.clear()
+    try:
+        # the hook runs INSIDE the spine's and a family's locked
+        # sections without deadlock: it only queues
+        with tracing._lock, histogram.histogram(tracing.GC_FAMILY)._lock:
+            gc.collect()
+        assert len(tracing._gc_pending) == 1
+        with tracing.trace("root") as r:
+            gc.collect()                            # interrupts the trace
+        # the root's own record flushed both: the untraced one to the
+        # family alone, the traced one as a span of the trace too
+        spans = tracing.get_trace(r.ctx[0]).spans
+        pause = [s for s in spans if s.name == "runtime.gc"]
+        assert len(pause) == 1 and pause[0].attrs["generation"] == 2
+        assert pause[0].parent == spans[-1].sid
+        assert histogram.get("runtime.gc").count == 2
+    finally:
+        tracing.watch_gc(False)
+    assert tracing._on_gc not in gc.callbacks
+    gc.collect()
+    assert not tracing._gc_pending
+
+
+# -- the request's span tree, route by route (ISSUE 25) ----------------------
+
+ROUTES = ("event_cache", "topk_cache", "device", "host_gate", "host_other")
+_HTTP = {"servlet.serving", "servlet.render", "servlet.yacysearch",
+         "switchboard.search", "search.page", "search.resultlist",
+         "search.snippets"}
+_HOST = {"search.join", "search.presort", "search.normalizing"}
+_BATCH = {"devstore.batch", "batcher.queue", "kernel.issue",
+          "kernel.device", "kernel.fetch"}
+# query, what the route adds to _HTTP (+ "search.route.<route>")
+ROUTE_CASES = {
+    "device": ("bigterm", {"search.devrank"} | _BATCH),
+    "event_cache": ("bigterm", set()),
+    "topk_cache": ("bigterm&nocache=true", set()),
+    "host_gate": ("small", _HOST),
+    "host_other": ("bigterm+site:h1.example+bigtwo", _HOST),
+}
+PARENTS = {
+    "servlet.serving": "", "servlet.yacysearch": "",
+    "servlet.render": "servlet.serving",
+    "switchboard.search": "servlet.yacysearch",
+    "search.page": "servlet.yacysearch",
+    "search.snippets": "search.page",
+    "search.devrank": "search.route.device",
+    "devstore.batch": "search.devrank",
+    **{n: "devstore.batch" for n in _BATCH - {"devstore.batch"}},
+}
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One node over real HTTP on a single-device store: two lists over
+    the host gate (lowered to 100 rows), one under it."""
+    import json
+    import tempfile
+    import urllib.request
+
+    import numpy as np
+    from yacy_search_server_tpu.index import postings as P
+    from yacy_search_server_tpu.index.postings import PostingsList
+    from yacy_search_server_tpu.server.httpd import YaCyHttpServer
+    from yacy_search_server_tpu.switchboard import Switchboard
+    from yacy_search_server_tpu.utils.config import Config
+    from yacy_search_server_tpu.utils.hashes import word2hash
+    cfg = Config()
+    cfg.set("index.device.mesh", "off")
+    sb = Switchboard(tempfile.mkdtemp(prefix="yacy-routes-"), config=cfg)
+    n = 6000
+    sb.index.metadata.bulk_load(
+        [f"{i:07d}{i % 7:05d}".encode() for i in range(n)],
+        sku=[f"http://h{i % 7}.example/d{i}" for i in range(n)],
+        title=[f"doc {i}" for i in range(n)],
+        host_s=[f"h{i % 7}.example" for i in range(n)],
+        size_i=[1000] * n, wordcount_i=[100] * n)
+    rng = np.random.default_rng(25)
+
+    def plist(m):
+        return PostingsList(
+            np.sort(rng.choice(n, m, replace=False)).astype(np.int32),
+            rng.integers(1, 50, (m, P.NF)).astype(np.int32))
+    sb.index.rwi.ingest_run({word2hash("bigterm"): plist(5000),
+                             word2hash("bigtwo"): plist(4000),
+                             word2hash("small"): plist(40)})
+    ds = sb.index.devstore
+    ds.small_rank_n = 100
+    srv = YaCyHttpServer(sb, port=0).start()
+
+    def get(query):
+        url = f"http://127.0.0.1:{srv.port}/yacysearch.json?query={query}"
+        with urllib.request.urlopen(url) as r:
+            ch = json.loads(r.read())["channels"][0]
+        assert len(ch["items"]) == 10
+        rec = None
+        for _ in range(200):            # the envelope closes after the
+            rec = tracing.get_trace(ch["traceID"])      # body is built
+            if rec and any(s.name == "servlet.serving" for s in rec.spans):
+                break
+            threading.Event().wait(0.01)
+        return rec
+
+    get("bigterm&nocache=true")         # compile outside the traced runs
+    yield sb, ds, get
+    srv.close()
+    sb.close()
+
+
+def _fresh(sb, ds):
+    from yacy_search_server_tpu.utils import histogram
+    sb.search_cache.clear()
+    ds._topk_cache.clear()
+    tracing.clear()
+    histogram.reset_windows()
+
+
+def _route_counts():
+    from yacy_search_server_tpu.utils import histogram
+    out = {}
+    for r in ROUTES:
+        h = histogram.get("search.route." + r)
+        out[r] = h.windowed_count() if h is not None else 0
+    return out
+
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_one_request_closes_its_routes_span_tree(served, route):
+    """GET /yacysearch.json through each of the five routes: exactly the
+    listed spans under ONE trace id, the right parents, no child
+    outside its parent, exactly one `search.route.*`."""
+    sb, ds, get = served
+    _fresh(sb, ds)
+    if route in ("event_cache", "topk_cache"):
+        get("bigterm")                  # the answer the caches repeat
+    query, adds = ROUTE_CASES[route]
+    rec = get(query)
+    spans = [s for s in rec.spans
+             if not s.name.startswith(("runtime.", "tail."))]
+    names = {s.name for s in spans}
+    kernels = {n for n in names if n.startswith("kernel._")}
+    assert len(kernels) == (route == "device")      # the kernel's wall
+    assert names - kernels == _HTTP | adds | {"search.route." + route}
+    assert [s.name for s in spans if s.name.startswith("search.route")] \
+        == ["search.route." + route]
+    by_sid = {s.sid: s for s in rec.spans}
+    for s in spans:
+        want = PARENTS.get(s.name)
+        if s.name.startswith("search.route."):
+            want = "switchboard.search"
+        elif s.name in _HOST:
+            want = "search.route." + route
+        elif s.name in kernels:
+            want = "devstore.batch"
+        got = by_sid[s.parent].name if s.parent else ""
+        if s.name == "search.resultlist":   # the event's join, the page's
+            assert got in ("switchboard.search", "search.page")
+            continue
+        assert got == want, (s.name, got, want)
+    # no child starts before or ends after its parent (1 ms for the two
+    # clocks a span is stamped with), so child cover never exceeds it
+    for s in spans:
+        outer = by_sid.get(s.parent) or next(
+            x for x in spans if x.name == "servlet.serving")
+        if s is outer:
+            continue
+        assert s.ts >= outer.ts - 0.001, (s.name, outer.name)
+        assert s.ts + s.dur_ms / 1e3 <= outer.ts + outer.dur_ms / 1e3 \
+            + 0.001, (s.name, outer.name)
+    serving = next(s for s in spans if s.name == "servlet.serving")
+    assert 0.0 < serving.attrs["cpu_ms"] <= serving.dur_ms + 1.0
+    if route == "device":
+        batch = next(s for s in spans if s.name == "devstore.batch")
+        assert batch.attrs["batch_n"] == 1
+        assert batch.attrs["kernel"].startswith("_rank_pruned")
+        assert "kernel." + batch.attrs["kernel"] in kernels
+
+
+def test_route_counts_tie_to_the_stores_counters(served):
+    """The identity, from the code: a `topk_cache` route is one
+    rank_cache_get hit (`rank_cache_hits` and `queries_served` move), a
+    `device` route one rank_term / rank_join answer (`queries_served`
+    moves), a declined device attempt is `fallbacks` and a `host_other`
+    route; `event_cache` and `host_gate` never reach the store. So
+    topk_cache + device == d(queries_served), topk_cache ==
+    d(rank_cache_hits), d(fallbacks) <= host_other, and the five sum to
+    the searches made."""
+    sb, ds, get = served
+    _fresh(sb, ds)
+    c0 = ds.counters()
+    plan = ["bigterm", "bigterm", "bigterm&nocache=true", "small",
+            "bigterm+bigtwo", "bigterm+bigtwo&nocache=true", "small",
+            "bigterm+site:h1.example+bigtwo", "bigtwo", "bigtwo"]
+    for q in plan:
+        get(q)
+    # a store that declines: the device attempt falls to the host
+    ds._topk_cache.clear()
+    ds.device_lost = True
+    try:
+        get("bigtwo&nocache=true")
+    finally:
+        ds.device_lost = False
+    c1 = ds.counters()
+    d = {k: c1[k] - c0[k] for k in ("queries_served", "rank_cache_hits",
+                                    "fallbacks")}
+    routes = _route_counts()
+    assert routes == {"event_cache": 3, "topk_cache": 1, "device": 4,
+                      "host_gate": 1, "host_other": 2}, routes
+    assert sum(routes.values()) == len(plan) + 1
+    assert routes["topk_cache"] + routes["device"] == d["queries_served"]
+    assert routes["topk_cache"] == d["rank_cache_hits"]
+    assert 1 == d["fallbacks"] <= routes["host_other"]
+
+
+def test_the_queue_wait_is_measured_with_tail_attribution_off(served):
+    """`batcher.queue` is stamped by the dispatcher that takes the part,
+    always; the tail classifier reads that one stamp when it is on."""
+    from yacy_search_server_tpu.utils import histogram, tailattr
+    sb, ds, get = served
+    _fresh(sb, ds)
+    was = tailattr.enabled()
+    tailattr.set_enabled(False)
+    try:
+        rec = get("bigterm")
+    finally:
+        tailattr.set_enabled(was)
+    by = {s.name: s for s in rec.spans}
+    assert "wave_n" not in by["devstore.batch"].attrs
+    assert 0.0 <= by["batcher.queue"].dur_ms <= by["devstore.batch"].dur_ms
+    assert histogram.get("batcher.queue").windowed_count() == 1
+    _fresh(sb, ds)
+    rec = get("bigterm")
+    by = {s.name: s for s in rec.spans}
+    assert by["devstore.batch"].attrs["wave_queue_ms"] == pytest.approx(
+        by["batcher.queue"].dur_ms, abs=0.001)

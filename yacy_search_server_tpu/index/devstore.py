@@ -74,7 +74,7 @@ from ..ops.ranking import (_ACTIVE_COLS, RankingProfile,
 from ..ops.streaming import merge_stats
 from ..utils.eventtracker import EClass, update as track
 from ..utils.profiler import PROFILER
-from ..utils import faultinject, histogram, profiling, tailattr, tracing
+from ..utils import faultinject, profiling, tailattr, tracing
 from . import integrity
 from . import postings as P
 from .pagedrun import PagedRun
@@ -255,24 +255,16 @@ def _pmax_window(max_tcount: int) -> int:
 
 def _emit_rt_spans(issue_ms: float, fetch_ms: float,
                    device_ms: float = 0.0) -> None:
-    """Record the issue/device/fetch round-trip decomposition: as child
-    spans under the active trace (which feeds the windowed histograms
-    through the span record, exemplar included), or straight into the
-    histograms when untraced — the kernel-stage p50/p95 on /metrics
-    covers every dispatch either way (ISSUE 4). Solo dispatches fetch
-    immediately after issuing, so their in-flight `device` window is ~0
-    and the device time rides inside `fetch`; the pipelined batch path
-    stamps a real in-flight window (see _QueryBatcher._complete)."""
-    if tracing.current() is None:
-        histogram.observe("kernel.issue", issue_ms)
-        histogram.observe("kernel.device", device_ms)
-        histogram.observe("kernel.fetch", fetch_ms)
-        return
-    tracing.emit("kernel.issue", issue_ms)
-    tracing.emit("kernel.device", device_ms)
-    tracing.emit("kernel.fetch", fetch_ms)
-
-
+    """Record the issue/device/fetch round-trip decomposition of a SOLO
+    dispatch: child spans under the active trace, the families alone
+    outside one (`tracing.record` does either) — the kernel-stage
+    p50/p95 on /metrics covers every dispatch (ISSUE 4). Solo dispatches
+    fetch immediately after issuing, so their in-flight `device` window
+    is ~0 and the device time rides inside `fetch`; the pipelined batch
+    path stamps a real in-flight window (see _QueryBatcher._complete)."""
+    tracing.record("kernel.issue", issue_ms)
+    tracing.record("kernel.device", device_ms)
+    tracing.record("kernel.fetch", fetch_ms)
 
 
 # ---------------------------------------------------------------------------
@@ -1847,9 +1839,6 @@ class _QueryBatcher:
         self._ms_lock = threading.Lock()   # extends race counters() reads
         self.query_dispatch_ms: "deque" = deque(maxlen=20000)
         self.query_kernel_ms: "deque" = deque(maxlen=20000)
-        # (ms, n_plain, n_join, n_join_families) of dispatches > 500 ms —
-        # the slow-dispatch composition trace the profiler prints
-        self.slow_log: "deque" = deque(maxlen=100)
         # ONE batch-former + a POOL of dispatcher threads. The former
         # owns the incoming queue, so a concurrent burst lands in FULL
         # batches (competing dispatchers would fragment it ~max_batch/4
@@ -1897,42 +1886,52 @@ class _QueryBatcher:
         (the solo kernels share the batch kernels' compile shapes, so a
         withdrawn query never pays a fresh jit compile).
 
-        Tracing: the whole enqueue→flush→dispatch wait is one span on
-        the SUBMITTER's trace; the dispatcher stamps the item with its
-        group's kernel wall (the same wall the profiler records), which
-        is re-emitted here as a child span — dispatcher threads carry no
-        trace context of their own."""
-        sp = tracing.span("devstore.batch", kind=item.get("kind", "term"))
-        untraced = sp is tracing._NOOP
-        t_sub = time.perf_counter()
+        Tracing: the whole enqueue→flush→dispatch wait is one wall on
+        the SUBMITTER's thread (`devstore.batch`: a span of its trace,
+        an annotation on the profiler's timeline, and always one
+        observation of the family). The batcher's own threads carry no
+        trace context: they annotate their work where it happens
+        (`batcher.form` / `batcher.handoff`, `kernel.issue`,
+        `kernel.fetch`) and stamp the item with its walls, which are
+        re-emitted here as child spans."""
+        sp = tracing.timed("devstore.batch", kind=item.get("kind", "term"))
         with sp:
+            # one (epoch, perf_counter) pair places the batcher's
+            # perf_counter stamps on the waterfall's clock
+            epoch0 = time.time() - time.perf_counter()
             res = self._submit_wait_inner(item)
             km = item.get("kernel_ms")
             # a withdrawn query's late-stamped dispatch is discarded
             # work: the solo retry emits the REAL kernel span, and a
             # timeout emit here would double-count the query's wall
             if km is not None and res[0] != "timeout":
-                if not untraced:
-                    tracing.emit(f"kernel.{item.get('kernel_name', '?')}",
-                                 km, batch=item.get("batch_n", 0))
+                # dispatch shapes: a count per (kernel, batch size) in
+                # the span record
+                shape = {"kernel": item.get("kernel_name", "?"),
+                         "batch_n": item.get("batch_n", 0)}
+                t_issue = item.get("issue_t0", 0.0)
+                t_fetch = item.get("fetch_t0", 0.0)
+                tracing.emit(f"kernel.{shape['kernel']}", km,
+                             ts=epoch0 + t_issue, batch=shape["batch_n"])
+                # enqueue -> a dispatcher takes the part, then the
                 # round-trip decomposition (pipelined dispatch): issue =
                 # host-side async dispatch of the jitted call; device =
                 # the in-flight window (device executing while the
                 # dispatcher already issues the next part); fetch = the
-                # completer's blocking device->host transfer.  Traced,
-                # the emits feed the histograms through the span record;
-                # untraced, record directly (ISSUE 4: the /metrics
-                # distributions must cover the whole workload)
-                for stage in ("issue", "device", "fetch"):
-                    ms = item.get(f"{stage}_ms")
+                # completer's blocking device->host transfer
+                for name, key, t0 in (
+                        ("batcher.queue", "queue_ms", item["t_submit"]),
+                        ("kernel.issue", "issue_ms", t_issue),
+                        ("kernel.device", "device_ms",
+                         t_fetch - item.get("device_ms", 0.0) / 1000.0),
+                        ("kernel.fetch", "fetch_ms", t_fetch)):
+                    ms = item.get(key)
                     if ms is not None:
-                        if untraced:
-                            histogram.observe(f"kernel.{stage}", ms)
-                        else:
-                            tracing.emit(f"kernel.{stage}", ms)
+                        tracing.record(name, ms, ts=epoch0 + t0, **shape)
+                sp.set(**shape)
             sp.set(outcome=res[0])
             wave = item.get("wave")
-            if wave is not None and not untraced:
+            if wave is not None:
                 # the wave stamp (ISSUE 15b) on the batch span: the
                 # tail classifier reads these to attribute the query's
                 # slowness to its wave (queue depth / occupancy /
@@ -1941,22 +1940,20 @@ class _QueryBatcher:
                        wave_qdepth=wave["qdepth"],
                        wave_compile=wave["compile"],
                        wave_kernel=wave["kernel"],
-                       wave_queue_ms=round(
-                           item.get("queue_wait_ms", 0.0), 3))
-        if untraced:
-            histogram.observe("devstore.batch",
-                              (time.perf_counter() - t_sub) * 1000.0)
+                       wave_queue_ms=round(item.get("queue_ms", 0.0), 3))
         return res
 
     def _submit_wait_inner(self, item: dict):
         ev = item["ev"]
         if tailattr.enabled():
-            # queue depth AT ENQUEUE + the submit stamp the wave uses
-            # to MEASURE this query's pre-issue wait (ISSUE 15b): the
-            # classifier must never infer queue time by subtracting
-            # overlapping kernel spans
+            # queue depth AT ENQUEUE, for the wave stamp (ISSUE 15b)
             item["q_depth"] = self._q.qsize()
-            item["t_submit"] = time.perf_counter()
+        # the ONE submit stamp: the dispatcher that takes the part
+        # MEASURES this query's pre-issue wait against it (`queue_ms`:
+        # the `batcher.queue` family, and what the tail classifier
+        # reads — queue time is never inferred by subtracting
+        # overlapping kernel spans)
+        item["t_submit"] = time.perf_counter()
         self._q.put(item)
         if ev.wait(timeout=self.WATCHDOG_S):
             return item["res"]
@@ -2099,7 +2096,6 @@ class _QueryBatcher:
         busy the handoff blocks — and the batch keeps growing from the
         backlog, so saturation produces FULL batches (one round trip for
         a whole burst) while an idle pool dispatches singles instantly."""
-        import queue as _queue
         while True:
             item = self._q.get()
             if item is None:
@@ -2116,76 +2112,91 @@ class _QueryBatcher:
                 return
             if not self._claim(item, stage="form"):
                 continue  # withdrawn by its submitter while queued
-            batch = [item]
+            # on the profiler's timeline the former's two halves are
+            # `batcher.form` (growing the batch) and `batcher.handoff`
+            # (blocked on the one-slot queue while the pool is busy)
+            with tracing.annotation("batcher.form"):
+                batch = self._grow_batch(item)
+            with tracing.annotation("batcher.handoff", batch_n=len(batch)):
+                self._hand_off(batch)
 
-            def joins_full() -> bool:
-                joins = [it for it in batch if it.get("kind") == "join"]
-                if not joins:
-                    return False
-                return len(joins) >= min(it.get("joincap",
-                                                self.MAX_JOIN_BATCH)
-                                         for it in joins)
+    def _joins_full(self, batch: list[dict]) -> bool:
+        joins = [it for it in batch if it.get("kind") == "join"]
+        if not joins:
+            return False
+        return len(joins) >= min(it.get("joincap", self.MAX_JOIN_BATCH)
+                                 for it in joins)
 
-            def drain() -> int:
-                got = 0
-                while len(batch) < self.max_batch and not joins_full():
-                    try:
-                        nxt = self._q.get_nowait()
-                    except _queue.Empty:
-                        return got
-                    if nxt is None:
-                        self._q.put(None)  # re-deliver shutdown signal
-                        return got
-                    if self._claim(nxt, stage="form"):
-                        batch.append(nxt)
-                        got += 1
+    def _drain_into(self, batch: list[dict]) -> int:
+        """Claim whatever is already queued into `batch`, up to its
+        caps; returns how many were added."""
+        import queue as _queue
+        got = 0
+        while len(batch) < self.max_batch and not self._joins_full(batch):
+            try:
+                nxt = self._q.get_nowait()
+            except _queue.Empty:
                 return got
+            if nxt is None:
+                self._q.put(None)  # re-deliver shutdown signal
+                return got
+            if self._claim(nxt, stage="form"):
+                batch.append(nxt)
+                got += 1
+        return got
 
-            # wave-aware growth: concurrent searchers complete together
-            # (they were batched together), so their next queries land
-            # together too. If the first drain found companions, a wave
-            # is in flight — keep collecting it (1.5 ms granularity,
-            # noise against a device round trip) until a pass finds
-            # nothing new. A LONE query dispatches immediately: without
-            # companions the first drain comes back empty. Small batches
-            # would otherwise self-perpetuate: they cap in-flight query
-            # coverage, completions come faster, and the next wave
-            # fragments the same way (the r4 150 q/s plateau).
-            if drain() > 0:
-                while len(batch) < self.max_batch and not joins_full():
-                    time.sleep(0.0015)
-                    if drain() == 0:
-                        break
-            while True:
-                if len(batch) >= self.max_batch or joins_full():
-                    # full: hand over, blocking per part until the pool
-                    # frees slots
-                    for part in self._split_parts(batch):
-                        self._ready.put(part)
+    def _grow_batch(self, item: dict) -> list[dict]:
+        # wave-aware growth: concurrent searchers complete together
+        # (they were batched together), so their next queries land
+        # together too. If the first drain found companions, a wave
+        # is in flight — keep collecting it (1.5 ms granularity,
+        # noise against a device round trip) until a pass finds
+        # nothing new. A LONE query dispatches immediately: without
+        # companions the first drain comes back empty. Small batches
+        # would otherwise self-perpetuate: they cap in-flight query
+        # coverage, completions come faster, and the next wave
+        # fragments the same way (the r4 150 q/s plateau).
+        batch = [item]
+        if self._drain_into(batch) > 0:
+            while len(batch) < self.max_batch \
+                    and not self._joins_full(batch):
+                time.sleep(0.0015)
+                if self._drain_into(batch) == 0:
                     break
+        return batch
+
+    def _hand_off(self, batch: list[dict]) -> None:
+        import queue as _queue
+        while True:
+            if len(batch) >= self.max_batch or self._joins_full(batch):
+                # full: hand over, blocking per part until the pool
+                # frees slots
+                for part in self._split_parts(batch):
+                    self._ready.put(part)
+                return
+            try:
+                parts = self._split_parts(batch)
+                self._ready.put_nowait(parts[0])
+                # remaining parts (other join families) go to other
+                # dispatchers — a single dispatcher running families
+                # back to back serialized the whole mixed load while
+                # the pool idled (the r4 modifier-mix convoy)
+                for part in parts[1:]:
+                    self._ready.put(part)
+                return
+            except _queue.Full:
+                # pool saturated: the batch cannot run yet anyway —
+                # keep growing it from whatever arrives
                 try:
-                    parts = self._split_parts(batch)
-                    self._ready.put_nowait(parts[0])
-                    # remaining parts (other join families) go to other
-                    # dispatchers — a single dispatcher running families
-                    # back to back serialized the whole mixed load while
-                    # the pool idled (the r4 modifier-mix convoy)
-                    for part in parts[1:]:
-                        self._ready.put(part)
-                    break
-                except _queue.Full:
-                    # pool saturated: the batch cannot run yet anyway —
-                    # keep growing it from whatever arrives
-                    try:
-                        nxt = self._q.get(timeout=0.005)
-                    except _queue.Empty:
-                        continue
-                    if nxt is None:
-                        self._q.put(None)
-                        self._ready.put(batch)
-                        break
-                    if self._claim(nxt, stage="form"):
-                        batch.append(nxt)
+                    nxt = self._q.get(timeout=0.005)
+                except _queue.Empty:
+                    continue
+                if nxt is None:
+                    self._q.put(None)
+                    self._ready.put(batch)
+                    return
+                if self._claim(nxt, stage="form"):
+                    batch.append(nxt)
 
     def _split_parts(self, batch: list[dict]) -> list[list[dict]]:
         """Partition a formed batch so no dispatcher serializes unrelated
@@ -2264,6 +2275,10 @@ class _QueryBatcher:
             # inside the dispatch makes the watchdog's worker_stall
             # attribution and the health rule testable deterministically
             faultinject.sleep("batcher.dispatch")
+            now = time.perf_counter()
+            for it in batch:    # a promotion has no waiting submitter
+                if "t_submit" in it:
+                    it["queue_ms"] = (now - it["t_submit"]) * 1000.0
             try:
                 self._dispatch(batch)
             except Exception:
@@ -2282,6 +2297,14 @@ class _QueryBatcher:
                         it["ev"].set()
             with self._ms_lock:
                 self.dispatches += 1
+
+    @staticmethod
+    def _issuing(kernel_name: str, items: list[dict]):
+        """The dispatcher's issue of one kernel call on the profiler's
+        timeline, on the thread that makes it (its wall, `issue_ms`, is
+        stamped on the items and re-emitted by their submitters)."""
+        return tracing.annotation("kernel.issue", kernel=kernel_name,
+                                  batch_n=len(items))
 
     def _stamp_wave(self, items: list[dict], kernel_name: str,
                     issue_ms: float) -> None:
@@ -2312,6 +2335,9 @@ class _QueryBatcher:
             self._stamp_wave(items, kernel_name, issue_ms)
         for it in items:
             it["issue_ms"] = issue_ms
+            it["issue_t0"] = t0
+            it["kernel_name"] = kernel_name
+            it["batch_n"] = len(items)
             it["stage"] = "inflight"    # issued, awaiting a completer
             it["issued"] = True         # a completer OWNS the answer now:
             #                             exception paths must not race it
@@ -2429,7 +2455,10 @@ class _QueryBatcher:
             it["fetch_t0"] = tf0
             it["stage"] = "fetch"
         try:
-            host = self.store.device_fetch(rec["out"])  # ONE packed transfer
+            with tracing.annotation("kernel.fetch", kernel=rec["name"],
+                                    batch_n=len(items)):
+                # ONE packed transfer
+                host = self.store.device_fetch(rec["out"])
         except Exception:
             with self._ms_lock:
                 self.exceptions += 1
@@ -2462,11 +2491,6 @@ class _QueryBatcher:
             self.query_dispatch_ms.extend([ms] * len(items))
             if ms > self.dispatch_ms_max:
                 self.dispatch_ms_max = ms
-            if ms > 500.0:
-                joins = [it for it in items if it.get("kind") == "join"]
-                self.slow_log.append(
-                    (round(ms, 1), len(items) - len(joins), len(joins),
-                     len({it["statics"] for it in joins})))
         if ms > 1000.0:
             track(EClass.SEARCH, "SLOWDISPATCH", len(items), ms)
 
@@ -2550,9 +2574,10 @@ class _QueryBatcher:
             maxt = _pmax_window(store._max_tcount)
             # ISSUE only (async dispatch): the packed kernel returns the
             # in-flight [bs, 2k+1] buffer; the completer pool fetches it
-            out = _rank_pruned_batch1_packed_kernel(
-                feats16, flags, docids, dead, pmax, qiq,
-                *consts, k=kk, maxt=maxt, bs=nbs)
+            with self._issuing("_rank_pruned_batch1_packed_kernel", items):
+                out = _rank_pruned_batch1_packed_kernel(
+                    feats16, flags, docids, dead, pmax, qiq,
+                    *consts, k=kk, maxt=maxt, bs=nbs)
             issue_ms = (time.perf_counter() - t0k) * 1000.0
 
             def finish(host, items=items, kk=kk, maxt=maxt, t0k=t0k,
@@ -2566,8 +2591,6 @@ class _QueryBatcher:
                         [wall * 1000.0] * len(items))
                 for it in items:   # trace stamps: re-emitted by submitters
                     it["kernel_ms"] = wall * 1000.0
-                    it["kernel_name"] = "_rank_pruned_batch1_packed_kernel"
-                    it["batch_n"] = len(items)
                 # silicon accounting: the device share of this dispatch
                 # (wall minus the measured trivial round trip) against
                 # the cost of the ACTIVE slots (pad slots stream nothing)
@@ -2629,8 +2652,9 @@ class _QueryBatcher:
             tmins, tmaxs, *prune_bound_consts(prof))
         t0k = time.perf_counter()
         maxt = _pmax_window(store._max_tcount)
-        out = _rank_pruned_batch1_bp_kernel(
-            pwords, dead, pmax, qiq, *consts, k=kk, maxt=maxt, bs=nbs)
+        with self._issuing("_rank_pruned_batch1_bp_kernel", items):
+            out = _rank_pruned_batch1_bp_kernel(
+                pwords, dead, pmax, qiq, *consts, k=kk, maxt=maxt, bs=nbs)
         issue_ms = (time.perf_counter() - t0k) * 1000.0
         row_bits = sum(it["span"].row_bits for it in items) / len(items)
 
@@ -2645,8 +2669,6 @@ class _QueryBatcher:
                 self.query_kernel_ms.extend([wall * 1000.0] * len(items))
             for it in items:
                 it["kernel_ms"] = wall * 1000.0
-                it["kernel_name"] = "_rank_pruned_batch1_bp_kernel"
-                it["batch_n"] = len(items)
             PROFILER.record(
                 "_rank_pruned_batch1_bp_kernel",
                 max(wall - store.dispatch_rt_ms / 1e3, 1e-6),
@@ -2682,13 +2704,14 @@ class _QueryBatcher:
         for it in items:
             t0k = time.perf_counter()
             try:
-                if "ann_cluster" in it:
-                    # ANN cluster promotion rides the same part kind
-                    # (ISSUE 11): warm/cold vector clusters upload into
-                    # the hot arena off the query path
-                    out = store._ann_promote_now(it["ann_cluster"])
-                else:
-                    out = store._promote_now(it["key"], it["run"])
+                with self._issuing("tier_promote", [it]):
+                    if "ann_cluster" in it:
+                        # ANN cluster promotion rides the same part
+                        # kind (ISSUE 11): warm/cold vector clusters
+                        # upload into the hot arena off the query path
+                        out = store._ann_promote_now(it["ann_cluster"])
+                    else:
+                        out = store._promote_now(it["key"], it["run"])
             except Exception:
                 with self._ms_lock:
                     self.exceptions += 1
@@ -2757,9 +2780,11 @@ class _QueryBatcher:
                     qi[i, 2 * ns + 2] = DAYS_NONE_LO if fd is None else fd
                     qi[i, 2 * ns + 3] = DAYS_NONE_HI if td is None else td
                 t0k = time.perf_counter()
-                out = _rank_scan_batch_packed_kernel(
-                    feats16, flags, docids, dead, qi, *consts,
-                    k=kk, n_spans=ns, bs=bs)
+                with self._issuing("_rank_scan_batch_packed_kernel",
+                                   chunk):
+                    out = _rank_scan_batch_packed_kernel(
+                        feats16, flags, docids, dead, qi, *consts,
+                        k=kk, n_spans=ns, bs=bs)
                 issue_ms = (time.perf_counter() - t0k) * 1000.0
 
                 def finish(host, chunk=chunk, kk=kk, ns=ns, t0k=t0k,
@@ -2772,8 +2797,6 @@ class _QueryBatcher:
                                                     * len(chunk))
                     for it in chunk:
                         it["kernel_ms"] = wall * 1000.0
-                        it["kernel_name"] = "_rank_scan_batch_packed_kernel"
-                        it["batch_n"] = len(chunk)
                     PROFILER.record(
                         "_rank_scan_batch_packed_kernel",
                         max(wall - store.dispatch_rt_ms / 1e3, 1e-6),
@@ -2813,8 +2836,10 @@ class _QueryBatcher:
                 for i, it in enumerate(chunk):
                     qi[i] = it["qrow"]
                 t0k = time.perf_counter()
-                out = _rerank_fwd_batch_packed_kernel(fwd, qi, nb=nb,
-                                                      bs=bs)
+                with self._issuing("_rerank_fwd_batch_packed_kernel",
+                                   chunk):
+                    out = _rerank_fwd_batch_packed_kernel(fwd, qi, nb=nb,
+                                                          bs=bs)
                 issue_ms = (time.perf_counter() - t0k) * 1000.0
 
                 def finish(host, chunk=chunk, nb=nb, t0k=t0k, fwd=fwd,
@@ -2825,9 +2850,6 @@ class _QueryBatcher:
                                                     * len(chunk))
                     for it in chunk:
                         it["kernel_ms"] = wall * 1000.0
-                        it["kernel_name"] = \
-                            "_rerank_fwd_batch_packed_kernel"
-                        it["batch_n"] = len(chunk)
                     PROFILER.record(
                         "_rerank_fwd_batch_packed_kernel",
                         max(wall - store.dispatch_rt_ms / 1e3, 1e-6),
@@ -2906,7 +2928,9 @@ class _QueryBatcher:
             for pos in range(0, len(its), bs):
                 chunk = its[pos:pos + bs]
                 t0k = time.perf_counter()
-                out = store._ann_fuse_issue(chunk, nb, kk, bs)
+                with self._issuing("_ann_fuse_batch_packed_kernel",
+                                   chunk):
+                    out = store._ann_fuse_issue(chunk, nb, kk, bs)
                 issue_ms = (time.perf_counter() - t0k) * 1000.0
 
                 def finish(host, chunk=chunk, nb=nb, kk=kk, t0k=t0k,
@@ -2917,9 +2941,6 @@ class _QueryBatcher:
                                                     * len(chunk))
                     for it in chunk:
                         it["kernel_ms"] = wall * 1000.0
-                        it["kernel_name"] = \
-                            "_ann_fuse_batch_packed_kernel"
-                        it["batch_n"] = len(chunk)
                     PROFILER.record(
                         "_ann_fuse_batch_packed_kernel",
                         max(wall - store.dispatch_rt_ms / 1e3, 1e-6),
@@ -2998,19 +3019,22 @@ class _QueryBatcher:
                     for i, it in enumerate(chunk):
                         qb[i] = it["qargs"]   # pad rows: count 0 -> empty
                     t0k = time.perf_counter()
-                    if any_bm:
-                        out = _rank_join_bm_batch_packed_kernel(
-                            *first["arrays"], first["dead"],
-                            *first["join"],
-                            qb, *consts, k=kk, n_inc=n_inc, n_exc=n_exc,
-                            r=r, inc_ms=inc_ms, exc_ms=exc_ms,
-                            inc_bm=inc_bm, exc_bm=exc_bm)
-                    else:
-                        out = _rank_join_batch_packed_kernel(
-                            *first["arrays"], first["dead"],
-                            *first["join"],
-                            qb, *consts, k=kk, n_inc=n_inc, n_exc=n_exc,
-                            r=r, inc_ms=inc_ms, exc_ms=exc_ms)
+                    with self._issuing(kname, chunk):
+                        if any_bm:
+                            out = _rank_join_bm_batch_packed_kernel(
+                                *first["arrays"], first["dead"],
+                                *first["join"],
+                                qb, *consts, k=kk, n_inc=n_inc,
+                                n_exc=n_exc, r=r, inc_ms=inc_ms,
+                                exc_ms=exc_ms, inc_bm=inc_bm,
+                                exc_bm=exc_bm)
+                        else:
+                            out = _rank_join_batch_packed_kernel(
+                                *first["arrays"], first["dead"],
+                                *first["join"],
+                                qb, *consts, k=kk, n_inc=n_inc,
+                                n_exc=n_exc, r=r, inc_ms=inc_ms,
+                                exc_ms=exc_ms)
                     issue_ms = (time.perf_counter() - t0k) * 1000.0
 
                     def finish(host, chunk=chunk, t0k=t0k, kname=kname,
@@ -3026,8 +3050,6 @@ class _QueryBatcher:
                                 [wall * 1000.0] * len(chunk))
                         for it in chunk:
                             it["kernel_ms"] = wall * 1000.0
-                            it["kernel_name"] = kname
-                            it["batch_n"] = len(chunk)
                         windows = tuple(m for m in inc_ms + exc_ms if m)
                         PROFILER.record(
                             kname,
@@ -3955,10 +3977,7 @@ class DeviceSegmentStore:
             self._bump_epoch()
             self._maybe_prewarm()    # pwords growth re-keys compiles
             ms = (time.perf_counter() - t0) * 1000.0
-            if tracing.current() is None:
-                histogram.observe("tier.promote", ms)
-            else:
-                tracing.emit("tier.promote", ms, src=src)
+            tracing.record("tier.promote", ms, src=src)
             return out
         finally:
             with self._lock:

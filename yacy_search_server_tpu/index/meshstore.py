@@ -265,9 +265,7 @@ class _MeshQueryBatcher:
         item = {"th": termhash, "profile": profile, "lang": language,
                 "kk": kk, "ev": threading.Event(), "res": ("ineligible",),
                 "lk": threading.Lock(), "taken": False}
-        sp = tracing.span("mesh.batch")
-        untraced = sp is tracing._NOOP
-        t_sub = time.perf_counter()
+        sp = tracing.timed("mesh.batch")
         with sp:
             res = self._submit_wait(item)
             km = item.get("kernel_ms")
@@ -276,19 +274,15 @@ class _MeshQueryBatcher:
             # program in _complete, NOT here — per-query recording
             # would inflate it by the batch factor)
             if km is not None and res[0] != "timeout":
-                if not untraced:
-                    tracing.emit(f"kernel.{item.get('kernel_name', '?')}",
-                                 km, batch=item.get("batch_n", 0))
+                tracing.emit(f"kernel.{item.get('kernel_name', '?')}",
+                             km, batch=item.get("batch_n", 0))
                 for stage in ("issue", "device", "fetch"):
                     ms = item.get(f"{stage}_ms")
                     if ms is not None:
-                        if untraced:
-                            histogram.observe(f"kernel.{stage}", ms)
-                        else:
-                            tracing.emit(f"kernel.{stage}", ms)
+                        tracing.record(f"kernel.{stage}", ms)
             sp.set(outcome=res[0])
             wave = item.get("wave")
-            if wave is not None and not untraced:
+            if wave is not None:
                 # per-wave stamp on the batch span (ISSUE 15b,
                 # devstore parity): the tail classifier's evidence
                 sp.set(wave_n=wave["n"], wave_occ=wave["occ"],
@@ -296,10 +290,7 @@ class _MeshQueryBatcher:
                        wave_compile=wave["compile"],
                        wave_kernel=wave["kernel"],
                        wave_queue_ms=round(
-                           item.get("queue_wait_ms", 0.0), 3))
-        if untraced:
-            histogram.observe("mesh.batch",
-                              (time.perf_counter() - t_sub) * 1000.0)
+                           item.get("queue_ms", 0.0), 3))
         return res
 
     def _submit_wait(self, item: dict):

@@ -189,6 +189,28 @@ class SearchEvent:
     # -- local batched path --------------------------------------------------
 
     def _run_local(self) -> None:
+        # the ranking stage is ONE span, named on the way out by the
+        # route that produced the candidates: the windowed count of
+        # `search.route.<route>` IS the route counter, measured where
+        # the routing happens (with `event_cache`, which
+        # SearchEventCache.get_event records, exactly one per search)
+        with tracing.timed("search.route") as rt:
+            self._route = "host_other"
+            try:
+                ranked = self._rank_local()
+            finally:
+                rt.rename("search.route." + self._route)
+        if ranked is not None:
+            self._fill_results(*ranked)
+
+    def _rank_local(self):
+        """(scores, docids) of the local candidates, or None for an
+        empty answer. Sets `self._route`: `topk_cache` and `device`
+        where _device_local answered (from a peek, from the store),
+        `host_gate` where its small-candidate gate sent the query to
+        the NumPy ranker, `host_other` for every other host answer
+        (modifiers, date sort, no store, the store declined) and for
+        the cache-only rung's miss."""
         q = self.query
         k_need = max(q.item_count + q.offset, 10) * TOPK_OVERSAMPLE
 
@@ -199,10 +221,11 @@ class SearchEvent:
         if self.degrade_level >= 3:
             got = self._cache_only(k_need)
             if got is not None:
+                self._route = "topk_cache"
                 scores, docids, self.local_rwi_considered = got
                 if len(docids):
-                    self._fill_results(scores, docids)
-            return
+                    return scores, docids
+            return None
 
         # hybrid-cache plumbing: _device_local may serve a FULL cached
         # hybrid answer (rerank included, zero device work) or hand back
@@ -216,13 +239,12 @@ class SearchEvent:
         if placed is not None:
             scores, docids, self.local_rwi_considered = placed
             if len(docids) == 0:
-                return
+                return None
             if q.hybrid and not self._rerank_done:
                 scores, docids = self._second_stage(scores, docids,
                                                     k_need,
                                                     allow_put=True)
-            self._fill_results(scores, docids)
-            return
+            return scores, docids
 
         with StageTimer(EClass.SEARCH, "JOIN"):
             joined = self.segment.term_search(
@@ -230,13 +252,13 @@ class SearchEvent:
                 exclude_hashes=q.goal.exclude_hashes or None)
         self.local_rwi_considered = len(joined)
         if len(joined) == 0:
-            return
+            return None
 
         with StageTimer(EClass.SEARCH, "PRESORT"):
             mask = self._constraint_mask(joined)
             cand = joined.select(mask)
         if len(cand) == 0:
-            return
+            return None
 
         # the authority signal is the only hosthash consumer; the per-row
         # python loop must not run for profiles that never read it
@@ -262,8 +284,7 @@ class SearchEvent:
             # cached one would flap the versioned top-k contract
             scores, docids = self._second_stage(scores, docids, k_need,
                                                 allow_put=False)
-
-        self._fill_results(scores, docids)
+        return scores, docids
 
     def _cache_only(self, k: int):
         """Ladder rung 3 (ISSUE 9): the versioned top-k cache is the
@@ -392,11 +413,9 @@ class SearchEvent:
                     got = hpeek(inc[0], q.profile, q.lang, k,
                                 q.hybrid_alpha, dense_first=df)
                     if got is not None:
-                        wall_ms = (time.perf_counter() - q0) * 1000.0
                         track(EClass.SEARCH, "DEVRANK", len(got[1]),
-                              wall_ms)
-                        tracing.emit("search.devrank", wall_ms,
-                                     cache="hybrid_hit")
+                              (time.perf_counter() - q0) * 1000.0)
+                        self._route = "topk_cache"
                         self._rerank_done = True
                         return got
                     # the vector-content version is snapshotted HERE,
@@ -414,13 +433,13 @@ class SearchEvent:
                 q0 = time.perf_counter()
                 got = peek(inc[0], q.profile, q.lang, k)
                 if got is not None:
-                    # the stage still lands in BOTH observability
-                    # surfaces (attributable, with zero device work
-                    # behind it); hit-only so misses don't double-count
-                    # the real DEVRANK stage below
-                    wall_ms = (time.perf_counter() - q0) * 1000.0
-                    track(EClass.SEARCH, "DEVRANK", len(got[1]), wall_ms)
-                    tracing.emit("search.devrank", wall_ms, cache="hit")
+                    # the stage still lands in the stage counters
+                    # (attributable, with zero device work behind it;
+                    # hit-only so misses don't double-count the real
+                    # DEVRANK stage below); its wall is the route span's
+                    track(EClass.SEARCH, "DEVRANK", len(got[1]),
+                          (time.perf_counter() - q0) * 1000.0)
+                    self._route = "topk_cache"
                     return got
         # tiny candidate sets: the host path scores them in microseconds
         # (ops/ranking.SMALL_RANK_N numpy twin); a device dispatch and
@@ -434,6 +453,7 @@ class SearchEvent:
             thresh = SMALL_RANK_N
         if min(self.segment.rwi.count_upper(th)
                for th in inc) <= thresh:
+            self._route = "host_gate"
             return None
         if m.date_sort:
             return None
@@ -460,15 +480,19 @@ class SearchEvent:
             extra = ({"allow_bitmap": self._facet_filter_bitmap(ds, m)}
                      if facet_mods else {})
             with StageTimer(EClass.SEARCH, "DEVRANK"):
-                return ds.rank_term(
+                got = ds.rank_term(
                     inc[0], q.profile, q.lang, k=k,
                     lang_filter=lang_filter, flag_bit=flag_bit,
                     from_days=m.from_days, to_days=m.to_days, **extra)
-        with StageTimer(EClass.SEARCH, "DEVJOIN"):
-            return ds.rank_join(
-                inc, exc, q.profile, q.lang, k=k,
-                lang_filter=lang_filter, flag_bit=flag_bit,
-                from_days=m.from_days, to_days=m.to_days)
+        else:
+            with StageTimer(EClass.SEARCH, "DEVJOIN"):
+                got = ds.rank_join(
+                    inc, exc, q.profile, q.lang, k=k,
+                    lang_filter=lang_filter, flag_bit=flag_bit,
+                    from_days=m.from_days, to_days=m.to_days)
+        if got is not None:         # None: the store declined, host ranks
+            self._route = "device"
+        return got
 
     def _facet_filter_bitmap(self, ds, m):
         """Device filter bitmap for the active metadata modifiers —
@@ -852,6 +876,12 @@ class SearchEvent:
         """One page of results, best-first (oneResult loop equivalent).
         `with_snippets` overrides the query's snippet_fetch for THIS call
         (shared QueryParams on a cached event must never be mutated)."""
+        # the metadata join of what the page still lacks, the page and
+        # its snippets: one wall, traced or not (`search.page`)
+        with tracing.timed("search.page"):
+            return self._results(offset, count, with_snippets)
+
+    def _results(self, offset, count, with_snippets) -> list[ResultEntry]:
         self.touched = time.time()
         q = self.query
         offset = q.offset if offset is None else offset
@@ -1076,11 +1106,18 @@ class SearchEventCache:
     def get_event(self, query: QueryParams, segment: Segment,
                   loader=None) -> SearchEvent:
         qid = query.query_id()
+        t0 = time.perf_counter()
         with self._lock:
             ev = self._events.get(qid)
             if ev is not None:
                 ev.touched = time.time()
-                return ev
+        if ev is not None:
+            # the fifth route, counted where it is taken: a live event
+            # answers and nothing is ranked (SearchEvent._run_local
+            # records the other four)
+            tracing.record("search.route.event_cache",
+                           (time.perf_counter() - t0) * 1000.0)
+            return ev
         ev = SearchEvent(query, segment, loader=loader)
         with self._lock:
             self.cleanup_locked()

@@ -24,11 +24,10 @@ import json
 import math
 import os
 import threading
-import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qsl, unquote, urlsplit
 
-from ..utils import faultinject, histogram, tracing
+from ..utils import faultinject, tracing
 from .objects import ServerObjects
 from .templates import TemplateEngine
 from . import servlets
@@ -377,14 +376,17 @@ class YaCyHttpServer:
                           "Host", f"{self.host}:{self.port}")}
             # servlet serving wall -> windowed histogram (ISSUE 4): the
             # full dispatch+render wall of EVERY servlet — including
-            # ones that raise into the 500 handler below (the finally:
-            # a wedged endpoint must not vanish from the very SLO
-            # histogram that would page on it).  When the servlet
-            # rooted a trace, its id becomes the histogram exemplar so
-            # a slow bucket on /metrics links to the waterfall
-            tracing.clear_last_trace_id()
-            t_sv = time.perf_counter()
-            try:
+            # ones that raise into the 500 handler below (the envelope
+            # records on the way out: a wedged endpoint must not vanish
+            # from the very SLO histogram that would page on it), with
+            # the thread's CPU time of the same interval beside it
+            # (`servlet.cpu`).  When the servlet rooted a trace, the
+            # wall joins it and its id becomes the histogram exemplar,
+            # so a slow bucket on /metrics links to the waterfall
+            # lint: tail-ok(servlet.cpu is the CPU share of the
+            # servlet.serving wall beside it, which the classifier
+            # reaches: a breakdown of that wall, not a wall of its own)
+            with tracing.envelope("servlet.serving", "servlet.cpu") as sv:
                 # env-gated failpoint INSIDE the measured wall: injected
                 # latency lands in the very SLO histogram the burn-rate
                 # rules read, so ladder tests drive real burns
@@ -394,13 +396,12 @@ class YaCyHttpServer:
                     body = prop.raw_body
                     ctype = prop.raw_ctype or "application/octet-stream"
                 else:
-                    body = self._render(name, ext, prop).encode("utf-8")
+                    # lint: tail-ok(a child span of servlet.serving,
+                    # which the classifier reaches)
+                    with tracing.timed("servlet.render", sv.ctx):
+                        body = self._render(name, ext, prop).encode("utf-8")
                     ctype = prop.raw_ctype or _CONTENT_TYPES.get(
                         ext, "text/html; charset=utf-8")
-            finally:
-                histogram.observe("servlet.serving",
-                                  (time.perf_counter() - t_sv) * 1000.0,
-                                  tracing.last_trace_id())
             # any downgraded answer is stamped (ISSUE 9 satellite): a
             # client/load balancer can tell a degraded 200 from a full
             # one without parsing the body.  A lost device (ISSUE 10c)
@@ -697,14 +698,10 @@ class YaCyHttpServer:
             # slo_serving_p95 and the incident can name the cause.
             # Other wire RPCs (DHT shipping, digests, scatter internals)
             # stay out: they are not query serving.
-            tracing.clear_last_trace_id()
-            t_sv = time.perf_counter()
-            try:
+            # lint: tail-ok(servlet.cpu: the CPU share of the
+            # servlet.serving wall, see handle())
+            with tracing.envelope("servlet.serving", "servlet.cpu"):
                 result = self.peer_server.handle(endpoint, params)
-            finally:
-                histogram.observe("servlet.serving",
-                                  (time.perf_counter() - t_sv) * 1000.0,
-                                  tracing.last_trace_id())
         else:
             result = self.peer_server.handle(endpoint, params)
         body = json.dumps(result, default=_wire_default).encode("utf-8")

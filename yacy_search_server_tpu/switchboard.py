@@ -83,6 +83,9 @@ class Switchboard:
         if "tracing.enabled" in set(self.config.keys()):
             tracing.set_enabled(
                 self.config.get_bool("tracing.enabled", True))
+        # the collector's pauses are the spine's too (`runtime.gc`): a
+        # hook of two clock reads per collection, off with tracing
+        tracing.watch_gc(tracing.enabled())
         sub = (lambda s: os.path.join(data_dir, s)) if data_dir else (
             lambda s: None)
         if data_dir:

@@ -14,7 +14,7 @@ from collections import deque
 from dataclasses import dataclass
 from enum import Enum
 
-from . import histogram, tracing
+from . import tracing
 
 
 class EClass(Enum):
@@ -84,23 +84,20 @@ def clear(eclass: EClass | None = None) -> None:
 class StageTimer:
     """Context manager reporting one stage's wall time on exit.
 
-    Doubles as the eventtracker→tracing bridge: when a trace is active
-    on the calling context, the stage is ALSO recorded as a span named
-    ``<class>.<label>`` — every existing StageTimer site (search
-    stages, pipeline stages, crawl stages) joins the trace waterfall
-    without a second timing call. Outside a trace the span handle is
-    the shared no-op object (zero alloc).
-
-    Histogram bridge (ISSUE 4): a traced stage reaches the windowed
-    histograms through the span record (with its trace-id exemplar); an
-    UNTRACED stage records here directly — so the per-stage p50/p95 on
+    Doubles as the eventtracker→tracing bridge: the stage is ALSO a
+    `tracing.timed` wall named ``<class>.<label>`` — a span (and, while
+    a profiler session records, an annotation) when a trace is active
+    on the calling context, and either way one observation of the
+    windowed family of that name, so every existing StageTimer site
+    (search stages, pipeline stages, crawl stages) joins the trace
+    waterfall without a second timing call and the per-stage p50/p95 on
     `/metrics` covers the whole workload, not just the traced slice."""
 
     def __init__(self, eclass: EClass, label: str, count: int = 0):
         self.eclass, self.label, self.count = eclass, label, count
 
     def __enter__(self):
-        self._span = tracing.span(
+        self._span = tracing.timed(
             f"{self.eclass.value}.{self.label.lower()}")
         self._span.__enter__()
         self._t0 = time.monotonic()
@@ -110,7 +107,4 @@ class StageTimer:
         ms = (time.monotonic() - self._t0) * 1000.0
         update(self.eclass, self.label, self.count, ms)
         self._span.__exit__(*exc)
-        if self._span is tracing._NOOP:
-            histogram.observe(
-                f"{self.eclass.value}.{self.label.lower()}", ms)
         return False

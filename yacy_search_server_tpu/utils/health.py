@@ -588,7 +588,15 @@ class HealthEngine:
         rule, drive the actuator engine on the fresh rule states, dump
         an incident on an ok/warn->critical edge (rate limited).
         Returns the overall state."""
+        # the evaluation + actuator wall (`runtime.health_tick`): the
+        # exposition render and the rules run on this thread, under the
+        # interpreter lock the serving threads wait for
+        with tracing.timed("runtime.health_tick"):
+            return self._tick(now)
+
+    def _tick(self, now: float | None) -> str:
         now = time.time() if now is None else now
+        tracing.flush_gc()
         # idle histogram families must not freeze their windows (a
         # sticky SLO verdict after traffic stops): the tick drives
         # rotation for whatever recording's lazy rotation missed

@@ -179,7 +179,8 @@ class Histogram:
     ring (operator percentiles) + per-bucket trace-id exemplars."""
 
     __slots__ = ("name", "help", "_lock", "counts", "sum_ms", "count",
-                 "_win", "_wi", "_next_rot", "_p95_cache", "exemplars")
+                 "_win", "_wsum", "_wt0", "_wi", "_next_rot", "_p95_cache",
+                 "exemplars")
 
     def __init__(self, name: str, help_: str = ""):
         self.name = name
@@ -189,6 +190,10 @@ class Histogram:
         self.sum_ms = 0.0
         self.count = 0
         self._win = [[0] * N_BUCKETS for _ in range(WINDOWS)]
+        # per slot: the sum of its values (a windowed mean, a rate of
+        # time spent) and when it was opened (what the ring covers)
+        self._wsum = [0.0] * WINDOWS
+        self._wt0 = [time.monotonic()] * WINDOWS
         self._wi = 0
         self._next_rot = time.monotonic() + ROTATE_EVERY_S
         self._p95_cache = 0.0                  # refreshed at rotation
@@ -208,6 +213,7 @@ class Histogram:
             self.sum_ms += ms
             self.count += 1
             self._win[self._wi][idx] += 1
+            self._wsum[self._wi] += ms
             if trace_id is not None and (
                     ms >= self._p95_cache or self.exemplars[idx] is None):
                 self.exemplars[idx] = (trace_id, ms, time.time())
@@ -222,6 +228,8 @@ class Histogram:
         for _ in range(steps):
             self._wi = (self._wi + 1) % WINDOWS
             self._win[self._wi] = [0] * N_BUCKETS
+            self._wsum[self._wi] = 0.0
+            self._wt0[self._wi] = now
         self._next_rot = now + ROTATE_EVERY_S
         # exemplars age out at the window horizon: a bucket must never
         # keep pointing at a trace from hours ago (likely evicted from
@@ -244,6 +252,8 @@ class Histogram:
         the live workload."""
         with self._lock:
             self._win = [[0] * N_BUCKETS for _ in range(WINDOWS)]
+            self._wsum = [0.0] * WINDOWS
+            self._wt0 = [time.monotonic()] * WINDOWS
             self._wi = 0
             self._p95_cache = 0.0
             self._next_rot = time.monotonic() + ROTATE_EVERY_S
@@ -276,6 +286,20 @@ class Histogram:
 
     def windowed_count(self, last: int | None = None) -> int:
         return sum(self.windowed_counts(last))
+
+    def windowed_sum(self) -> float:
+        """Sum in ms of the retained windows' values: over
+        `windowed_count()` a mean, over `windowed_span_s()` the time
+        spent per second."""
+        with self._lock:
+            return sum(self._wsum)
+
+    def windowed_span_s(self) -> float:
+        """Seconds the retained windows cover: since the oldest slot was
+        opened — the family's creation, its last `reset_window()`, or
+        the rotation that dropped what came before."""
+        with self._lock:
+            return time.monotonic() - min(self._wt0)
 
     def window_seconds(self, last: int | None = None) -> float:
         """Wall time the newest `last` windows actually cover: the

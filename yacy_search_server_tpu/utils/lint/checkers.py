@@ -974,9 +974,11 @@ def tail_classifier_families(repo: Repo) -> set[str]:
 
 @checker("tail-reach", "tail-ok")
 def check_tail_reach(repo: Repo, stats: dict):
-    """Every histogram family a servlet wall observes directly
-    (``histogram.observe("<family>", ...)`` anywhere under server/)
-    must be reachable by the tail classifier — listed in
+    """Every histogram family a servlet wall observes directly (a
+    literal family name handed to ``histogram.observe`` or to one of
+    the spine's always-measured walls — ``tracing.envelope`` (both of
+    its families), ``tracing.timed``, ``tracing.record`` — anywhere
+    under server/) must be reachable by the tail classifier — listed in
     utils/tailattr.CLASSIFIER_FAMILIES — or carry a reasoned
     ``# lint: tail-ok(reason)``.  A serving wall the classifier cannot
     see is a p99 bucket nothing can ever explain: it fills the SLO
@@ -985,26 +987,28 @@ def check_tail_reach(repo: Repo, stats: dict):
     findings = []
     fams = tail_classifier_families(repo)
     observed = 0
+    walls = {"histogram.observe": 1, "tracing.envelope": 2,
+             "tracing.timed": 1, "tracing.record": 1}
     for ctx in repo.under("yacy_search_server_tpu/server/"):
         for node in ast.walk(ctx.tree):
-            if not (isinstance(node, ast.Call)
-                    and dotted(node.func) == "histogram.observe"
-                    and node.args
-                    and isinstance(node.args[0], ast.Constant)
-                    and isinstance(node.args[0].value, str)):
+            if not isinstance(node, ast.Call):
                 continue
-            observed += 1
-            fam = node.args[0].value
-            if fam in fams:
-                continue
-            if ctx.exempt(("tail-ok",), [node.lineno]):
-                continue
-            findings.append(Finding(
-                "tail-reach", ctx.rel, node.lineno,
-                f"servlet wall observes histogram family {fam!r} the "
-                f"tail classifier cannot reach — add it to "
-                f"utils/tailattr.CLASSIFIER_FAMILIES (and teach the "
-                f"classifier) or annotate `# lint: tail-ok(reason)`"))
+            for arg in node.args[:walls.get(dotted(node.func), 0)]:
+                if not (isinstance(arg, ast.Constant)
+                        and isinstance(arg.value, str)):
+                    continue
+                observed += 1
+                fam = arg.value
+                if fam in fams:
+                    continue
+                if ctx.exempt(("tail-ok",), ctx.node_lines(node)):
+                    continue
+                findings.append(Finding(
+                    "tail-reach", ctx.rel, node.lineno,
+                    f"servlet wall observes histogram family {fam!r} "
+                    f"the tail classifier cannot reach — add it to "
+                    f"utils/tailattr.CLASSIFIER_FAMILIES (and teach the "
+                    f"classifier) or annotate `# lint: tail-ok(reason)`"))
     stats["servlet_observed_families"] = observed
     stats["classifier_families"] = len(fams)
     return findings

@@ -44,7 +44,7 @@ import threading
 import time
 from collections import deque
 
-from . import histogram, tailattr
+from . import histogram, tailattr, tracing
 
 # -- thread-role canon --------------------------------------------------------
 
@@ -242,7 +242,11 @@ class SamplingProfiler:
                 return
             if _enabled:
                 try:
-                    self._sample()
+                    # the tick's own busy wall: it walks every thread's
+                    # stack under the interpreter lock, so this is time
+                    # taken from the serving threads
+                    with tracing.timed("runtime.sampler_tick"):
+                        self._sample()
                 except Exception:   # lint: broad-except-ok(the sampler
                     # must survive any racing interpreter state — a dead
                     # sampler silently ends all whitebox evidence)

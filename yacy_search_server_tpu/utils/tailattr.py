@@ -842,15 +842,20 @@ CONVICTIONS = ConvictionTracker()
 def stamp_wave(items: list, kernel: str, max_batch: int,
                first_use: bool, issue_ms: float,
                extra: dict | None = None) -> dict:
-    """Build ONE dispatch wave's timeline stamp and attach it (plus the
-    per-item MEASURED pre-issue wait, submit -> now) to every item —
-    the shared builder both batchers call (devstore `_stamp_wave`,
-    meshstore `_dispatch`), so wave evidence cannot diverge between
-    them.  Items carry `t_submit`/`q_depth` from their submit path;
-    `extra` is the store's tier/deferral snapshot."""
+    """Build ONE dispatch wave's timeline stamp and attach it to every
+    item — the shared builder both batchers call (devstore
+    `_stamp_wave`, meshstore `_dispatch`), so wave evidence cannot
+    diverge between them.  The per-item pre-issue wait is MEASURED by
+    the batcher, not here: `queue_ms`, the one stamp the
+    `batcher.queue` family records too (a batcher that has not taken
+    it yet, the mesh's, is measured submit -> now).  `q_depth` comes
+    from the submit path; `extra` is the store's tier/deferral
+    snapshot."""
     now = time.perf_counter()
-    waits = [(now - it["t_submit"]) * 1000.0 for it in items
-             if "t_submit" in it]
+    for it in items:
+        if "queue_ms" not in it and "t_submit" in it:
+            it["queue_ms"] = (now - it["t_submit"]) * 1000.0
+    waits = [it["queue_ms"] for it in items if "queue_ms" in it]
     wave = {"ts": round(time.time(), 3), "kernel": kernel,
             "n": len(items),
             "occ": round(len(items) / max(1, max_batch), 3),
@@ -862,8 +867,6 @@ def stamp_wave(items: list, kernel: str, max_batch: int,
             **(extra or {})}
     for it in items:
         it["wave"] = wave
-        if "t_submit" in it:
-            it["queue_wait_ms"] = (now - it["t_submit"]) * 1000.0
     ATTR.note_wave(wave)
     return wave
 

@@ -13,6 +13,19 @@ Design rules (the EventTracker discipline, applied to spans):
 - **Zero-alloc when disabled / untraced.** `span()` returns ONE shared
   no-op object unless tracing is enabled AND a trace is active on the
   calling context. A hot path outside any trace pays a contextvar read.
+- **One clock with the chip.** Every live span also opens a
+  `jax.profiler.TraceAnnotation` of its name on the thread where the
+  work runs, while a profiler session is recording: the spans then sit
+  in the host planes of the same `.xplane.pb` as the device operations
+  (`benchmarks/host_spans.py` labels device idle gaps with them).
+  Outside a session a span pays one `is_enabled()` read; a process that
+  never loaded JAX pays a dict lookup. `annotation()` is the same for
+  work that has no span of its own (the batcher's threads).
+- **One call for a wall that is always measured.** `timed()` is a live
+  span under a trace and a plain timer outside one; `record()` is the
+  same for a wall measured elsewhere. Either way the family in
+  `utils/histogram.py` gets the observation, so a distribution covers
+  the whole workload and not its traced share.
 - **Context-carried.** The active (trace_id, span_id) rides a
   contextvar, so nested spans parent correctly across the synchronous
   call tree; explicit `attach()` / `span_in()` / `emit()` carry the
@@ -32,12 +45,14 @@ Design rules (the EventTracker discipline, applied to spans):
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import secrets
+import sys
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from contextvars import ContextVar
 from dataclasses import dataclass, field
 
@@ -152,6 +167,8 @@ def _register(trace_id: str, root_name: str) -> TraceRecord:
 
 def _record(trace_id: str, span: Span) -> None:
     global dropped_spans
+    if _gc_pending:
+        flush_gc()
     # every completed span ALSO lands in the windowed histogram for its
     # name, carrying its trace id as the exemplar — the one wiring point
     # that gives every traced wall (servlet roots, StageTimer bridge
@@ -176,10 +193,10 @@ def _record(trace_id: str, span: Span) -> None:
 # -- context -----------------------------------------------------------------
 
 # trace id of the most recent ROOT span completed on this context: lets
-# a caller that wraps traced work (httpd's servlet dispatch wall) stamp
-# its histogram exemplar with the request's trace even though the trace
-# closed inside the callee.  Per-context (thread-per-request), cleared
-# by the wrapper before dispatch.
+# a caller that wraps traced work (httpd's servlet dispatch wall, see
+# `envelope`) join the request's trace and stamp its histogram exemplar
+# even though the trace closed inside the callee.  Per-context
+# (thread-per-request), cleared by the envelope on entry.
 _last_root: ContextVar = ContextVar("yacy_last_root_trace", default=None)
 
 # root-completion hooks (ISSUE 15): the tail-attribution engine
@@ -208,15 +225,6 @@ def _fire_root_hooks(tid: str, name: str, dur_ms: float) -> None:
                 "root hook failed for %s", name, exc_info=True)
 
 
-def last_trace_id() -> str | None:
-    """Trace id of the most recent root span completed on this context."""
-    return _last_root.get()
-
-
-def clear_last_trace_id() -> None:
-    _last_root.set(None)
-
-
 def current() -> tuple[str, str] | None:
     """The active (trace_id, span_id), or None."""
     return _ctx.get()
@@ -237,6 +245,48 @@ def detach(token) -> None:
     _ctx.reset(token)
 
 
+# -- the profiler's clock ------------------------------------------------------
+
+_TraceMe = None     # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _recording():
+    """The annotation class while a profiler session records, else None
+    (then nothing is allocated). JAX is never imported from here: the
+    jax-free children (crash tests, clients) stay jax-free."""
+    global _TraceMe
+    tm = _TraceMe
+    if tm is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as tm
+        except ImportError:         # JAX is mid-import on another thread
+            return None
+        _TraceMe = tm
+    return tm if tm.is_enabled() else None
+
+
+def _annotate(name: str):
+    """An ENTERED annotation for a span to close on its way out, or
+    None while no session records."""
+    tm = _recording()
+    if tm is None:
+        return None
+    ann = tm(name)
+    ann.__enter__()
+    return ann
+
+
+def annotation(name: str, **attrs):
+    """A block on the profiler's timeline and nowhere else: for work
+    whose wall a submitter re-emits as a span later (the batcher's
+    former, dispatchers and completers carry no trace of their own).
+    `attrs` become the event's stats."""
+    tm = _recording()
+    return _NOOP if tm is None else tm(name, **attrs)
+
+
 # -- span context managers ---------------------------------------------------
 
 class _NoopSpan:
@@ -254,13 +304,16 @@ class _NoopSpan:
     def set(self, **attrs) -> None:
         pass
 
+    def rename(self, name: str) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
 
 class _LiveSpan:
     __slots__ = ("_tid", "_sid", "_parent", "_name", "_attrs",
-                 "_t0", "_ts", "_token", "_root", "_end_trace")
+                 "_t0", "_ts", "_token", "_root", "_end_trace", "_ann")
 
     def __init__(self, tid: str, parent: str, name: str, attrs: dict,
                  root: bool = False, end_trace: bool = False):
@@ -273,6 +326,7 @@ class _LiveSpan:
         self._end_trace = end_trace
 
     def __enter__(self):
+        self._ann = _annotate(self._name)
         self._ts = time.time()
         self._t0 = time.perf_counter()
         self._token = _ctx.set((self._tid, self._sid))
@@ -283,6 +337,8 @@ class _LiveSpan:
         if etype is not None:
             self._attrs["error"] = etype.__name__
         dur_ms = (time.perf_counter() - self._t0) * 1000.0
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
         _record(self._tid, Span(
             self._sid, self._parent, self._name, self._ts,
             dur_ms, self._attrs))
@@ -299,9 +355,43 @@ class _LiveSpan:
     def set(self, **attrs) -> None:
         self._attrs.update(attrs)
 
+    def rename(self, name: str) -> None:
+        """Name the span by an outcome known only inside it (the route
+        a search took). The profiler's annotation keeps the name it was
+        opened under."""
+        self._name = name
+
     @property
     def ctx(self) -> tuple[str, str]:
         return (self._tid, self._sid)
+
+
+class _Timed:
+    """`timed()` outside a trace: the wall still reaches its family and
+    the profiler's timeline; no span, no context."""
+
+    __slots__ = ("_name", "_t0", "_ann")
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __enter__(self):
+        self._ann = _annotate(self._name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        dur_ms = (time.perf_counter() - self._t0) * 1000.0
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
+        histogram.observe(self._name, dur_ms)
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def rename(self, name: str) -> None:
+        self._name = name
 
 
 def trace(name: str, trace_id: str | None = None, **attrs):
@@ -337,6 +427,18 @@ def span_in(ctx: tuple[str, str] | None, name: str, **attrs):
     if not _enabled or ctx is None:
         return _NOOP
     return _LiveSpan(ctx[0], ctx[1], name, attrs)
+
+
+def timed(name: str, ctx: tuple[str, str] | None = None, **attrs):
+    """A wall that is ALWAYS measured: a child span under `ctx` or the
+    active trace, and outside any trace (or with tracing disabled) a
+    timer that still feeds the family `name` — the one call for a stage
+    whose distribution must cover the whole workload (StageTimer, the
+    batcher's submit wait, the page, the route)."""
+    c = ctx if ctx is not None else _ctx.get()
+    if not _enabled or c is None:
+        return _Timed(name)
+    return _LiveSpan(c[0], c[1], name, attrs)
 
 
 def attached(ctx: tuple[str, str] | None):
@@ -376,17 +478,142 @@ def remote_trace(trace_id: str, name: str, **attrs):
 def emit(name: str, dur_ms: float, ctx: tuple[str, str] | None = None,
          ts: float | None = None, **attrs) -> None:
     """Record an already-measured wall as a completed span — the bridge
-    for timings taken elsewhere (the roofline profiler's kernel walls,
-    the batcher's per-dispatch walls). Uses the active context unless an
+    for timings taken elsewhere (the roofline profiler's kernel walls)
+    and for zero-length markers. Uses the active context unless an
     explicit one is given; silently a no-op outside any trace."""
-    if not _enabled:
-        return
+    if _enabled and (ctx is not None or _ctx.get() is not None):
+        record(name, dur_ms, ctx, ts, **attrs)
+
+
+def record(name: str, dur_ms: float, ctx: tuple[str, str] | None = None,
+           ts: float | None = None, sid: str | None = None,
+           **attrs) -> None:
+    """An already-measured wall, ALWAYS into its family: as a completed
+    span under `ctx` or the active trace (the span record feeds the
+    family, exemplar included), else straight into the family — the
+    after-the-fact twin of `timed()` (the batcher's stamps a submitter
+    re-emits, the route of a cache hit, the servlet's wall)."""
     c = ctx if ctx is not None else _ctx.get()
-    if c is None:
+    if not _enabled or c is None:
+        histogram.observe(name, dur_ms)
         return
     if ts is None:
         ts = time.time() - dur_ms / 1000.0
-    _record(c[0], Span(_new_sid(), c[1], name, ts, dur_ms, attrs))
+    _record(c[0], Span(sid or _new_sid(), c[1], name, ts, dur_ms, attrs))
+
+
+class _Envelope:
+    """The wall AROUND a request whose trace is rooted beneath it (see
+    `envelope`)."""
+
+    __slots__ = ("_name", "_cpu", "_sid", "_ts", "_t0", "_c0", "_ann")
+
+    def __init__(self, name: str, cpu_family: str):
+        self._name = name
+        self._cpu = cpu_family
+        self._sid = _new_sid()
+
+    def __enter__(self):
+        _last_root.set(None)
+        self._ann = _annotate(self._name)
+        self._ts = time.time()
+        self._c0 = time.thread_time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        dur_ms = (time.perf_counter() - self._t0) * 1000.0
+        cpu_ms = (time.thread_time() - self._c0) * 1000.0
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
+        tid = _last_root.get()
+        histogram.observe(self._cpu, cpu_ms, tid)
+        record(self._name, dur_ms, (tid, "") if tid else None, self._ts,
+               self._sid, cpu_ms=round(cpu_ms, 3))
+        return False
+
+    @property
+    def ctx(self) -> tuple[str, str] | None:
+        """Parent context for work done inside the envelope AFTER the
+        trace beneath it closed (the template render); None until a
+        root has completed on this context."""
+        tid = _last_root.get()
+        return (tid, self._sid) if tid else None
+
+
+def envelope(name: str, cpu_family: str):
+    """A wall measured by a caller that cannot know whether the callee
+    will root a trace (httpd around any servlet): always into the family
+    `name`, with the thread's CPU time (`time.thread_time()`) of the same
+    interval into `cpu_family` — wall less CPU is what the thread spent
+    waiting: for the interpreter lock, a lock, the device. When a root
+    span completed inside, the wall also joins that trace as a
+    parentless span (like a remote segment's root) and carries its id as
+    the exemplar, so a slow bucket links to the waterfall."""
+    return _Envelope(name, cpu_family)
+
+
+# -- the collector ------------------------------------------------------------
+
+GC_FAMILY = "runtime.gc"
+GC_SPAN_MIN_MS = 1.0
+_gc_t0 = 0.0
+_gc_ann = None
+# (ms, interrupted context, start, generation, collected) of collections
+# not yet in the family; bounded, so a process that closes no span loses
+# the oldest
+_gc_pending: deque = deque(maxlen=4096)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook. The collector runs on whichever thread
+    tripped the threshold, between two of its bytecodes and with the
+    interpreter lock held: the pause is that thread's, and every other
+    thread's wait. The thread may be INSIDE one of this module's or a
+    histogram's locked sections, so the hook takes no lock: it stamps
+    two clock reads and queues the observation; `flush_gc` (the next
+    span to close, the health tick, a reader) files it."""
+    global _gc_t0, _gc_ann
+    if phase == "start":
+        _gc_ann = _annotate(GC_FAMILY)
+        _gc_t0 = time.perf_counter()
+        return
+    dur_ms = (time.perf_counter() - _gc_t0) * 1000.0
+    if _gc_ann is not None:
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+    if dur_ms >= GC_SPAN_MIN_MS:
+        _gc_pending.append((dur_ms, _ctx.get(),
+                            time.time() - dur_ms / 1000.0,
+                            info.get("generation", 0),
+                            info.get("collected", 0)))
+    else:
+        _gc_pending.append((dur_ms, None, 0.0, 0, 0))
+
+
+def flush_gc() -> None:
+    """File the queued collections: every one an observation of
+    `runtime.gc`, one over GC_SPAN_MIN_MS that interrupted a trace also
+    a span of it (so a young collection costs two clock reads, an
+    append and, here, a bucket increment)."""
+    while _gc_pending:
+        try:
+            dur_ms, ctx, ts, gen, collected = _gc_pending.popleft()
+        except IndexError:
+            return
+        if ctx is None:
+            histogram.observe(GC_FAMILY, dur_ms)
+        else:
+            record(GC_FAMILY, dur_ms, ctx, ts, generation=gen,
+                   collected=collected)
+
+
+def watch_gc(on: bool = True) -> None:
+    """Install (or remove) the collector hook; idempotent."""
+    if on and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not on and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 # -- pipeline (begin/end across async stages) --------------------------------
