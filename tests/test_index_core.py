@@ -378,3 +378,262 @@ class TestMalformedUrls:
     def test_url2hash_malformed(self):
         assert len(url2hash("http://[broken")) == 12
         assert len(url2hash("http://example.com:bad/x")) == 12
+
+
+# -- the host conjunction reads what it needs (ISSUE 26) ----------------------
+# term_search fetches its driving list and PROBES the far longer ones; the
+# oracle is what it did before: join_constructive over rwi.get of every term
+
+_UNIVERSE = 40_000
+
+
+def _th(name: str) -> bytes:
+    return name.encode("ascii").ljust(12, b"_")
+
+
+def _drawn(rng, n: int, among=None) -> PostingsList:
+    """n sorted docids (of `among`, else of the universe) under random rows."""
+    pool = _UNIVERSE if among is None else among
+    d = np.sort(rng.choice(pool, n, replace=False)).astype(np.int32)
+    return PostingsList(d, rng.integers(0, 1 << 20, (n, P.NF)).astype(np.int32))
+
+
+def _holding(rng, n: int, core: np.ndarray) -> PostingsList:
+    """n random docids and, besides, every docid of `core`."""
+    rows = rng.integers(0, 1 << 20, (len(core), P.NF)).astype(np.int32)
+    return merge([_drawn(rng, n), PostingsList(core, rows)])
+
+
+def _oracle_term_search(seg, inc, exc):
+    lists = [seg.rwi.get(th) for th in inc]
+    if any(len(c) == 0 for c in lists):
+        return PostingsList.empty()
+    joined = join_constructive(lists)
+    for th in exc:
+        ex = seg.rwi.get(th)
+        if len(joined) and len(ex):
+            joined = exclude_destructive(joined, ex)
+    return joined
+
+
+def _shape_two(seg, rng):
+    seg.rwi.ingest_run({_th("long"): _drawn(rng, 20_000)})
+    seg.rwi.ingest_run({_th("short"): _drawn(rng, 200)})
+    return [_th("long"), _th("short")], [], "probe"
+
+
+def _shape_three(seg, rng):
+    core = _drawn(rng, 100).docids
+    for name in ("long1", "long2"):
+        seg.rwi.ingest_run({_th(name): _holding(rng, 20_000, core)})
+    seg.rwi.ingest_run({_th("short"): _holding(rng, 300, core)})
+    return [_th("long1"), _th("short"), _th("long2")], [], "probe"
+
+
+def _shape_six(seg, rng):
+    # two short lists of a size and a middling one are merged, three long
+    # ones probed: the base is the shorter of the two short lists
+    sizes = {"long1": 20_000, "short_b": 250, "mid": 1_000, "long2": 30_000,
+             "short_a": 200, "long3": 25_000}
+    core = _drawn(rng, 120).docids
+    for name, n in sizes.items():
+        seg.rwi.ingest_run({_th(name): _holding(rng, n - 120, core)})
+    return [_th(name) for name in sizes], [], "probe"
+
+
+def _shape_exclusion(seg, rng):
+    for name, n in (("long1", 20_000), ("long2", 20_000), ("short", 300),
+                    ("short2", 300)):
+        seg.rwi.ingest_run({_th(name): _drawn(rng, n)})
+    return [_th("short"), _th("long1")], [_th("long2"), _th("short2"),
+                                          _th("absent")], "probe"
+
+
+def _shape_generations(seg, rng):
+    # the long term in three generations; the later ones override rows of
+    # the earlier and add their own
+    first = _drawn(rng, 20_000)
+    seg.rwi.ingest_run({_th("long"): first})
+    seg.rwi.ingest_run({_th("short"): _drawn(rng, 300, among=first.docids)})
+    for _ in range(2):
+        over = _drawn(rng, 3_000, among=first.docids)
+        new = _drawn(rng, 2_000)
+        seg.rwi.ingest_run({_th("long"): merge([new, over])})
+    return [_th("short"), _th("long")], [], "probe"
+
+
+def _shape_ram_delta(seg, rng):
+    first = _drawn(rng, 20_000)
+    seg.rwi.ingest_run({_th("long"): first})
+    short = _drawn(rng, 300, among=first.docids)
+    seg.rwi.ingest_run({_th("short"): short})
+    # RAM rows over the runs: overriding rows of both terms, one docid
+    # written twice (the last wins), and docids the runs do not hold
+    seg.rwi.add_many(_th("long"), _drawn(rng, 150, among=short.docids))
+    seg.rwi.add_many(_th("long"), _drawn(rng, 150, among=short.docids))
+    seg.rwi.add_many(_th("long"), _drawn(rng, 500))
+    seg.rwi.add_many(_th("short"), _drawn(rng, 40, among=first.docids))
+    return [_th("long"), _th("short")], [], "probe"
+
+
+def _shape_tombstones_short(seg, rng):
+    long = _drawn(rng, 20_000)
+    short = _drawn(rng, 300, among=long.docids)
+    seg.rwi.ingest_run({_th("long"): long})
+    seg.rwi.ingest_run({_th("short"): short})
+    for d in short.docids[::7].tolist():
+        seg.rwi.delete_doc(d)
+    return [_th("short"), _th("long")], [], "probe"
+
+
+def _shape_tombstones_long(seg, rng):
+    long = _drawn(rng, 20_000)
+    short = _drawn(rng, 300)
+    seg.rwi.ingest_run({_th("long"): long})
+    seg.rwi.ingest_run({_th("short"): short})
+    for d in long.docids[::150].tolist():    # rows of the long list alone
+        seg.rwi.delete_doc(d)
+    return [_th("short"), _th("long")], [], "probe"
+
+
+def _shape_long_tombstoned_short(seg, rng):
+    # the list that is longer by its extents is the SHORTER one once the
+    # tombstones are applied: the bounds cannot say, the old path decides
+    a = _drawn(rng, 3_000)
+    b = _drawn(rng, 300)
+    seg.rwi.ingest_run({_th("a"): a})
+    seg.rwi.ingest_run({_th("b"): b})
+    alive = set(a.docids[::20].tolist()) | set(b.docids.tolist())
+    for d in a.docids.tolist():
+        if d not in alive:
+            seg.rwi.delete_doc(d)
+    return [_th("a"), _th("b")], [], "merge"
+
+
+def _shape_absent(seg, rng):
+    seg.rwi.ingest_run({_th("long"): _drawn(rng, 20_000)})
+    seg.rwi.ingest_run({_th("short"): _drawn(rng, 200)})
+    return [_th("short"), _th("nowhere"), _th("long")], [], "merge"
+
+
+def _shape_equal(seg, rng):
+    # lists of one length: the first in the query is the base
+    first = _drawn(rng, 2_000)
+    seg.rwi.ingest_run({_th("one"): first})
+    seg.rwi.ingest_run({_th("two"): _drawn(rng, 2_000, among=first.docids)})
+    return [_th("two"), _th("one")], [], "merge"
+
+
+def _shape_extents_mislead(seg, rng):
+    # `b` has the larger extents (three generations of the same 40 docids)
+    # and the SHORTER list: the rows must come from it
+    a = _drawn(rng, 100)
+    seg.rwi.ingest_run({_th("a"): a})
+    among = a.docids[:60]
+    for _ in range(3):
+        seg.rwi.ingest_run({_th("b"): PostingsList(
+            among[:40].copy(),
+            rng.integers(0, 1 << 20, (40, P.NF)).astype(np.int32))})
+    return [_th("a"), _th("b")], [], "merge"
+
+
+def _shape_same_term_twice(seg, rng):
+    seg.rwi.ingest_run({_th("long"): _drawn(rng, 20_000)})
+    seg.rwi.ingest_run({_th("short"): _drawn(rng, 200)})
+    return [_th("short"), _th("long"), _th("short")], [], "probe"
+
+
+def _shape_resident(seg, rng):
+    # the long list already materialized (a paged index serves the probe
+    # from the TermCache's copy)
+    inc, exc, path = _shape_two(seg, rng)
+    seg.rwi.get(_th("long"))
+    return inc, exc, path
+
+
+_SHAPES = [_shape_two, _shape_three, _shape_six, _shape_exclusion,
+           _shape_generations, _shape_ram_delta, _shape_tombstones_short,
+           _shape_tombstones_long, _shape_long_tombstoned_short,
+           _shape_absent, _shape_equal, _shape_extents_mislead,
+           _shape_same_term_twice, _shape_resident]
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["ram", "paged"])
+@pytest.mark.parametrize("shape", _SHAPES,
+                         ids=[f.__name__[7:] for f in _SHAPES])
+def test_term_search_probe_equals_join_over_get(shape, paged, tmp_path):
+    seg = Segment(str(tmp_path / "seg") if paged else None)
+    try:
+        for seed in (1, 2):
+            rng = np.random.default_rng(seed)
+            inc, exc, path = shape(seg, rng)
+            for order in (inc, inc[::-1]):
+                how = {}
+                got = seg.term_search(include_hashes=order,
+                                      exclude_hashes=exc, how=how)
+                want = _oracle_term_search(seg, order, exc)
+                assert how["path"] == path
+                assert got.docids.dtype == want.docids.dtype == np.int32
+                assert got.feats.dtype == want.feats.dtype == np.int32
+                np.testing.assert_array_equal(got.docids, want.docids)
+                np.testing.assert_array_equal(got.feats, want.feats)
+                assert len(want) or shape is _shape_absent
+    finally:
+        seg.close()
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["ram", "paged"])
+@pytest.mark.parametrize("shape", _SHAPES,
+                         ids=[f.__name__[7:] for f in _SHAPES])
+def test_rwi_probe_equals_rows_of_get(shape, paged, tmp_path):
+    """rwi.probe at any docids (tombstoned ones and strangers among them)
+    says what rwi.get holds there, generation for generation."""
+    seg = Segment(str(tmp_path / "seg") if paged else None)
+    try:
+        rng = np.random.default_rng(3)
+        inc, exc, _ = shape(seg, rng)
+        at = np.sort(rng.choice(_UNIVERSE, 5_000, replace=False)
+                     ).astype(np.int32)
+        for th in set(inc + exc):
+            found, rows = seg.rwi.probe(th, at)
+            whole = seg.rwi.get(th)
+            np.testing.assert_array_equal(found, np.isin(at, whole.docids))
+            np.testing.assert_array_equal(
+                rows, whole.feats[np.isin(whole.docids, at)])
+            assert rows.dtype == np.int32 and rows.shape[1] == P.NF
+            only, none = seg.rwi.probe(th, at, want_feats=False)
+            assert none is None
+            np.testing.assert_array_equal(only, found)
+    finally:
+        seg.close()
+
+
+def test_term_search_at_cell_lengths_probes_and_caches_no_long_list(tmp_path):
+    """The benchmark cell's And HighLow: 2,048 rows against 524,288. The
+    long list is neither materialized nor put into the TermCache, and the
+    answer is the old path's."""
+    seg = Segment(str(tmp_path / "seg"))
+    try:
+        rng = np.random.default_rng(26)
+        high = PostingsList(
+            np.sort(rng.choice(2_500_000, 524_288, replace=False)
+                    ).astype(np.int32),
+            rng.integers(0, 1 << 20, (524_288, P.NF)).astype(np.int32))
+        low = _drawn(rng, 2_048, among=high.docids[::64])
+        low.docids[1::2] += 1       # half of them miss the long list
+        run = seg.rwi.ingest_run({_th("high"): high})
+        seg.rwi.ingest_run(
+            {_th("low"): P.sort_dedupe(low.docids, low.feats)})
+        how = {}
+        got = seg.term_search(include_hashes=[_th("high"), _th("low")],
+                              how=how)
+        assert how["path"] == "probe"
+        assert how["rows"] < 2 * 2_048
+        assert seg.rwi.term_cache.peek((run.path, _th("high"))) is None
+        assert seg.rwi.term_cache.resident_bytes < 1 << 20
+        want = _oracle_term_search(seg, [_th("high"), _th("low")], [])
+        assert 512 <= len(want) <= 2_048
+        np.testing.assert_array_equal(got.docids, want.docids)
+        np.testing.assert_array_equal(got.feats, want.feats)
+    finally:
+        seg.close()

@@ -175,6 +175,128 @@ def test_rwi_quarantines_corrupt_run_and_serves_survivors(tmp_path):
                           np.sort(survivors.docids[survivors.docids >= 100]))
 
 
+# -- the probe path (ISSUE 26): a span reached only through a conjunction ----
+
+def test_span_probe_detects_flipped_bytes(tmp_path):
+    path, run = _write_run(tmp_path)
+    run.close()
+    _flip_dat_bytes(path)
+    run = PagedRun.open(path)
+    with pytest.raises(CorruptRunError, match="span checksum"):
+        run.probe(b"term00000000", np.arange(5, dtype=np.int32))
+    assert integrity.corruption_counts()[("run", "error")] == 1
+
+
+def test_span_probe_verify_off_serves_unchecked(tmp_path):
+    path, run = _write_run(tmp_path)
+    run.close()
+    _flip_dat_bytes(path)
+    integrity.set_verify_on_read(False)
+    run = PagedRun.open(path)
+    found, rows = run.probe(b"term00000000", np.arange(5, dtype=np.int32))
+    assert len(rows) == int(found.sum()) > 0      # no claim made
+    assert integrity.verified_total() == 0
+
+
+def test_span_probe_verifies_once_per_open_run(tmp_path):
+    """A span is held to its crc before its first row is served and not
+    again while the run stays open; drop_term forgets it; a term the
+    TermCache holds is served from that (verified) copy."""
+    path, run = _write_run(tmp_path)
+    run.close()
+    cache = TermCache()
+    run = PagedRun.open(path, cache)
+    at = np.arange(0, 50, 5, dtype=np.int32)
+    base = integrity.verified_total()
+    run.probe(b"term00000000", at)
+    assert integrity.verified_total() == base + 1
+    for _ in range(3):
+        found, rows = run.probe(b"term00000000", at)
+        assert found.all() and rows.shape == (10, P.NF)
+    assert integrity.verified_total() == base + 1
+    run.probe(b"term00000001", at, want_feats=False)
+    assert integrity.verified_total() == base + 2
+    assert cache.resident_bytes == 0, "a probe materializes nothing"
+    # a flip AFTER the check goes unseen until the run is opened again:
+    # the granularity this path states (get() checks each materialization)
+    _flip_dat_bytes(path)
+    with pytest.raises(CorruptRunError):
+        PagedRun.open(path, cache).probe(b"term00000000", at)
+    run.drop_term(b"term00000001")
+    assert run.probe(b"term00000001", at) is None
+    assert b"term00000001" not in run._verified
+
+
+def _conjunction_over_two_generations(tmp_path):
+    """A long term in two paged generations and a short one in a third."""
+    from yacy_search_server_tpu.index.segment import Segment
+    seg = Segment(str(tmp_path / "seg"))
+    rng = np.random.default_rng(26)
+    long_th, short_th = b"longterm0000", b"shortterm000"
+    gen1 = PostingsList(np.arange(0, 8_000, dtype=np.int32),
+                        rng.integers(0, 100, (8_000, P.NF)).astype(np.int32))
+    gen2 = PostingsList(np.arange(4_000, 12_000, dtype=np.int32),
+                        rng.integers(0, 100, (8_000, P.NF)).astype(np.int32))
+    short = PostingsList(np.arange(0, 12_000, 100, dtype=np.int32),
+                         rng.integers(0, 100, (120, P.NF)).astype(np.int32))
+    run1 = seg.rwi.ingest_run({long_th: gen1})
+    seg.rwi.ingest_run({long_th: gen2})
+    seg.rwi.ingest_run({short_th: short})
+    return seg, run1, [long_th, short_th]
+
+
+def test_conjunction_quarantines_corrupt_probed_run(tmp_path):
+    """Flipped bytes in the long term's span, reached only by the probe
+    of a conjunction: never a crash, the run quarantines and is counted
+    exactly as through get(), the survivors answer."""
+    seg, run1, inc = _conjunction_over_two_generations(tmp_path)
+    try:
+        _flip_dat_bytes(run1.path)
+        how = {}
+        out = seg.term_search(include_hashes=inc, how=how)   # NOT an exception
+        assert how["path"] == "probe"
+        assert seg.rwi.run_count() == 2, "corrupt run must leave serving"
+        assert integrity.corruption_counts()[("run", "quarantined")] == 1
+        assert integrity.corruption_counts()[("run", "error")] == 1
+        # the surviving generation's rows of the long term still join
+        assert out.docids.tolist() == list(range(4_000, 12_000, 100))
+        # stable: the next conjunction answers identically, no second
+        # quarantine
+        again = seg.term_search(include_hashes=inc)
+        np.testing.assert_array_equal(out.docids, again.docids)
+        np.testing.assert_array_equal(out.feats, again.feats)
+        assert integrity.corruption_counts()[("run", "quarantined")] == 1
+    finally:
+        seg.close()
+
+
+def test_conjunction_checksums_a_probed_span_once(tmp_path):
+    seg, run1, inc = _conjunction_over_two_generations(tmp_path)
+    try:
+        base = integrity.verified_total()
+        first = seg.term_search(include_hashes=inc)
+        # the short list materialized (1) + two spans of the long term
+        assert integrity.verified_total() == base + 3
+        again = seg.term_search(include_hashes=inc)
+        assert integrity.verified_total() == base + 3
+        np.testing.assert_array_equal(first.feats, again.feats)
+        assert seg.rwi.term_cache.peek((run1.path, inc[0])) is None
+    finally:
+        seg.close()
+
+
+def test_conjunction_verify_off_serves_unchecked(tmp_path):
+    seg, run1, inc = _conjunction_over_two_generations(tmp_path)
+    try:
+        _flip_dat_bytes(run1.path)
+        integrity.set_verify_on_read(False)
+        out = seg.term_search(include_hashes=inc)
+        assert len(out) == 120 and seg.rwi.run_count() == 3
+        assert integrity.corruption_counts().get(("run", "error"), 0) == 0
+    finally:
+        seg.close()
+
+
 def test_rwi_open_quarantines_corrupt_run(tmp_path):
     """A run that fails open-scrub at startup quarantines instead of
     refusing to start the node."""

@@ -45,7 +45,7 @@ import numpy as np
 from ..utils import faultinject
 from . import integrity
 from .integrity import CorruptRunError
-from .postings import NF, PostingsList
+from .postings import NF, PostingsList, probe_rows
 
 _MAGIC = "PR2"
 _LEGACY_MAGICS = ("PR1",)   # round-2 format: no per-term checksums
@@ -86,6 +86,17 @@ class TermCache:
                 self.hits += 1
             else:
                 self.misses += 1
+            return p
+
+    def peek(self, key: tuple) -> PostingsList | None:
+        """The resident copy if there is one (a hit, recency refreshed);
+        absence counts no miss: the caller reads the map instead of
+        materializing (the probe path)."""
+        with self._lock:
+            p = self._map.get(key)
+            if p is not None:
+                self._map.move_to_end(key)
+                self.hits += 1
             return p
 
     def put(self, key: tuple, p: PostingsList) -> None:
@@ -136,6 +147,11 @@ class PagedRun:
         # per-term span checksums (crc32 over docid+feat row bytes);
         # empty for legacy PR1 files — no claim, no verification
         self._crcs = crcs or {}
+        # spans whose bytes matched their crc since this run was opened:
+        # a run is immutable, so the probe path checks a span once and
+        # then reads single rows off the map (the set dies with the run,
+        # a term's entry with drop_term)
+        self._verified: set[bytes] = set()
         # both memmaps published through ONE attribute: readers run
         # lock-free (rwi.get materializes spans outside the index lock),
         # so the pair must appear atomically — publishing docids and
@@ -291,22 +307,49 @@ class PagedRun:
         # TermCache hit re-serves verified rows with zero recompute).
         # Mismatch raises typed; the owning RWIIndex quarantines the run
         # and answers the term from surviving generations/RAM.
-        want = self._crcs.get(termhash)
-        if want is not None and integrity.VERIFY_ON_READ:
-            got = integrity.crc32(
-                np.ascontiguousarray(p.feats, dtype="<i4").tobytes(),
-                integrity.crc32(np.ascontiguousarray(
-                    p.docids, dtype="<i4").tobytes()))
-            if got != want:
-                integrity.note_corruption("run", "error")
-                raise CorruptRunError(
-                    f"span checksum mismatch for term "
-                    f"{termhash.decode('ascii', 'replace')} in "
-                    f"{self.path}")
-            integrity.note_verified()
+        self._verify_span(termhash, p.docids, p.feats)
         if self._cache is not None:
             self._cache.put(key, p)
         return p
+
+    def _verify_span(self, termhash: bytes, docids: np.ndarray,
+                     feats: np.ndarray) -> None:
+        """Hold a span's bytes (a copy, or the map's slices: the crc
+        streams over the buffer) to the crc its .tix line claims."""
+        want = self._crcs.get(termhash)
+        if want is None or not integrity.VERIFY_ON_READ:
+            return      # legacy file or verification off: no claim made
+        if len(docids) and integrity.crc_arrays(docids, feats) != want:
+            integrity.note_corruption("run", "error")
+            raise CorruptRunError(
+                f"span checksum mismatch for term "
+                f"{termhash.decode('ascii', 'replace')} in "
+                f"{self.path}")
+        integrity.note_verified()
+        self._verified.add(termhash)
+
+    def probe(self, termhash: bytes, docids: np.ndarray,
+              want_feats: bool = True):
+        """postings.probe_rows of `docids` against this run's span of the
+        term, or None if the run does not hold the term. Reads the
+        TermCache's copy where one is resident, else the map — after the
+        WHOLE span has passed its crc once since the run was opened: no
+        row or docid of an unverified span is served, as through get()."""
+        span = self._index.get(termhash)
+        if span is None:
+            return None
+        p = None
+        if self._cache is not None:
+            p = self._cache.peek((self.path, termhash))
+        if p is not None:
+            return probe_rows(p.docids, p.feats, docids, want_feats)
+        start, count = span
+        all_docids, all_feats = self._maps()
+        span_docids = all_docids[start:start + count]
+        span_feats = all_feats[start:start + count]
+        if termhash not in self._verified:
+            self._verify_span(termhash, span_docids, span_feats)
+        return probe_rows(span_docids, span_feats, docids, want_feats)
 
     def span(self, termhash: bytes) -> tuple[int, int] | None:
         """(start, count) rows of a term in the flat arrays (arena packing)."""
@@ -351,6 +394,7 @@ class PagedRun:
             return 0
         if self._cache is not None:
             self._cache.invalidate((self.path, termhash))
+        self._verified.discard(termhash)
         self.n_postings -= span[1]
         return span[1]
 

@@ -122,6 +122,23 @@ def merge(lists: list[PostingsList]) -> PostingsList:
     return sort_dedupe(d, f)
 
 
+def probe_rows(list_docids: np.ndarray, list_feats: np.ndarray,
+               docids: np.ndarray, want_feats: bool = True):
+    """Which of the sorted `docids` one term's list holds, and the feature
+    rows of those: (found mask over `docids`, rows aligned to
+    docids[found], or None when the membership alone is wanted). The list
+    may be a map's slice: a binary search and a gather touch only the
+    pages they land on, nothing is materialized."""
+    if len(list_docids) == 0:
+        found = np.zeros(len(docids), dtype=bool)
+        return found, (list_feats[:0] if want_feats else None)
+    idx = np.searchsorted(list_docids, docids)
+    found = np.take(list_docids, idx, mode="clip") == docids
+    if not want_feats:
+        return found, None
+    return found, np.take(list_feats, idx[found], axis=0)
+
+
 def remove_docids(p: PostingsList, dead: np.ndarray) -> PostingsList:
     """Drop postings whose docid is in the sorted `dead` array (tombstones)."""
     if len(p) == 0 or len(dead) == 0:

@@ -36,7 +36,8 @@ from . import integrity
 from .colstore import journal_append
 from .integrity import CorruptRunError
 from .pagedrun import PagedRun, TermCache
-from .postings import NF, PostingsList, merge, remove_docids, sort_dedupe
+from .postings import (NF, PostingsList, merge, probe_rows, remove_docids,
+                       sort_dedupe)
 from ..ingest import slo as ingest_slo
 from ..utils import faultinject, profiling
 from ..utils.eventtracker import EClass, update as track
@@ -66,8 +67,8 @@ class FrozenRun:
     Two roles: (a) the only run form for RAM-only indexes (no data_dir);
     (b) the transient form a fresh flush/merge serves from while its
     PagedRun file is being written outside the lock (then swapped out).
-    Shares the run interface with pagedrun.PagedRun: get/has/term_hashes/
-    drop_term/span/close.
+    Shares the run interface with pagedrun.PagedRun: get/probe/has/
+    term_hashes/drop_term/span/close.
     """
 
     def __init__(self, terms: dict[bytes, PostingsList], path: str | None = None,
@@ -80,6 +81,13 @@ class FrozenRun:
 
     def get(self, termhash: bytes) -> PostingsList | None:
         return self.terms.get(termhash)
+
+    def probe(self, termhash: bytes, docids: np.ndarray,
+              want_feats: bool = True):
+        p = self.terms.get(termhash)
+        if p is None:
+            return None
+        return probe_rows(p.docids, p.feats, docids, want_feats)
 
     def has(self, termhash: bytes) -> bool:
         return termhash in self.terms
@@ -690,32 +698,100 @@ class RWIIndex:
             out = remove_docids(out, dead)
         return out
 
+    def probe(self, termhash: bytes, docids: np.ndarray,
+              want_feats: bool = True):
+        """The rows get(termhash) holds AT the sorted int32 `docids`,
+        without materializing the term: (found mask over `docids`,
+        feature rows aligned to docids[found], or None when
+        `want_feats` is false and the membership alone is wanted).
+
+        Walks the generations a get() would merge, newest first, and
+        the first generation that holds a docid answers for it (RAM
+        beats runs, a later run an earlier one: merge()'s last-wins);
+        tombstoned docids are in no answer. A paged run is read through
+        PagedRun.probe, which verifies a span before its first row is
+        served; a mismatch quarantines the run exactly as in get(), and
+        the surviving generations answer."""
+        docids = np.asarray(docids, dtype=np.int32)
+        with self._lock:
+            runs = list(self._runs)
+            ram = self._ram_postings(termhash)
+            dead = self._dead_sorted() if self._tombstones else None
+        generations = runs[::-1]
+        if ram is not None:
+            generations.insert(0, FrozenRun({termhash: ram}))
+        holders = [run for run in generations if run.has(termhash)]
+
+        def ask(run, at):
+            try:
+                return run.probe(termhash, at, want_feats)
+            except CorruptRunError as e:
+                self._quarantine_run(run, e)
+                return None
+
+        if dead is None and len(holders) == 1:
+            # the usual case, one generation and no tombstone: its answer
+            # is the answer, with no bookkeeping between the array calls
+            # (each of which hands the interpreter lock to another thread)
+            got = ask(holders.pop(), docids)
+            if got is not None:
+                return got
+        found = np.zeros(len(docids), dtype=bool)
+        feats = np.empty((len(docids), NF), np.int32) if want_feats else None
+        todo = np.arange(len(docids))     # positions no generation holds yet
+        if dead is not None:
+            todo = todo[~probe_rows(dead, None, docids, False)[0]]
+        for run in holders:
+            if len(todo) == 0:
+                break
+            got = ask(run, docids[todo])
+            if got is None:
+                continue
+            hit, rows = got
+            found[todo[hit]] = True
+            if want_feats:
+                feats[todo[hit]] = rows
+            todo = todo[~hit]
+        return found, (feats[found] if want_feats else None)
+
     def count(self, termhash: bytes) -> int:
         """Posting count (the queryRWICount RPC answer); tombstones applied."""
         return len(self.get(termhash))
 
-    def count_upper(self, termhash: bytes) -> int:
-        """Cheap upper bound on a term's posting count: per-run span
+    def count_bounds(self, termhash: bytes) -> tuple[int, int]:
+        """Cheap (lower, upper) bounds on len(get(termhash)): per-run span
         extents + RAM buffer length, NO postings materialization and no
-        tombstone filtering. Gate decisions (device vs host path) only
-        need the magnitude."""
+        tombstone filtering. Upper: every generation's rows (a docid in
+        two generations counts twice). Lower: the merged list holds at
+        least its largest run's rows, less at most one row a tombstone
+        (no finer: ingest_run does not hold its rows to the tombstones
+        its dead_seq claims folded in)."""
         with self._lock:
-            total = 0
+            upper = largest = 0
             ram = self._ram.get(termhash)
             if ram is not None:
-                total += len(ram)
+                upper += len(ram)
             for run in list(self._runs):
                 sp = run.span(termhash)
                 if sp is not None:
-                    total += sp[1]
+                    n = sp[1]
                 elif run.has(termhash):
                     try:
                         p = run.get(termhash)
                     except CorruptRunError as e:
                         self._quarantine_run(run, e)
                         continue
-                    total += len(p) if p is not None else 0
-            return total
+                    n = len(p) if p is not None else 0
+                else:
+                    continue
+                upper += n
+                largest = max(largest, n)
+            return max(0, largest - len(self._tombstones)), upper
+
+    def count_upper(self, termhash: bytes) -> int:
+        """count_bounds' upper bound. Gate decisions (device vs host
+        path) only need the magnitude."""
+        return self.count_bounds(termhash)[1]
 
     def has_term(self, termhash: bytes) -> bool:
         with self._lock:

@@ -40,6 +40,14 @@ from .metadata import DocumentMetadata, MetadataStore, metadata_from_parsed
 from .postings import PostingsList
 from .rwi import RWIIndex
 
+# a conjunction probes a term's list at the docids that survive so far,
+# instead of fetching it whole, when the list is at least this many times
+# as long as the driving list; nearer in size it fetches and merges them.
+# Read off a measurement (CHANGES.md, PR 26): from here up a probe loses
+# little to a linear merge even where the long list is already resident,
+# and wins several times that where it would have to be materialized
+PROBE_MIN_RATIO = 16
+
 # private-range catchall term: every document is indexed under it so a
 # peer can enumerate/count its whole index (reference: Segment.java:766-768
 # catchall term insert)
@@ -367,26 +375,80 @@ class Segment:
     def term_search(self, include_words: list[str] | None = None,
                     exclude_words: list[str] | None = None,
                     include_hashes: list[bytes] | None = None,
-                    exclude_hashes: list[bytes] | None = None) -> PostingsList:
-        """Conjunctive multi-term search with exclusion (TermSearch parity)."""
+                    exclude_hashes: list[bytes] | None = None,
+                    how: dict | None = None) -> PostingsList:
+        """Conjunctive multi-term search with exclusion (TermSearch parity).
+
+        Reads what the join needs: the shortest include term is fetched
+        whole and DRIVES; a term whose list is at least PROBE_MIN_RATIO
+        times as long is probed at the surviving docids (rwi.probe)
+        instead of being fetched, merged and cached whole; lists of a
+        size are fetched and merged as before. The answer is
+        join_constructive's over rwi.get of every term, bit for bit: a
+        term is probed only where the cheap bounds (rwi.count_bounds)
+        prove it longer than the list the rows are taken from. `how`,
+        if given, receives the path taken (`single` term, `probe`,
+        `merge`) and the posting rows read."""
         inc = list(include_hashes or []) + [word2hash(w) for w in (include_words or [])]
         exc = list(exclude_hashes or []) + [word2hash(w) for w in (exclude_words or [])]
+        if how is None:
+            how = {}
+        how.update(path="single" if len(inc) == 1 else "merge", rows=0)
         if not inc:
             return PostingsList.empty()
+        rwi = self.rwi
 
-        containers = [self.rwi.get(th) for th in inc]
-        # all-or-nothing subset rule (TermSearch.java:56-58): a conjunction
-        # missing any term yields nothing
+        def probe_of(th, want_feats=True):
+            how["path"] = "probe"       # one probed term names the path
+
+            def probe(docids):
+                found, rows = rwi.probe(th, docids, want_feats)
+                how["rows"] += int(found.sum())
+                return found, rows
+            return probe
+
+        containers, probes = [], []
+        if len(inc) == 1:
+            containers.append(rwi.get(inc[0]))
+        else:
+            bounds = [rwi.count_bounds(th) for th in inc]
+            # all-or-nothing subset rule (TermSearch.java:56-58): a
+            # conjunction missing any term yields nothing
+            if any(upper == 0 for _, upper in bounds):
+                return PostingsList.empty()
+            drive = min(range(len(inc)), key=lambda i: bounds[i][1])
+            driving = rwi.get(inc[drive])
+            if len(driving) == 0:
+                return PostingsList.empty()
+            reach = PROBE_MIN_RATIO * len(driving)
+            # containers stay in query order: among lists of one length
+            # the first is the base
+            for i, th in enumerate(inc):
+                if i == drive:
+                    containers.append(driving)
+                elif bounds[i][0] >= reach:
+                    probes.append(probe_of(th))
+                else:
+                    containers.append(rwi.get(th))
+        how["rows"] += sum(len(c) for c in containers)
         if any(len(c) == 0 for c in containers):
             return PostingsList.empty()
 
-        joined = join_constructive(containers)
-        if len(joined) == 0:
-            return joined
+        joined = join_constructive(containers, probes)
         for th in exc:
-            ex = self.rwi.get(th)
-            if len(ex):
-                joined = exclude_destructive(joined, ex)
+            if len(joined) == 0:
+                break
+            lower, upper = rwi.count_bounds(th)
+            if upper == 0:
+                continue
+            if lower >= PROBE_MIN_RATIO * len(joined):
+                found, _ = probe_of(th, want_feats=False)(joined.docids)
+                joined = joined.select(~found)
+            else:
+                ex = rwi.get(th)
+                how["rows"] += len(ex)
+                if len(ex):
+                    joined = exclude_destructive(joined, ex)
         return joined
 
     def get_metadata(self, docid: int) -> DocumentMetadata | None:
@@ -410,20 +472,28 @@ class Segment:
         self.dense.close()
 
 
-def join_constructive(containers: list[PostingsList]) -> PostingsList:
+def join_constructive(containers: list[PostingsList],
+                      probes=()) -> PostingsList:
     """Intersect sorted postings on docid; vectorized join.
 
     Replaces the reference's size-adaptive hash-probe/merge join
-    (ReferenceContainer.java:397-489) with numpy set intersection: the
-    size-adaptivity lives inside np.intersect1d. Joined feature rows come
-    from the rarest term's postings; worddistance (P.F_WORDDISTANCE) is set
-    to the span of first-appearance positions of the query words, matching
-    the reference's accumulated position-distance semantics
-    (WordReferenceVars.join); hitcount is the minimum over the terms.
+    (ReferenceContainer.java:397-489): the materialized `containers`
+    are intersected by a linear two-pointer merge (utils/native, else
+    np.intersect1d), and each of `probes` — a callable(docids) ->
+    (found mask, feature rows of the found), Segment.term_search's
+    stand-in for a list far longer than the survivors — narrows them by
+    binary search without its list being read whole. Which of the two a
+    term gets is term_search's choice, from the lists' lengths. Joined
+    feature rows come from the rarest term's postings (the first of the
+    shortest containers; a probed term is never shorter); worddistance
+    (P.F_WORDDISTANCE) is set to the span of first-appearance positions
+    of the query words, matching the reference's accumulated
+    position-distance semantics (WordReferenceVars.join); hitcount is
+    the minimum over the terms, the flags are OR-ed.
     """
     if not containers:
         return PostingsList.empty()
-    if len(containers) == 1:
+    if len(containers) == 1 and not probes:
         return containers[0]
     containers = sorted(containers, key=len)
     base = containers[0]
@@ -438,22 +508,31 @@ def join_constructive(containers: list[PostingsList]) -> PostingsList:
         if len(common) == 0:
             return PostingsList.empty()
 
-    idx0 = np.searchsorted(base.docids, common)
-    feats = base.feats[idx0].copy()
+    if common is base.docids:       # nothing merged yet: probes only
+        feats = base.feats.copy()
+    else:
+        feats = base.feats[np.searchsorted(base.docids, common)]
     pos_min = feats[:, P.F_POSINTEXT].copy()
     pos_max = feats[:, P.F_POSINTEXT].copy()
-    hit_min = feats[:, P.F_HITCOUNT].copy()
-    flags = feats[:, P.F_FLAGS].copy()
-    for c in containers[1:]:
-        idx = np.searchsorted(c.docids, common)
-        other = c.feats[idx]
+
+    def fold(other):
         np.minimum(pos_min, other[:, P.F_POSINTEXT], out=pos_min)
         np.maximum(pos_max, other[:, P.F_POSINTEXT], out=pos_max)
-        np.minimum(hit_min, other[:, P.F_HITCOUNT], out=hit_min)
-        flags |= other[:, P.F_FLAGS]
+        np.minimum(feats[:, P.F_HITCOUNT], other[:, P.F_HITCOUNT],
+                   out=feats[:, P.F_HITCOUNT])
+        feats[:, P.F_FLAGS] |= other[:, P.F_FLAGS]
+
+    for c in containers[1:]:
+        fold(c.feats[np.searchsorted(c.docids, common)])
+    for probe in probes:
+        found, other = probe(common)
+        if not found.all():
+            common, feats = common[found], feats[found]
+            pos_min, pos_max = pos_min[found], pos_max[found]
+            if len(common) == 0:
+                return PostingsList.empty()
+        fold(other)
     feats[:, P.F_WORDDISTANCE] = pos_max - pos_min
-    feats[:, P.F_HITCOUNT] = hit_min
-    feats[:, P.F_FLAGS] = flags
     return PostingsList(common.astype(np.int32), feats)
 
 

@@ -246,10 +246,18 @@ class SearchEvent:
                                                     allow_put=True)
             return scores, docids
 
-        with StageTimer(EClass.SEARCH, "JOIN"):
+        # the stage says how the conjunction was read (`single` term,
+        # long lists probed from the short one, or lists of a size
+        # merged) and how many posting rows that took; each path is a
+        # stage counter of its own (yacy_stage_events_total JOIN_<PATH>)
+        how: dict = {}
+        with StageTimer(EClass.SEARCH, "JOIN") as stage:
             joined = self.segment.term_search(
                 include_hashes=q.goal.include_hashes or None,
-                exclude_hashes=q.goal.exclude_hashes or None)
+                exclude_hashes=q.goal.exclude_hashes or None, how=how)
+            stage.count = how["rows"]
+            stage.set(path=how["path"], rows=how["rows"])
+        track(EClass.SEARCH, "JOIN_" + how["path"].upper(), how["rows"])
         self.local_rwi_considered = len(joined)
         if len(joined) == 0:
             return None
@@ -444,7 +452,9 @@ class SearchEvent:
         # tiny candidate sets: the host path scores them in microseconds
         # (ops/ranking.SMALL_RANK_N numpy twin); a device dispatch and
         # its round trip would dominate.
-        # A conjunction's join size is bounded by its RAREST term.
+        # A conjunction's join size is bounded by its RAREST term, and so
+        # is what the host join READS: Segment.term_search fetches that
+        # list and probes the long ones at its docids.
         from ..ops.ranking import SMALL_RANK_N
         # store-overridable threshold: a mesh dryrun (or a locally
         # attached device with a ~0 dispatch floor) may lower it

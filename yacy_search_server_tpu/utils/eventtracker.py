@@ -103,6 +103,10 @@ class StageTimer:
         self._t0 = time.monotonic()
         return self
 
+    def set(self, **attrs) -> None:
+        """Attributes for the stage's span, known only inside it."""
+        self._span.set(**attrs)
+
     def __exit__(self, *exc):
         ms = (time.monotonic() - self._t0) * 1000.0
         update(self.eclass, self.label, self.count, ms)
