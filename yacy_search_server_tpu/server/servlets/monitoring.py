@@ -474,7 +474,8 @@ def prometheus_text(sb, include_buckets: bool = True,
     p.family("yacy_device_serving_total", "counter",
              "device store serving counters")
     for key in ("queries_served", "fallbacks", "stream_scans",
-                "filtered_served", "join_served", "join_fallbacks",
+                "filtered_served", "join_served", "join_sm_served",
+                "join_fallbacks",
                 "batch_dispatches", "batch_exceptions",
                 "batch_ineligible", "prune_rounds",
                 # kernel shapes the start-up prewarm could not compile
@@ -493,6 +494,14 @@ def prometheus_text(sb, include_buckets: bool = True,
                 "device_round_trips"):
         p.sample("yacy_device_serving_total", c.get(key, 0),
                  {"counter": key})
+    p.family("yacy_devstore_join_bitmaps", "gauge",
+             "join-bitmap slots of the serving arena: in use, and lists "
+             "of bitmap size refused one (they join by sort-merge: "
+             "join_sm_served beside join_served)")
+    p.sample("yacy_devstore_join_bitmaps", c.get("join_bitmap_slots", 0),
+             {"state": "slots"})
+    p.sample("yacy_devstore_join_bitmaps", c.get("join_bitmap_refused", 0),
+             {"state": "refused"})
     # HBM accounting for the fleet (ISSUE 8 satellite): per-tier byte
     # occupancy and the promotion/demotion flow — always emitted (zeros
     # without a devstore) so the fleet digest's tier fields and any

@@ -624,6 +624,9 @@ def device_store(header, post, sb):
     if kind == "DeviceSegmentStore":
         c = ds.counters()
         rows += [
+            ("join_sm_served", c["join_sm_served"]),
+            ("join_bitmap_slots", c["join_bitmap_slots"]),
+            ("join_bitmap_refused", c["join_bitmap_refused"]),
             ("arena_rows_used", ds.arena.used_rows),
             ("arena_rows_capacity", ds.arena.capacity_rows),
             ("arena_bytes", ds.arena.bytes_used()),
