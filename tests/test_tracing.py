@@ -643,14 +643,18 @@ _HOST = {"search.join", "search.presort", "search.normalizing"}
 _BATCH = {"devstore.batch", "batcher.queue", "kernel.issue",
           "kernel.device", "kernel.fetch"}
 # query, what the route adds to _HTTP (+ "search.route.<route>")
+# (a NEW event gathers its metadata: `search.metajoin`, PR 28; a cached
+# event's page finds its cushion drained and reads nothing)
+_NEW = {"search.metajoin"}
 ROUTE_CASES = {
-    "device": ("bigterm", {"search.devrank"} | _BATCH),
+    "device": ("bigterm", {"search.devrank"} | _BATCH | _NEW),
     "event_cache": ("bigterm", set()),
-    "topk_cache": ("bigterm&nocache=true", set()),
-    "host_gate": ("small", _HOST),
-    "host_other": ("bigterm+site:h1.example+bigtwo", _HOST),
+    "topk_cache": ("bigterm&nocache=true", _NEW),
+    "host_gate": ("small", _HOST | _NEW),
+    "host_other": ("bigterm+site:h1.example+bigtwo", _HOST | _NEW),
 }
 PARENTS = {
+    "search.metajoin": "search.resultlist",
     "servlet.serving": "", "servlet.yacysearch": "",
     "servlet.render": "servlet.serving",
     "switchboard.search": "servlet.yacysearch",

@@ -270,6 +270,8 @@ class SegmentReader:
         self.meta: dict = self.header.get("meta", {})
         self._arrays: dict[str, np.memmap] = {}
         self._texts: dict[str, tuple] = {}
+        self._views: dict[str, memoryview] = {}
+        self._text_views: dict[str, tuple] = {}
 
     def array(self, name: str) -> np.ndarray:
         got = self._arrays.get(name)
@@ -338,24 +340,44 @@ class SegmentReader:
             self._texts[name] = got
         return got
 
+    # The served path reads VALUES through plain buffer views of the
+    # mappings, never through the np.memmap objects: every array-valued
+    # `memmap[...]` (a slice as much as an index array) builds a memmap
+    # whose __array_finalize__ calls np.may_share_memory, and that lets
+    # go of the interpreter lock - under concurrent requests one
+    # hand-off, up to a switch interval of waiting, per value read
+    # (PERF.md, PR 28). A memoryview element is a Python number and a
+    # memoryview slice never leaves the lock.
+
+    def view(self, name: str) -> memoryview:
+        """A numeric column as a buffer (`view[i]` is a Python int or
+        float); a fixed-width bytes column (the S12 url hashes) as its
+        raw bytes, row i at `view[i * width:(i + 1) * width]`."""
+        got = self._views.get(name)
+        if got is None:
+            arr = self.array(name)
+            got = memoryview(arr)
+            if arr.dtype.kind == "S":
+                got = got.cast("B")
+            self._views[name] = got
+        return got
+
+    def text_views(self, name: str) -> tuple[memoryview, memoryview]:
+        """(offsets, blob) of a text column as buffers: row i is
+        `str(blob[offsets[i]:offsets[i + 1]], "utf-8", "replace")`."""
+        got = self._text_views.get(name)
+        if got is None:
+            offsets, blob = self._text_maps(name)
+            got = (memoryview(offsets), memoryview(blob))
+            self._text_views[name] = got
+        return got
+
     def text(self, name: str, i: int) -> str:
-        offsets, blob = self._text_maps(name)
-        lo, hi = int(offsets[i]), int(offsets[i + 1])
+        offsets, blob = self.text_views(name)
+        lo, hi = offsets[i], offsets[i + 1]
         if lo == hi:
             return ""
-        return bytes(blob[lo:hi]).decode("utf-8", "replace")
-
-    def texts_at(self, name: str, rows: np.ndarray) -> list[str]:
-        """Batched text reads: ONE fancy-indexed offsets lookup instead
-        of per-row python (the navigator/drain hot path reads several
-        fields x ~80 candidates per query)."""
-        offsets, blob = self._text_maps(name)
-        rows = np.asarray(rows, np.int64)
-        lo = np.asarray(offsets[rows], np.int64)
-        hi = np.asarray(offsets[rows + 1], np.int64)
-        return [("" if a == b else
-                 bytes(blob[a:b]).decode("utf-8", "replace"))
-                for a, b in zip(lo.tolist(), hi.tolist())]
+        return str(blob[lo:hi], "utf-8", "replace")
 
     def text_column(self, name: str) -> list[str]:
         """Materialize a whole text column (compaction path)."""
@@ -366,5 +388,7 @@ class SegmentReader:
                 for i in range(self.n)]
 
     def close(self) -> None:
+        self._views.clear()
+        self._text_views.clear()
         self._arrays.clear()
         self._texts.clear()

@@ -10,6 +10,9 @@ value; the UI renders the top entries as refinement links.
 
 from __future__ import annotations
 
+import datetime
+
+from ..index.metadata import split_multi
 from ..utils.scoremap import ScoreMap
 
 DEFAULT_NAVIGATORS = ("hosts", "language", "filetype", "authors", "year",
@@ -53,13 +56,13 @@ def make_navigators(names=DEFAULT_NAVIGATORS) -> dict[str, Navigator]:
     return {n: Navigator(n, fields[n]) for n in names if n in fields}
 
 
+_EPOCH_ORDINAL = datetime.date(1970, 1, 1).toordinal()
+
+
 def _add_value(nav: Navigator, v) -> None:
     if nav.name == "year" and v:
-        import datetime
-        v = datetime.date.fromordinal(
-            datetime.date(1970, 1, 1).toordinal() + int(v)).year
+        v = datetime.date.fromordinal(_EPOCH_ORDINAL + int(v)).year
     if nav.name == "dates" and v:
-        from ..index.metadata import split_multi
         for date in split_multi(str(v)):
             nav.add(date)
         return
@@ -72,15 +75,12 @@ def accumulate(navigators: dict[str, Navigator], meta) -> None:
         _add_value(nav, meta.get(nav.field))
 
 
-def accumulate_batch(navigators: dict[str, Navigator], store,
-                     docids) -> None:
-    """Count a CANDIDATE SET into every navigator with one batched
-    column read per field (per-row LazyRow.get over ~80 oversampled
-    candidates x 7 fields was the serving path's top host cost)."""
-    from ..index.metadata import INT_FIELDS
+def accumulate_batch(navigators: dict[str, Navigator], cols: dict,
+                     alive) -> None:
+    """Count a CANDIDATE SET into every navigator from the columns one
+    MetadataStore.rows_at gather read (`cols[nav.field]`, index for
+    index with `alive`; a dead candidate counts nowhere)."""
     for nav in navigators.values():
-        vals = (store.int_values(docids, nav.field)
-                if nav.field in INT_FIELDS
-                else store.text_values(docids, nav.field))
-        for v in vals:
-            _add_value(nav, v)
+        for ok, v in zip(alive, cols[nav.field]):
+            if ok and v != "":          # an empty text counts nowhere
+                _add_value(nav, v)

@@ -44,13 +44,18 @@ from ..utils import profiling, tracing
 from ..utils.eventtracker import EClass, StageTimer, update as track
 from ..utils.hashes import hosthash
 from ..utils.topk import WeakPriorityQueue
-from .navigator import accumulate, make_navigators
+from .navigator import accumulate_batch, make_navigators
 from .query import QueryParams
 from .snippet import extract_snippet
 
 # oversampling factor for the device top-k so host-side diversity/filter
 # rechecks still fill the page (reference pulls from an unbounded-ish heap)
 TOPK_OVERSAMPLE = 8
+
+# the columns every ResultEntry is built from (SearchEvent._make_entry)
+ENTRY_FIELDS = ("sku", "title", "host_s", "url_file_ext_s", "language_s",
+                "size_i", "wordcount_i", "last_modified_days_i",
+                "references_i")
 
 _CD_FLAG = {CD_IMAGE: FLAG_CAT_HASIMAGE, CD_AUDIO: FLAG_CAT_HASAUDIO,
             CD_VIDEO: FLAG_CAT_HASVIDEO, CD_APP: FLAG_CAT_HASAPP}
@@ -167,6 +172,13 @@ class SearchEvent:
         self._pending: list[tuple[int, int]] = []  # lazily-drained ranked
         self._drained = 0                          # local entries drained
         self._ranker = CardinalRanker(query.profile, query.lang)
+        # what the metadata join reads for a candidate that becomes an
+        # entry: the filter-only columns only when this query rechecks
+        # them (_make_entry)
+        self._entry_fields = ENTRY_FIELDS + tuple(
+            f for f, asked in (("author", query.modifier.author),
+                               ("keywords", query.modifier.keyword),
+                               ("text_t", query.goal.phrases)) if asked)
         # the trace this event was born under: remote feeder threads and
         # late-merging producers parent their spans here (the contextvar
         # does not cross the fan-out thread boundary)
@@ -329,40 +341,65 @@ class SearchEvent:
         bottleneck. A cushion beyond the page keeps post-ranking boosts
         competing across the page boundary.
 
-        Facets accumulate over the FULL ranked candidate set here (cheap
-        columnar reads), not over materialized entries — the reference's
-        facet counts also cover the whole query result, not the page
-        (Solr facet counting)."""
-        self._pending = list(zip(scores.tolist(), docids.tolist()))
-        self._pending.reverse()          # pop() from the end = best-first
-        if self.navigators:
-            meta = self.segment.metadata
-            alive = [int(d) for d in docids.tolist()
-                     if not meta.is_deleted(int(d))
-                     and int(d) < meta.capacity()]
-            from .navigator import accumulate_batch
-            accumulate_batch(self.navigators, meta, alive)
-        self._drain(self.query.offset + self.query.item_count)
+        Facets accumulate over the FULL ranked candidate set (the
+        reference's facet counts also cover the whole query result, not
+        the page — Solr facet counting), from the same gather that reads
+        the first cushion's rows."""
+        with self._lock:
+            self._pending = list(zip(scores.tolist(), docids.tolist()))
+            self._pending.reverse()      # pop() from the end = best-first
+            self._drain(self.query.offset + self.query.item_count,
+                        facets=bool(self.navigators))
 
-    def _drain(self, need: int) -> None:
+    def _drain(self, need: int, facets: bool = False) -> None:
         """Materialize pending local candidates until `cushion` of them
         have been drained (counted independently of the heap, which remote
         feeders also fill — remote inserts must not starve better local
-        candidates out of materialization)."""
+        candidates out of materialization). The metadata join is ONE
+        gather per increment: what the cushion still lacks (an eviction
+        refills with a gather of its own), and with `facets` the
+        navigator columns of every pending candidate beside it."""
         cushion = need * 2 + 6
         with self._lock:
             if not self._pending:
                 return
-            with StageTimer(EClass.SEARCH, "RESULTLIST"):
+            with StageTimer(EClass.SEARCH, "RESULTLIST") as stage:
+                gathered = 0
                 while self._pending and self._drained < cushion:
-                    score, docid = self._pending.pop()
-                    made = self._make_entry(int(docid), int(score))
-                    if made is None:
-                        self.local_rwi_evicted += 1
-                        continue
-                    self._drained += 1
-                    entry, _meta = made
-                    self._insert(entry)
+                    take = min(cushion - self._drained, len(self._pending))
+                    read = len(self._pending) if facets else take
+                    batch = self._pending[-read:]
+                    batch.reverse()                      # best-first
+                    del self._pending[-take:]
+                    rows = self._gather([d for _, d in batch], take, facets)
+                    facets = False
+                    gathered += read
+                    for i in range(take):
+                        score, docid = batch[i]
+                        entry = self._make_entry(rows, i, int(docid),
+                                                 int(score))
+                        if entry is None:
+                            self.local_rwi_evicted += 1
+                            continue
+                        self._drained += 1
+                        stage.count += 1
+                        self._insert(entry)
+                stage.set(rows=stage.count, gathered=gathered)
+
+    def _gather(self, docids: list, head: int, facets: bool):
+        """The event's metadata join: ONE MetadataStore.rows_at read of
+        the entry columns and url hashes of the first `head` docids and,
+        with `facets`, of the navigator columns of all of them, counted
+        into the navigators here (a column both need is read once)."""
+        nav_fields = ([nav.field for nav in self.navigators.values()]
+                      if facets else ())
+        with StageTimer(EClass.SEARCH, "METAJOIN", len(docids)):
+            rows = self.segment.metadata.rows_at(
+                docids, nav_fields, head_fields=self._entry_fields,
+                head=head)
+            if facets:
+                accumulate_batch(self.navigators, rows.cols, rows.alive)
+        return rows
 
     def _device_local(self, k: int):
         """Eligibility gate + dispatch for the device-resident serving path
@@ -726,16 +763,17 @@ class SearchEvent:
             mask &= np.isin(plist.docids, allowed, assume_unique=False)
         return mask
 
-    def _make_entry(self, docid: int, score: int):
-        """Metadata join + modifier recheck; returns (ResultEntry, row)
-        or None when evicted. Uses the lazy column-backed row — this runs
-        once per oversampled candidate, the serving drain's hot loop."""
+    def _make_entry(self, rows, i: int, docid: int, score: int):
+        """Modifier recheck + ResultEntry over row `i` of a gather
+        (_gather -> MetadataStore.rows_at); None when evicted. The entry
+        is built from what the gather read: a row deleted since then is
+        the race a reader always had with a writer."""
         q = self.query
-        m = self.segment.metadata.row(docid)
-        if m is None:
+        if not rows.alive[i]:
             return None
-        url = m.get("sku", "")
-        title = m.get("title", "") or url
+        cols = rows.cols
+        url = cols["sku"][i]
+        title = cols["title"][i] or url
         if q.url_filter is not None and q.url_filter(url):
             return None
         if q.modifier.inurl and q.modifier.inurl.lower() not in url.lower():
@@ -743,7 +781,7 @@ class SearchEvent:
         if q.modifier.intitle and q.modifier.intitle.lower() not in title.lower():
             return None
         if q.modifier.author:
-            if q.modifier.author.lower() not in (m.get("author") or "").lower():
+            if q.modifier.author.lower() not in (cols["author"][i] or "").lower():
                 return None
         # metadata-facet recheck (site:/tld:/filetype:/protocol): the
         # device path filters by a facet BITMAP that may be up to
@@ -752,26 +790,25 @@ class SearchEvent:
         # only ever DELAYS inclusion (the reference's soft-commit lag)
         mod = q.modifier
         if mod.sitehost or mod.tld or mod.filetype or mod.protocol:
-            host = (m.get("host_s") or "").lower()
+            host = (cols["host_s"][i] or "").lower()
             if mod.sitehost:
                 want = mod.sitehost.lower()
                 if host != want and not host.endswith("." + want):
                     return None
             if mod.tld and not host.endswith("." + mod.tld.lower()):
                 return None
-            if mod.filetype and (m.get("url_file_ext_s") or "").lower() \
+            if mod.filetype and (cols["url_file_ext_s"][i] or "").lower() \
                     != mod.filetype.lower():
                 return None
             if mod.protocol and not url.lower().startswith(
                     mod.protocol.lower() + ":"):
                 return None
         if q.modifier.keyword:
-            if q.modifier.keyword.lower() not in (m.get("keywords") or "").lower():
+            if q.modifier.keyword.lower() not in (cols["keywords"][i] or "").lower():
                 return None
         # quoted phrases must literally appear (QueryGoal phrase recheck)
         if q.goal.phrases:
-            text = m.get("text_t", "")
-            tl = text.lower()
+            tl = cols["text_t"][i].lower()
             for ph in q.goal.phrases:
                 if ph not in tl and ph not in title.lower():
                     return None
@@ -779,13 +816,13 @@ class SearchEvent:
         # only the ~10 returned entries need one, not the whole
         # oversampled top-k — the drain loop is the serving hot path
         return ResultEntry(
-            docid=docid, urlhash=self.segment.metadata.urlhash_of(docid),
+            docid=docid, urlhash=rows.urlhashes[i],
             score=score, url=url, title=title, snippet="",
-            host=m.get("host_s", ""), filetype=m.get("url_file_ext_s", ""),
-            language=m.get("language_s", ""), size=m.get("size_i", 0),
-            wordcount=m.get("wordcount_i", 0),
-            lastmod_days=m.get("last_modified_days_i", 0),
-            references=m.get("references_i", 0)), m
+            host=cols["host_s"][i], filetype=cols["url_file_ext_s"][i],
+            language=cols["language_s"][i], size=cols["size_i"][i],
+            wordcount=cols["wordcount_i"][i],
+            lastmod_days=cols["last_modified_days_i"][i],
+            references=cols["references_i"][i])
 
     # -- fusion (local batch now, remote feeders in M5) ----------------------
 

@@ -545,8 +545,9 @@ def index_reindex_monitor(header, post, sb) -> ServerObjects:
     meta = sb.index.metadata
     docids = [d for d in range(meta.capacity())
               if not meta.is_deleted(d)]
-    # one batched per-segment column read, not capacity() row lookups
-    pending = sum(1 for v in meta.text_values(docids, "process_sxt")
+    # one gather, not capacity() row lookups
+    field = "process_sxt"
+    pending = sum(1 for v in meta.rows_at(docids, (field,)).cols[field]
                   if v)
     prop.put("pending", pending)
     prop.put("doccount", sb.index.doc_count())
