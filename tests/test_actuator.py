@@ -334,7 +334,7 @@ def test_autotuner_respects_bounds_and_steps_by_one(tmp_path):
 def test_disabled_engine_is_inert_on_the_serving_path(tmp_path):
     """actuator.enabled=false must disarm EVERY surface, not just the
     tick: admission admits everything and a frozen ladder rung stops
-    applying (the bench A/B OFF windows rely on exactly this)."""
+    applying."""
     sb = Switchboard(data_dir=str(tmp_path / "DATA"))
     try:
         act = sb.actuators

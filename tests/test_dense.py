@@ -397,8 +397,7 @@ def test_dense_snapshot_legacy_footer_free_loads(tmp_path):
 
 
 def test_dense_snapshot_verify_switch_respected(tmp_path):
-    """VERIFY_ON_READ off: a corrupt-crc file still loads (the A/B
-    bench switch) — detection is read-side only, writers always stamp."""
+    """VERIFY_ON_READ off: a corrupt-crc file still loads — detection is read-side only, writers always stamp."""
     import os
 
     from yacy_search_server_tpu.index import integrity

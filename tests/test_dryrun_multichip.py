@@ -1,6 +1,6 @@
 """The driver's multichip dryrun must be hermetic w.r.t. the default backend.
 
-Round-1 regression: ``MULTICHIP_r01.json`` came back ``ok=false`` because
+Round-1 regression: the dryrun came back ``ok=false`` because
 ``MeshRanker.__init__`` created its ranking constants with bare
 ``jnp.asarray`` — which places on the DEFAULT backend even when the mesh
 is the 8-device virtual CPU pool, so any TPU-side failure (libtpu version

@@ -1,12 +1,11 @@
 """Game day (ISSUE 19): the workload-realism layer, the chaos
 conductor's fault schedule, the verdict engine's joins, the straggler
 conviction tracker (ROADMAP 1c read-only slice), the faultinject wire
-schedule metadata, and the committed CHAOS_r02.json acceptance gates.
+schedule metadata, and the Performance_GameDay_p panel.
 
 The verdict-engine tests feed SYNTHETIC evidence — the engine is pure
 joins by contract, which is exactly what makes the incident→fault
-attribution testable without a 3-process soak.  The committed-artifact
-test then holds the real soak's output to the same gates."""
+attribution testable without a 3-process soak."""
 
 import json
 import os
@@ -18,8 +17,6 @@ from yacy_search_server_tpu.utils.gameday import (
     SCHEDULABLE_FAULTS, ClientPool, Conductor, Phase, RateEnvelope,
     ScheduledFault, VerdictEngine, ZipfSampler, default_envelope,
     default_schedule)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -348,165 +345,52 @@ def test_conviction_singleton_in_metrics_exposition(tmp_path):
         in text
 
 
-# -- the committed artifact (the CI completeness gate, satellite 5) ----------
-
-def test_committed_chaos_r02_artifact():
-    """CHAOS_r02.json must come from a real `bench.py --game-day`
-    multi-process soak and satisfy the ISSUE 19 acceptance wholesale:
-    >=3 overlapping scheduled faults, EVERY scheduled fault row carries
-    a passing verdict (detected + attributed to the right cause label
-    and member + 100%% answered + bounded SLO recovery), zero
-    unattributed verdicts, never a 5xx, bit-identical rankings after
-    full recovery, and every conductor-schedulable fault exercised."""
-    path = os.path.join(REPO, "CHAOS_r02.json")
-    assert os.path.exists(path), \
-        "CHAOS_r02.json missing (run bench.py --game-day)"
-    with open(path, encoding="utf-8") as f:
-        art = json.load(f)
-    assert art["metric"] == "game_day"
-    assert art["procs"] >= 3
-    rows = art["schedule"]
-    assert len(rows) >= 3
-    assert art["overlaps"], "the schedule must overlap faults"
-    # every scheduled fault row has a verdict, and it passes
-    for r in rows:
-        assert r["verdict"] == "pass", r
-        assert r["answered_detail"]["errors"] == 0, r
-        assert r["arm_ack"].get("result") == "ok", r
-        assert r["clear_ack"].get("result") == "ok", r
-        assert r["armed_ts"] and r["cleared_ts"], r
-    summary = art["verdict_summary"]
-    assert summary["all_pass"] and summary["faults"] == len(rows)
-    assert summary["unattributed_verdicts"] == 0, summary
-    assert summary["never_500"], art["workload"]["by_status"]
-    assert art["bit_identity"]["identical"], art["bit_identity"]
-    assert art["recovery"]["collective_resumed"], art["recovery"]
-    # no dead schedulable faults: every conductor-schedulable point
-    # appears in the committed run
-    assert {r["point"] for r in rows} >= set(SCHEDULABLE_FAULTS)
-    # workload realism made it into the run: zipf terms, spike phase,
-    # per-client identity, and the admission path actually engaged
-    wl = art["workload"]
-    assert any(p["name"] == "spike" for p in wl["phases"])
-    assert len(wl["clients"]) >= 2
-    assert wl["by_status"].get("429", 0) > 0, \
-        "admission must ENGAGE under the zipf-head client"
-    # the wire schedule trail (do_meshfault?list=1) is the source of
-    # truth: every scheduled fault's arm appears on its target member
-    wire = art["fault_wire_schedule"]
-    for r in rows:
-        trail = wire[r["target"]]
-        assert any(e["point"] == r["point"] and e["action"] == "arm"
-                   for e in trail), (r["point"], trail)
-
-
-# -- drill trend, run-over-run (ISSUE 20 satellite) --------------------------
-
-def _newest_schedule_artifact():
-    import glob
-    for p in sorted(glob.glob(os.path.join(REPO, "CHAOS_r*.json")),
-                    reverse=True):
-        with open(p, encoding="utf-8") as f:
-            art = json.load(f)
-        if art.get("schedule"):
-            return p, art
-    pytest.fail("no committed game-day artifact with a schedule")
-
-
-def test_drill_trend_self_diff_is_complete_and_zero():
-    """Completeness: EVERY scheduled fault of the newest committed
-    artifact appears in the trend, and a self-diff is all-zero deltas
-    with no regressions (the identity the bench-side embed relies on)."""
-    from tools import drill_trend
-    _path, art = _newest_schedule_artifact()
-    t = drill_trend.trend(art, art)
-    assert {(r["point"], r["target"]) for r in t["faults"]} == \
-        {(str(r["point"]), str(r["target"])) for r in art["schedule"]}
-    assert t["regressions"] == 0 and t["improvements"] == 0
-    assert not t["new_faults"] and not t["dropped_faults"]
-    for r in t["faults"]:
-        assert not r["regressed"] and not r["improved"]
-        assert r["recovered_s"]["delta_s"] in (0.0, None)
-        for c in drill_trend.CHECKS:
-            assert r["checks"][c]["prev"] == r["checks"][c]["cur"]
-    assert t["all_pass"]["prev"] == t["all_pass"]["cur"]
-
-
-def test_drill_trend_flags_check_flip_and_verdict_regression():
-    from tools import drill_trend
-    prev = {"round": 1, "schedule": [
-        {"point": "mesh.step", "target": "mesh1", "verdict": "pass",
-         "detected": True, "attributed": True, "answered": True,
-         "slo_recovery": True, "bit_identical": True,
-         "recovery": {"recovered_s": 4.0}}]}
-    cur = json.loads(json.dumps(prev))
-    cur["round"] = 2
-    cur["schedule"][0]["attributed"] = False
-    cur["schedule"][0]["verdict"] = "fail"
-    cur["schedule"][0]["recovery"]["recovered_s"] = 9.0
-    t = drill_trend.trend(prev, cur)
-    assert t["regressions"] == 1
-    row = t["faults"][0]
-    assert row["regressed"] and not row["improved"]
-    assert row["checks"]["attributed"] == {"prev": True, "cur": False}
-    assert row["recovered_s"]["delta_s"] == 5.0
-    # the flip back reads as an improvement, never a regression
-    t2 = drill_trend.trend(cur, prev)
-    assert t2["regressions"] == 0 and t2["improvements"] == 1
-    # fault present only on one side: reported, not crashed on
-    cur2 = json.loads(json.dumps(prev))
-    cur2["schedule"].append({"point": "device.transfer_fail",
-                             "target": "mesh2", "verdict": "pass"})
-    t3 = drill_trend.trend(prev, cur2)
-    assert t3["new_faults"] == [["device.transfer_fail", "mesh2"]]
-    assert t3["regressions"] == 0
-
-
-def test_committed_round3_embeds_trend_and_convicted_profile():
-    """The ISSUE 20 acceptance on the committed artifact: from round 3
-    every --game-day run carries (a) the run-over-run trend block with
-    zero regressions against the named prior artifact, and (b) a
-    straggler_convicted incident whose crumb embeds the convicted
-    member's WIRE-FETCHED whitebox profile — sampled in the straggler's
-    own process (distinct pid) with a member-runloop stack naming the
-    armed straggle site."""
-    path, art = _newest_schedule_artifact()
-    if art.get("round", 0) < 3:
-        pytest.skip("pre-ISSUE-20 artifact")
-    t = art["trend"]
-    assert t["regressions"] == 0, (path, t)
-    assert os.path.exists(os.path.join(REPO, t["prev_artifact"]))
-    assert t["faults"], "trend block diffed no faults"
-
-    mesh_incidents = (art.get("incidents") or {}).get("mesh", [])
-    convs = [i for i in mesh_incidents
-             if i.get("name") == "straggler_convicted"]
-    assert convs, "drill produced no conviction incident"
-    inc = convs[0]
-    assert inc["member"] == inc["crumb"]["member"]
-    prof = inc["crumb"].get("profile")
-    assert prof, "conviction crumb carries no profile"
-    assert prof["samples_total"] > 0
-    runloop = [s for s in prof["stacks"]
-               if s["role"] == "member-runloop"]
-    assert runloop, prof["stacks"][:4]
-    assert any("faultinject" in s["stack"] for s in runloop), \
-        "member-runloop stacks never caught the armed straggle site"
-
-
 # -- the servlet -------------------------------------------------------------
 
-def test_gameday_servlet_renders_artifact():
+@pytest.mark.parametrize("source", ("none", "live"))
+def test_gameday_servlet_renders_artifact(source, monkeypatch):
+    """With no drill in this process the panel says so (no rows); a
+    `gameday.LAST_RUN` renders one row per scheduled fault with the
+    verdict engine's gates."""
     from yacy_search_server_tpu.server import servlets
     from yacy_search_server_tpu.server.objects import ServerObjects
+    from yacy_search_server_tpu.utils import gameday
 
+    last_run = None
+    if source == "live":
+        f = _fault("device.transfer_fail", 2, 100.0, 200.0)
+        rows = _engine(f, mesh_incidents=[
+            {"name": "mesh_member_lost", "member": "mesh2",
+             "cause": "lost", "ts": 120.0, "incident_seq": 1},
+            {"name": "mesh_member_recovered", "member": "mesh2",
+             "cause": "ok", "ts": 205.0, "incident_seq": 2}]).verdicts()
+        last_run = {
+            "schedule": rows, "overlaps": [["F1", "F2"]],
+            "verdict_summary": {"faults": len(rows), "passed": 0,
+                                "all_pass": False, "never_500": True,
+                                "unattributed_verdicts": 0},
+            "workload": {"queries_total": 7, "duration_s": 3}}
+    monkeypatch.setattr(gameday, "LAST_RUN", last_run)
     fn = servlets.lookup("Performance_GameDay_p")
     assert fn is not None
     view = json.loads(fn({}, ServerObjects({"format": "json"}),
                          None).raw_body)
-    assert "schedule" in view and "source" in view
+    assert view["source"] == source
     prop = fn({}, ServerObjects(), None)
     assert prop.get_int("rows") == len(view["schedule"])
-    if view["source"] != "none":
-        assert prop.get_int("faults") == \
-            view["verdict_summary"]["faults"]
+    assert prop.get("source") == source
+    if source == "none":
+        assert prop.get_int("rows") == 0 and prop.get_int("faults") == 0
+        assert prop.get("note") == "no drill has run in this process"
+        return
+    assert prop.get("note") == ""
+    assert prop.get_int("rows") == 1 and prop.get_int("faults") == 1
+    assert prop.get("rows_0_point") == "device.transfer_fail"
+    assert prop.get("rows_0_target") == "mesh2"
+    assert prop.get_int("rows_0_detected") == 1
+    assert prop.get_int("rows_0_attributed") == 1
+    assert prop.get("rows_0_verdict") == rows[0]["verdict"]
+    assert prop.get_int("overlaps") == 1
+    assert prop.get("overlaps_0_pair") == "F1+F2"
+    assert prop.get_int("queries_total") == 7
+    assert prop.get_int("never_500") == 1

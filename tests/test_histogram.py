@@ -47,8 +47,7 @@ def test_bucket_index_places_values_under_their_bound():
 def test_percentiles_agree_with_nearest_rank_within_bucket_resolution():
     """The histogram-derived p50/p95 must agree with the shared
     nearest-rank percentile over the raw samples within the bucket
-    resolution (~12.5%) — the cross-check bound the bench artifacts
-    pin."""
+    resolution (~12.5%)."""
     rng = np.random.default_rng(7)
     samples = np.exp(rng.normal(math.log(20.0), 1.0, 20_000))  # lognormal
     h = hg.histogram("agree.test")
@@ -63,7 +62,7 @@ def test_percentiles_agree_with_nearest_rank_within_bucket_resolution():
 
 def test_shared_percentile_implementation():
     # ONE nearest-rank convention across the observability layer: the
-    # tracing/profiler/bench alias must BE the histogram module's pctl
+    # tracing/profiler alias must BE the histogram module's pctl
     assert tracing._pctl is hg.pctl
     from yacy_search_server_tpu.utils.profiler import RooflineProfiler
     assert RooflineProfiler._pctl is hg.pctl

@@ -245,44 +245,6 @@ def test_kill_one_member_fleet_still_answers_then_reaps(fleet,
         os.kill(victim, 0)
 
 
-# -- committed artifact (satellite: --capacity validation pattern) -----------
-
-MESH_PROCS_KEYS = (
-    "procs", "cells", "queries", "answered", "qps",
-    "bit_identical_vs_single_process", "distinct_pids",
-    "fusion_collective_ms", "digest_bytes", "worker_stall",
-    "per_process", "ok",
-)
-
-
-def test_committed_multichip_r06_artifact():
-    """MULTICHIP_r06.json must come from a real multi-process soak
-    (bench.py --mesh-procs N): per-process counters, the fusion-
-    collective histogram, distinct pids, zero worker_stall — a soak
-    that failed any gate must not have committed a green artifact."""
-    import json
-    art = os.path.join(REPO, "MULTICHIP_r06.json")
-    assert os.path.exists(art), \
-        "MULTICHIP_r06.json missing (run bench.py --mesh-procs 3)"
-    obj = json.loads(open(art, encoding="utf-8").read())
-    missing = [k for k in MESH_PROCS_KEYS if k not in obj]
-    assert not missing, f"artifact missing {missing}"
-    assert obj["ok"] is True
-    assert obj["procs"] >= 2
-    assert obj["answered"] == obj["queries"] > 0
-    assert obj["distinct_pids"] == obj["procs"]
-    assert obj["worker_stall"] == 0
-    assert obj["bit_identical_vs_single_process"] is True
-    assert obj["fusion_collective_ms"]["count"] > 0
-    assert len(obj["per_process"]) == obj["procs"]
-    for row in obj["per_process"]:
-        # .get: the r06 artifact predates the step_errors counter
-        assert row["queries_total"] == \
-            row["answered_collective"] + row["answered_host"] \
-            + row.get("step_errors", 0)
-        assert "qps" in row and "collective_hist" in row
-
-
 # -- partition-math determinism (satellite) ----------------------------------
 
 def test_term_shard_properties_over_random_hashes_and_shapes():

@@ -1,6 +1,6 @@
 """Bit-packed posting block format (ops/packed.py) — round-trip
 property tests over adversarial column ranges, device-decode parity, and
-the compression accounting the capacity bench reports.
+the compression accounting `DeviceStore_p` reports.
 
 The pack/unpack twins must be exact inverses for EVERY int16-compact
 block (the parity of the whole compressed-residency subsystem rests on

@@ -4,7 +4,7 @@ The batcher's dispatcher threads now ISSUE kernel calls asynchronously
 and a completer pool performs the blocking fetch — these tests pin:
 
 - bit-parity of the pipelined path against the host oracle, and against
-  the same batcher with pipelining off (the bench A/B switch);
+  the same batcher with pipelining off (`index.device.pipeline`);
 - the issue/device/fetch span decomposition on traced queries;
 - counter EXACTNESS under a 32-thread hammer (the satellite fix: the
   batcher counters were bare `+=` from many threads — now under
@@ -80,7 +80,7 @@ def test_pipelined_batch_parity_and_span_decomposition():
 
 
 def test_pipeline_off_is_bit_identical():
-    """The bench's A/B switch: pipeline=False completes inline (the
+    """`index.device.pipeline` off: pipeline=False completes inline (the
     pre-pipeline behavior) with bit-identical results."""
     ds = _built_store()
     try:

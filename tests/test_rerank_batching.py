@@ -13,7 +13,7 @@ doc vectors from a device-resident forward index
   the dot-product's accumulation-order rounding (the oracle caveat
   dense_boost_topk_np states), and the pinned tie ordering;
 - solo (rerankBatching=off) vs batched (on, concurrent threads) answers
-  bit-identical — the bench A/B switch contract;
+  bit-identical — the `index.device.rerankBatching` contract;
 - the pinned tie discipline (score DESC, then docid ASC) on every
   rerank path, so equal-scored candidates can never flap the top-k
   cache between bit-different answers (arxiv 1807.05798);

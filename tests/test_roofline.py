@@ -1,19 +1,24 @@
 """Silicon accounting tests (ISSUE 1 tentpole).
 
 The cost models in ops/roofline.py claim closed-form FLOPs / bytes for
-every serving kernel; these tests pin the claims against XLA's own
+every serving kernel; these tests pin the flop counts against XLA's own
 compiled cost analysis (within 10% on 3 representative shapes per
-kernel), exercise the roofline math, and bound the profiler's hot-path
-overhead (< 1% on a 1k-query microbench).
+kernel; the compulsory bytes come from array shapes and have no
+compiler to agree with), exercise the roofline math, and bound the
+profiler's hot-path overhead (< 1% on a 1k-query microbench).
 
 Loop-carried kernels (lax.scan / fori_loop / lax.map bodies) are
 cross-checked at their UNIT-TRIP shape: HloCostAnalysis counts a loop
 body once regardless of trip count, so the comparable analytical number
 is the one-step cost (the model multiplies by the trip count for real
 executions — that part is plain arithmetic, not an estimate).
+
+Three kernels (the two bit-packed scorers, the BlockRank iteration) are
+held to the module AS LOWERED (`lowered=True`): their optimised count
+charges gathers/scatters by how the host's CPU pipeline expands them,
+and read differently on two machines running the same jax.
 """
 
-import os
 import time
 
 import numpy as np
@@ -33,10 +38,10 @@ TOL = 0.10    # the 10% cross-check bar
 
 
 def _xla(jitfn, *args, **kw):
-    flops, by = RF.xla_cost(jitfn, *args, **kw)
-    if np.isnan(flops) or np.isnan(by):
+    flops = RF.xla_cost(jitfn, *args, **kw)
+    if np.isnan(flops):
         pytest.skip("backend does not expose cost_analysis")
-    return flops, by
+    return flops
 
 
 def _close(model: float, xla: float, what: str):
@@ -83,8 +88,8 @@ def test_registry_covers_the_named_kernels():
                                          (8, 128, 256), (4, 64, 4096)))
 def test_xla_all_gather_topk(ndev, k, rows):
     """The fused fusion collective's cost model vs XLA (ISSUE 12
-    acceptance: XLA-cross-checked, gathered bytes scale with k not
-    corpus rows) — the whole shard_map program: local tie-exact top-k
+    acceptance: flops XLA-cross-checked, gathered bytes scale with k
+    not corpus rows) — the whole shard_map program: local tie-exact top-k
     + k-row gather + tie-pinned merge."""
 
     from jax.sharding import Mesh, NamedSharding
@@ -108,10 +113,9 @@ def test_xla_all_gather_topk(ndev, k, rows):
                         NamedSharding(mesh, PS("doc")))
     da = jax.device_put(jnp.arange(n, dtype=jnp.int32),
                         NamedSharding(mesh, PS("doc")))
-    flops, by = _xla(fn, sa, da)
+    flops = _xla(fn, sa, da)
     c = RF.cost("all_gather_topk", k=k, ndev=ndev, rows=rows)
     _close(c.flops, flops, f"all_gather_topk[{ndev},{k},{rows}] flops")
-    _close(c.xla_bytes, by, f"all_gather_topk[{ndev},{k},{rows}] bytes")
     # the k-scaling contract: quadrupling corpus rows grows the model's
     # gathered wire payload not at all (compulsory bytes: 8·G + local)
     big = RF.cost("all_gather_topk", k=k, ndev=ndev, rows=rows * 4)
@@ -123,20 +127,18 @@ def test_xla_all_gather_topk(ndev, k, rows):
 def test_xla_cardinal_scores16(n):
     f16, fl, dd, v, hh = _block(n)
     cj = jax.jit(lambda *a: R.cardinal_scores16(*a, with_authority=False))
-    flops, by = _xla(cj, f16, fl, v, hh, None, *_consts())
+    flops = _xla(cj, f16, fl, v, hh, None, *_consts())
     c = RF.cost("cardinal_scores16", n=n)
     _close(c.flops, flops, f"cardinal_scores16[{n}] flops")
-    _close(c.xla_bytes, by, f"cardinal_scores16[{n}] bytes")
 
 
 @pytest.mark.parametrize("n,k", ((4096, 16), (32768, 128), (131072, 16)))
 def test_xla_score_topk16(n, k):
     f16, fl, dd, v, hh = _block(n)
-    flops, by = _xla(R.score_topk16, f16, fl, dd, v, hh, *_consts(),
-                     k=k, with_authority=False)
+    flops = _xla(R.score_topk16, f16, fl, dd, v, hh, *_consts(),
+                 k=k, with_authority=False)
     c = RF.cost("score_topk16", n=n, k=k)
     _close(c.flops, flops, f"score_topk16[{n},{k}] flops")
-    _close(c.xla_bytes, by, f"score_topk16[{n},{k}] bytes")
 
 
 @pytest.mark.parametrize("n,k", ((8192, 16), (32768, 16), (65536, 128)))
@@ -145,10 +147,9 @@ def test_xla_score_topk_int32(n, k):
     dd = jnp.arange(n, dtype=jnp.int32)
     v = jnp.ones(n, bool)
     hh = jnp.zeros(n, jnp.int32)
-    flops, by = _xla(R.score_topk, f, dd, v, hh, *_consts(), k=k)
+    flops = _xla(R.score_topk, f, dd, v, hh, *_consts(), k=k)
     c = RF.cost("score_topk", n=n, k=k)
     _close(c.flops, flops, f"score_topk[{n},{k}] flops")
-    _close(c.xla_bytes, by, f"score_topk[{n},{k}] bytes")
 
 
 @pytest.mark.parametrize("tile", (16384, 32768, 65536))
@@ -161,11 +162,10 @@ def test_xla_scan_score_topk_unit_step(tile):
              "col_max": jnp.full(P.NF, 1000, jnp.int32),
              "tf_min": jnp.float32(0), "tf_max": jnp.float32(1),
              "host_counts": jnp.zeros(1, jnp.int32)}
-    flops, by = _xla(S.scan_score_topk, f16, fl, dd, v, hh, stats,
-                     *_consts(), k=16, tile=tile)
+    flops = _xla(S.scan_score_topk, f16, fl, dd, v, hh, stats,
+                 *_consts(), k=16, tile=tile)
     c = RF.cost("scan_score_topk", n=tile, k=16, tile=tile)
     _close(c.flops, flops, f"scan_score_topk[{tile}] flops")
-    _close(c.xla_bytes, by, f"scan_score_topk[{tile}] bytes")
 
 
 @pytest.mark.parametrize("n,t", ((32768, 3), (131072, 5), (32768, 8)))
@@ -175,45 +175,41 @@ def test_xla_bm25_topk(n, t):
     df = jnp.ones(t, jnp.int32)
     v = jnp.ones(n, bool)
     dd = jnp.arange(n, dtype=jnp.int32)
-    flops, by = _xla(R.bm25_topk, tf, dl, df, jnp.int32(n), v, dd, k=16)
+    flops = _xla(R.bm25_topk, tf, dl, df, jnp.int32(n), v, dd, k=16)
     c = RF.cost("bm25_topk", n=n, t=t, k=16)
     _close(c.flops, flops, f"bm25_topk[{n},{t}] flops")
-    _close(c.xla_bytes, by, f"bm25_topk[{n},{t}] bytes")
 
 
 @pytest.mark.parametrize("n", (32768, 65536, 131072))
 def test_xla_hybrid_rerank_solo(n):
     dv = jnp.zeros((n, 256), jnp.float32)
     q = jnp.zeros(256, jnp.float32)
-    flops, by = _xla(D.hybrid_rerank_topk, q, dv,
-                     jnp.zeros(n, jnp.float32), jnp.ones(n, bool),
-                     jnp.float32(0.5), k=128)
+    flops = _xla(D.hybrid_rerank_topk, q, dv,
+                 jnp.zeros(n, jnp.float32), jnp.ones(n, bool),
+                 jnp.float32(0.5), k=128)
     c = RF.cost("hybrid_rerank_topk", n=n, k=128)
     _close(c.flops, flops, f"hybrid_rerank_topk[{n}] flops")
-    _close(c.xla_bytes, by, f"hybrid_rerank_topk[{n}] bytes")
 
 
 @pytest.mark.parametrize("n,b", ((32768, 16), (65536, 16), (65536, 8)))
 def test_xla_hybrid_rerank_batch(n, b):
     q = jnp.zeros((b, 256), jnp.float32)
     dv = jnp.zeros((n, 256), jnp.float32)
-    flops, by = _xla(D.hybrid_rerank_topk_batch, q, dv,
-                     jnp.zeros((b, n), jnp.float32),
-                     jnp.ones((b, n), bool), jnp.float32(0.5), k=128)
+    flops = _xla(D.hybrid_rerank_topk_batch, q, dv,
+                 jnp.zeros((b, n), jnp.float32),
+                 jnp.ones((b, n), bool), jnp.float32(0.5), k=128)
     c = RF.cost("hybrid_rerank_topk_batch", n=n, b=b, k=128)
     _close(c.flops, flops, f"hybrid_batch[{n},{b}] flops")
-    _close(c.xla_bytes, by, f"hybrid_batch[{n},{b}] bytes")
 
 
 @pytest.mark.parametrize("n", (32768, 65536, 131072))
 def test_xla_dense_boost(n):
     dv = jnp.zeros((n, 256), jnp.float32)
     q = jnp.zeros(256, jnp.float32)
-    flops, by = _xla(D.dense_boost_topk, q, dv, jnp.zeros(n, jnp.int32),
-                     jnp.ones(n, bool), jnp.float32(0.5), k=128)
+    flops = _xla(D.dense_boost_topk, q, dv, jnp.zeros(n, jnp.int32),
+                 jnp.ones(n, bool), jnp.float32(0.5), k=128)
     c = RF.cost("dense_boost_topk", n=n, k=128)
     _close(c.flops, flops, f"dense_boost[{n}] flops")
-    _close(c.xla_bytes, by, f"dense_boost[{n}] bytes")
 
 
 @pytest.mark.parametrize("nb,bs,cap", (
@@ -223,11 +219,10 @@ def test_xla_rerank_fwd_batch_packed(nb, bs, cap):
     gathering from a [cap, dim] f16 device-resident forward index."""
     fwd = jnp.zeros((cap, 256), jnp.float16)
     qi = jnp.zeros((bs, 2 + 2 * nb + 256), jnp.int32)
-    flops, by = _xla(D._rerank_fwd_batch_packed_kernel, fwd, qi,
-                     nb=nb, bs=bs)
-    c = RF.cost("_rerank_fwd_batch_packed_kernel", bs=bs, nb=nb, cap=cap)
+    flops = _xla(D._rerank_fwd_batch_packed_kernel, fwd, qi,
+                 nb=nb, bs=bs)
+    c = RF.cost("_rerank_fwd_batch_packed_kernel", bs=bs, nb=nb)
     _close(c.flops, flops, f"rerank_fwd[{nb},{bs},{cap}] flops")
-    _close(c.xla_bytes, by, f"rerank_fwd[{nb},{bs},{cap}] bytes")
 
 
 @pytest.mark.parametrize("bs,C", ((4, 256), (16, 1024), (16, 4096)))
@@ -237,11 +232,10 @@ def test_xla_ann_assign(bs, C):
     from yacy_search_server_tpu.ops import ann as AN
     cent = jnp.zeros((C, 256), jnp.float16)
     qv = jnp.zeros((bs, 256), jnp.float32)
-    flops, by = _xla(AN._ann_assign_batch_kernel, cent, qv, np_=8,
-                     c_real=C)
+    flops = _xla(AN._ann_assign_batch_kernel, cent, qv, np_=8,
+                 c_real=C)
     c = RF.cost("_ann_assign_batch_kernel", bs=bs, dim=256, C=C, np_=8)
     _close(c.flops, flops, f"ann_assign[{bs},{C}] flops")
-    _close(c.xla_bytes, by, f"ann_assign[{bs},{C}] bytes")
 
 
 @pytest.mark.parametrize("bs,nb,cap,k", ((4, 1024, 65536, 64),
@@ -256,12 +250,11 @@ def test_xla_ann_fuse(bs, nb, cap, k):
     scales = jnp.zeros(cap, jnp.float16)
     sdocids = jnp.zeros(cap, jnp.int32)
     qi = jnp.zeros((bs, 2 + 3 * nb + 256), jnp.int32)
-    flops, by = _xla(AN._ann_fuse_batch_packed_kernel, slab, scales,
-                     sdocids, qi, nb=nb, bs=bs, k=k)
+    flops = _xla(AN._ann_fuse_batch_packed_kernel, slab, scales,
+                 sdocids, qi, nb=nb, bs=bs, k=k)
     c = RF.cost("_ann_fuse_batch_packed_kernel", bs=bs, nb=nb, dim=256,
-                cap=cap, k=k)
+                k=k)
     _close(c.flops, flops, f"ann_fuse[{bs},{nb},{cap},{k}] flops")
-    _close(c.xla_bytes, by, f"ann_fuse[{bs},{nb},{cap},{k}] bytes")
 
 
 @pytest.mark.parametrize("bs,rows", ((2, 256), (8, 1024), (16, 4096)))
@@ -275,22 +268,21 @@ def test_xla_pack_block_batch(bs, rows):
     fl = rng.integers(0, 1 << 20, (bs, rows)).astype(np.int32)
     dd = rng.integers(0, 1 << 20, (bs, rows)).astype(np.int32)
     nv = np.full(bs, rows, np.int32)
-    flops, by = _xla(IB._pack_block_batch_kernel, f16, fl, dd, nv,
-                     rows=rows)
+    flops = _xla(IB._pack_block_batch_kernel, f16, fl, dd, nv,
+                 rows=rows)
     c = RF.cost("_pack_block_batch_kernel", bs=bs, rows=rows)
     _close(c.flops, flops, f"pack_block_batch[{bs},{rows}] flops")
-    _close(c.xla_bytes, by, f"pack_block_batch[{bs},{rows}] bytes")
 
 
 @pytest.mark.parametrize("n,e", ((1024, 8192), (1024, 16384), (2048, 8192)))
 def test_xla_power_iterate_unit_step(n, e):
     from yacy_search_server_tpu.ops import blockrank as B
-    flops, by = _xla(B._power_iterate_sparse, jnp.zeros(e, jnp.int32),
-                     jnp.zeros(e, jnp.int32), jnp.ones(e, jnp.float32),
-                     jnp.zeros(n, bool), jnp.float32(0.85), n=n)
+    flops = _xla(B._power_iterate_sparse, jnp.zeros(e, jnp.int32),
+                 jnp.zeros(e, jnp.int32), jnp.ones(e, jnp.float32),
+                 jnp.zeros(n, bool), jnp.float32(0.85), n=n,
+                 lowered=True)
     c = RF.cost("_power_iterate_sparse", n=n, edges=e, iters=1)
     _close(c.flops, flops, f"power[{n},{e}] flops")
-    _close(c.xla_bytes, by, f"power[{n},{e}] bytes")
 
 
 # devstore kernels share one arena fixture (compiles are the slow part)
@@ -319,23 +311,22 @@ def test_xla_rank_pruned_batch1(arena, bs, maxt):
     zf = np.zeros(bs, np.float32)
     qi, qf, nbs = DS._pack_batch1(z, z, z, z, zc, zc, zf, zf,
                                   np.int32(0), np.int32(0))
-    flops, by = _xla(DS._rank_pruned_batch1_kernel, arena["f16"],
-                     arena["fl"], arena["dd"], arena["dead"],
-                     arena["pmax"], qi, qf, *_consts(), k=16, maxt=maxt,
-                     bs=nbs)
+    flops = _xla(DS._rank_pruned_batch1_kernel, arena["f16"],
+                 arena["fl"], arena["dd"], arena["dead"],
+                 arena["pmax"], qi, qf, *_consts(), k=16, maxt=maxt,
+                 bs=nbs)
     c = RF.cost("_rank_pruned_batch1_kernel", bs=bs, tile=arena["TILE"],
-                maxt=maxt, k=16, cap=arena["cap"], doc_cap=1 << 16,
-                tcap=1 << 12)
+                maxt=maxt, k=16)
     _close(c.flops, flops, f"pruned_batch1[{bs},{maxt}] flops")
-    _close(c.xla_bytes, by, f"pruned_batch1[{bs},{maxt}] bytes")
 
 
 @pytest.mark.parametrize("bs,pw_cap", ((4, 1 << 18), (16, 1 << 18),
                                        (16, 1 << 20)))
 def test_xla_rank_pruned_batch1_bp(arena, bs, pw_cap):
-    """The bit-packed fused-decode pruned kernel: the XLA byte model
-    carries a per-pw-word multi-gather slope (each decode gather
-    charges the packed-words operand)."""
+    """The bit-packed fused-decode pruned kernel, against the module as
+    lowered: optimised, each decode gather is charged per word of the
+    packed arena (`pw_cap`), by a factor that moves with the XLA build;
+    the model is per scored row at either capacity."""
     from yacy_search_server_tpu.index import devstore as DS
     from yacy_search_server_tpu.ops import packed as PK
     z = np.zeros(bs, np.int32)
@@ -344,15 +335,13 @@ def test_xla_rank_pruned_batch1_bp(arena, bs, pw_cap):
     zm = np.zeros((bs, PK.META_LEN), np.int32)
     qiq, nbs = DS._pack_batch1_bp(z, z, z, z, zm, zc, zc, zf, zf,
                                   np.int32(0), np.int32(0))
-    flops, by = _xla(DS._rank_pruned_batch1_bp_kernel,
-                     jnp.zeros(pw_cap, jnp.int32), arena["dead"],
-                     arena["pmax"], qiq, *_consts(), k=16, maxt=64,
-                     bs=nbs)
+    flops = _xla(DS._rank_pruned_batch1_bp_kernel,
+                 jnp.zeros(pw_cap, jnp.int32), arena["dead"],
+                 arena["pmax"], qiq, *_consts(), k=16, maxt=64,
+                 bs=nbs, lowered=True)
     c = RF.cost("_rank_pruned_batch1_bp_kernel", bs=bs,
-                tile=arena["TILE"], maxt=64, k=16, pw_cap=pw_cap,
-                doc_cap=1 << 16, tcap=1 << 12)
+                tile=arena["TILE"], maxt=64, k=16)
     _close(c.flops, flops, f"pruned_bp[{bs},{pw_cap}] flops")
-    _close(c.xla_bytes, by, f"pruned_bp[{bs},{pw_cap}] bytes")
 
 
 @pytest.mark.parametrize("bs,pw_cap", ((1, 1 << 18), (4, 1 << 20)))
@@ -363,13 +352,12 @@ def test_xla_rank_scan_bp_unit_trip(arena, bs, pw_cap):
     from yacy_search_server_tpu.ops import packed as PK
     qi = np.zeros((bs, 6 + PK.META_LEN), np.int32)
     qi[:, 1] = arena["TILE"]
-    flops, by = _xla(DS._rank_scan_batch_bp_kernel,
-                     jnp.zeros(pw_cap, jnp.int32), arena["dead"], qi,
-                     *_consts(), k=16, bs=bs)
+    flops = _xla(DS._rank_scan_batch_bp_kernel,
+                 jnp.zeros(pw_cap, jnp.int32), arena["dead"], qi,
+                 *_consts(), k=16, bs=bs, lowered=True)
     c = RF.cost("_rank_scan_batch_bp_kernel", rows=bs * arena["TILE"],
-                k=16, bs=bs, pw_cap=pw_cap, doc_cap=1 << 16)
+                k=16, bs=bs)
     _close(c.flops, flops, f"scan_bp[{bs},{pw_cap}] flops")
-    _close(c.xla_bytes, by, f"scan_bp[{bs},{pw_cap}] bytes")
 
 
 def test_xla_rank_pruned_unit_trip(arena):
@@ -379,14 +367,13 @@ def test_xla_rank_pruned_unit_trip(arena):
     z = np.zeros(16, np.int32)
     zc = np.zeros((16, P.NF), np.int32)
     zf = np.zeros(16, np.float32)
-    flops, by = _xla(DS._rank_pruned_batch_kernel, arena["f16"],
-                     arena["fl"], arena["dd"], arena["dead"],
-                     arena["pmax"], z, z, z, z, zc, zc, zf, zf,
-                     np.int32(0), np.int32(0), *_consts(), k=16, b=8)
+    flops = _xla(DS._rank_pruned_batch_kernel, arena["f16"],
+                 arena["fl"], arena["dd"], arena["dead"],
+                 arena["pmax"], z, z, z, z, zc, zc, zf, zf,
+                 np.int32(0), np.int32(0), *_consts(), k=16, b=8)
     c = RF.cost("_rank_pruned_kernel", b=1, bs=1, tile=arena["TILE"],
                 k=16)
     _close(c.flops, flops, "pruned unit-trip flops")
-    _close(c.xla_bytes, by, "pruned unit-trip bytes")
 
 
 @pytest.mark.parametrize("r,m", ((65536, 65536), (131072, 65536),
@@ -394,30 +381,27 @@ def test_xla_rank_pruned_unit_trip(arena):
 def test_xla_rank_join(arena, r, m):
     from yacy_search_server_tpu.index import devstore as DS
     qargs = np.zeros((1, 9), np.int32)
-    flops, by = _xla(DS._rank_join_batch_kernel, arena["f16"],
-                     arena["fl"], arena["dd"], arena["dead"],
-                     arena["jd"], arena["jp"], qargs, *_consts(),
-                     k=16, n_inc=1, n_exc=0, r=r, inc_ms=(m,), exc_ms=())
+    flops = _xla(DS._rank_join_batch_kernel, arena["f16"],
+                 arena["fl"], arena["dd"], arena["dead"],
+                 arena["jd"], arena["jp"], qargs, *_consts(),
+                 k=16, n_inc=1, n_exc=0, r=r, inc_ms=(m,), exc_ms=())
     c = RF.cost("_rank_join_batch_kernel", r=r, m=m, n_inc=1, n_exc=0,
                 bs=1, k=16)
     _close(c.flops, flops, f"join[{r},{m}] flops")
-    _close(c.xla_bytes, by, f"join[{r},{m}] bytes")
 
 
 @pytest.mark.parametrize("r,bs", ((65536, 1), (131072, 1), (65536, 4)))
 def test_xla_rank_join_bm(arena, r, bs):
     from yacy_search_server_tpu.index import devstore as DS
     qargs = np.zeros((bs, 9), np.int32)
-    flops, by = _xla(DS._rank_join_bm_batch_kernel, arena["f16"],
-                     arena["fl"], arena["dd"], arena["dead"],
-                     arena["jd"], arena["jp"], arena["bmtab"], qargs,
-                     *_consts(), k=16, n_inc=1, n_exc=0, r=r,
-                     inc_ms=(0,), exc_ms=(), inc_bm=(True,), exc_bm=())
+    flops = _xla(DS._rank_join_bm_batch_kernel, arena["f16"],
+                 arena["fl"], arena["dd"], arena["dead"],
+                 arena["jd"], arena["jp"], arena["bmtab"], qargs,
+                 *_consts(), k=16, n_inc=1, n_exc=0, r=r,
+                 inc_ms=(0,), exc_ms=(), inc_bm=(True,), exc_bm=())
     c = RF.cost("_rank_join_bm_batch_kernel", r=r, n_inc=1, n_exc=0,
-                bs=bs, k=16, doc_cap=1 << 16, jcap=1 << 17, nslots=2,
-                nwords=1 << 15)
+                bs=bs, k=16)
     _close(c.flops, flops, f"join_bm[{r},{bs}] flops")
-    _close(c.xla_bytes, by, f"join_bm[{r},{bs}] bytes")
 
 
 @pytest.mark.parametrize("k", (16, 128))
@@ -428,7 +412,7 @@ def test_xla_rank_spans(arena, k):
               jnp.full(1, -1, jnp.int32))
     zero_ext = (np.zeros(P.NF, np.int32), np.zeros(P.NF, np.int32),
                 np.float32(0), np.float32(0))
-    flops, by = _xla(
+    flops = _xla(
         DS._rank_spans_kernel, arena["f16"], arena["fl"], arena["dd"],
         arena["dead"], np.zeros(ns, np.int32), np.zeros(ns, np.int32),
         *d_args, jnp.zeros(1, jnp.uint32), np.int32(DS.NO_LANG),
@@ -439,18 +423,17 @@ def test_xla_rank_spans(arena, k):
     c = RF.cost("_rank_spans_kernel", rows=ns * arena["TILE"],
                 n_spans=ns, k=k)
     _close(c.flops, flops, f"spans[{k}] flops")
-    _close(c.xla_bytes, by, f"spans[{k}] bytes")
 
 
 # -- roofline math -----------------------------------------------------------
 
 def test_bound_verdict_and_util():
     peak = RF.DevicePeak("test", 100e12, 1e12)   # ridge = 100 flops/byte
-    mem = RF.roofline_point("m", RF.Cost(10e9, 1e9, 1e9), 0.01, peak)
+    mem = RF.roofline_point("m", RF.Cost(10e9, 1e9), 0.01, peak)
     assert mem.bound == "memory"
     # 1e9 bytes in 10 ms = 100 GB/s of a 1000 GB/s peak -> 10%
     assert mem.util_pct == pytest.approx(10.0, rel=1e-6)
-    comp = RF.roofline_point("c", RF.Cost(200e9, 1e9, 1e9), 0.01, peak)
+    comp = RF.roofline_point("c", RF.Cost(200e9, 1e9), 0.01, peak)
     assert comp.bound == "compute"
     # 200e9 flops in 10 ms = 20 TFLOP/s of 100 TFLOP/s -> 20%
     assert comp.util_pct == pytest.approx(20.0, rel=1e-6)
@@ -472,36 +455,6 @@ def test_ascii_table_renders():
                              0.005, peak)]
     table = RF.ascii_table(pts, peak)
     assert "score_topk16" in table and "util%" in table
-
-
-@pytest.mark.slow
-def test_bench_roofline_mode_emits_every_kernel():
-    """`bench.py --roofline` end to end at a small block size: one
-    roofline_kernel JSON line per registered kernel, plus the summary
-    with per-query util percentiles (the BENCH artifact contract)."""
-    import json
-    import subprocess
-    import sys as _sys
-
-    proc = subprocess.run(
-        [_sys.executable, "bench.py", "--roofline", "--n", "40000"],
-        capture_output=True, text=True, timeout=1200,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        or ".", env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    recs = [json.loads(ln) for ln in proc.stdout.splitlines()
-            if ln.startswith("{")]
-    summary = [r for r in recs if r["metric"] == "roofline_summary"]
-    kernels = {r["kernel"]: r for r in recs
-               if r["metric"] == "roofline_kernel"}
-    assert len(summary) == 1
-    assert {"util_pct_p50", "util_pct_p95", "bound"} <= set(summary[0])
-    assert set(kernels) == set(RF.registered())
-    for r in kernels.values():
-        assert r["flops"] > 0 and r["bytes"] > 0
-        assert r["achieved_gflops_s"] > 0 and r["achieved_gbps"] > 0
-        assert 0 < r["util_pct"] <= 100
-        assert r["bound"] in ("memory", "compute")
 
 
 # -- profiler ----------------------------------------------------------------
@@ -527,7 +480,7 @@ def test_profiler_records_and_query_util():
 
 def test_profiler_overhead_under_one_percent():
     """record() rides the serving hot path: the latency it adds to a
-    1k-query microbench must stay < 1% of the bench's baseline wall.
+    1k-query microbench must stay < 1% of its baseline wall.
 
     The added latency is measured directly (amortized record() cost ×
     1k calls) rather than as an A/B wall-clock difference: on a shared

@@ -17,6 +17,7 @@ from yacy_search_server_tpu.index.devstore import DeviceSegmentStore
 from yacy_search_server_tpu.index.postings import PostingsList
 from yacy_search_server_tpu.index.rwi import RWIIndex
 from yacy_search_server_tpu.ops.ranking import RankingProfile
+from yacy_search_server_tpu.utils.profiler import PROFILER
 
 TERMS = [b"scanterm0AAA", b"scanterm1AAA"]
 
@@ -93,10 +94,11 @@ def test_batched_scan_matches_solo_and_actually_batches():
         assert batched.stream_scans >= len(expected)
         assert c["batch_exceptions"] == exc0
         assert c["batch_timeout_worker_stall"] == stall0
-        # the rank-service stats carry the silicon-accounting fields
-        assert c["util_pct_p50"] > 0
-        assert c["util_pct_p95"] >= c["util_pct_p50"]
-        assert c["bound"] in ("memory", "compute")
+        # the scan dispatches filed their silicon-accounting samples
+        util = PROFILER.query_util()
+        assert util["util_pct_p50"] > 0
+        assert util["util_pct_p95"] >= util["util_pct_p50"]
+        assert util["bound"] in ("memory", "compute")
         assert c["batch_timeouts"] == (c["batch_timeout_queue_full"]
                                        + c["batch_timeout_flush_deadline"]
                                        + c["batch_timeout_worker_stall"])

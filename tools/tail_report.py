@@ -1,12 +1,10 @@
 #!/usr/bin/env python3
 """Render a tail-forensics view as operator tables (ISSUE 15 tooling).
 
-Input: a committed ``TAIL_r01.json`` artifact (bench.py
---tail-forensics), or a live ``Performance_Tail_p?format=json`` export
-— both carry the same verdict-ring / cause-histogram / scoreboard /
-waterfall shape.
+Input: a ``Performance_Tail_p?format=json`` export (live, or saved to
+a file): the verdict ring, cause histogram, scoreboard and waterfall.
 
-    python tools/tail_report.py TAIL_r01.json
+    python tools/tail_report.py tail.json
     curl -s 'http://localhost:8090/Performance_Tail_p.html?format=json' \
         | python tools/tail_report.py -
 """
@@ -109,8 +107,8 @@ def render(view: dict) -> str:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("path", help="TAIL_r01.json / Performance_Tail_p "
-                                 "json export, or - for stdin")
+    ap.add_argument("path", help="a Performance_Tail_p json export, "
+                                 "or - for stdin")
     args = ap.parse_args(argv)
     if args.path == "-":
         view = json.load(sys.stdin)

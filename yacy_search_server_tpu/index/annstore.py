@@ -636,8 +636,8 @@ class AnnVectorIndex:
 
     def exact_topk(self, qvec, k: int, chunk: int = 1 << 19):
         """The exact host oracle over the WHOLE quantized corpus
-        (chunked full scan) — the recall denominator for bench
-        --dense-first and the recall tests. Same quantized score
+        (chunked full scan) — the recall denominator of the recall tests
+        (tests/test_ann.py). Same quantized score
         domain as the probe path; (score DESC, docid ASC) ties."""
         q = np.asarray(qvec, np.float32)
         # one consistent ref snapshot: build() replaces these arrays

@@ -1851,15 +1851,7 @@ class _QueryBatcher:
         # compile-vs-reuse bit of the per-wave stamp (ISSUE 15b):
         # first use of a jitted kernel pays its compile in issue_ms
         self._seen_kernels: set[str] = set()
-        # per-QUERY time series (bounded): the wall of the dispatch a
-        # query rode in, and the kernel-call+fetch wall of its group —
-        # the decomposition that makes the local-attach p50 claim
-        # computable (VERDICT r4 #3: p50_local = host + kernel, with
-        # kernel separated from the dispatch round trip)
-        from collections import deque
-        self._ms_lock = threading.Lock()   # extends race counters() reads
-        self.query_dispatch_ms: "deque" = deque(maxlen=20000)
-        self.query_kernel_ms: "deque" = deque(maxlen=20000)
+        self._ms_lock = threading.Lock()   # the counters above
         # ONE batch-former + a POOL of dispatcher threads. The former
         # owns the incoming queue, so a concurrent burst lands in FULL
         # batches (competing dispatchers would fragment it ~max_batch/4
@@ -2353,7 +2345,7 @@ class _QueryBatcher:
                            kernel_name: str, t0: float,
                            issue_ms: float) -> None:
         """Hand an ISSUED (in-flight) kernel call to the completer pool;
-        with pipelining off (bench A/B windows) the fetch runs inline —
+        with pipelining off (`index.device.pipeline`) the fetch runs inline —
         the pre-pipeline behavior, bit-identical results either way."""
         if tailattr.enabled():
             self._stamp_wave(items, kernel_name, issue_ms)
@@ -2512,7 +2504,6 @@ class _QueryBatcher:
             return
         ms = (time.perf_counter() - rec["t0"]) * 1000.0
         with self._ms_lock:
-            self.query_dispatch_ms.extend([ms] * len(items))
             if ms > self.dispatch_ms_max:
                 self.dispatch_ms_max = ms
         if ms > 1000.0:
@@ -2604,15 +2595,11 @@ class _QueryBatcher:
                     *consts, k=kk, maxt=maxt, bs=nbs)
             issue_ms = (time.perf_counter() - t0k) * 1000.0
 
-            def finish(host, items=items, kk=kk, maxt=maxt, t0k=t0k,
-                       feats16=feats16, dead=dead, pmax=pmax, b=b):
+            def finish(host, items=items, kk=kk, maxt=maxt, t0k=t0k, b=b):
                 s = host[:, :kk]
                 d = host[:, kk:2 * kk]
                 ok = host[:, 2 * kk] != 0
                 wall = time.perf_counter() - t0k
-                with self._ms_lock:
-                    self.query_kernel_ms.extend(
-                        [wall * 1000.0] * len(items))
                 for it in items:   # trace stamps: re-emitted by submitters
                     it["kernel_ms"] = wall * 1000.0
                 # silicon accounting: the device share of this dispatch
@@ -2622,8 +2609,7 @@ class _QueryBatcher:
                     "_rank_pruned_batch1_packed_kernel",
                     max(wall - store.dispatch_rt_ms / 1e3, 1e-6),
                     queries=len(items), bs=len(items), tile=TILE,
-                    maxt=maxt, k=kk, cap=int(feats16.shape[0]),
-                    doc_cap=int(dead.shape[0]), tcap=int(pmax.shape[0]))
+                    maxt=maxt, k=kk)
                 # up to `dispatchers` completers run finishes
                 # concurrently: the store counters need the lock too
                 with store._lock:
@@ -2683,22 +2669,18 @@ class _QueryBatcher:
         row_bits = sum(it["span"].row_bits for it in items) / len(items)
 
         def finish(host, items=items, kk=kk, maxt=maxt, t0k=t0k,
-                   pwords=pwords, dead=dead, pmax=pmax,
                    row_bits=row_bits):
             s = host[:, :kk]
             d = host[:, kk:2 * kk]
             ok = host[:, 2 * kk] != 0
             wall = time.perf_counter() - t0k
-            with self._ms_lock:
-                self.query_kernel_ms.extend([wall * 1000.0] * len(items))
             for it in items:
                 it["kernel_ms"] = wall * 1000.0
             PROFILER.record(
                 "_rank_pruned_batch1_bp_kernel",
                 max(wall - store.dispatch_rt_ms / 1e3, 1e-6),
                 queries=len(items), bs=len(items), tile=TILE, maxt=maxt,
-                k=kk, row_bits=row_bits, pw_cap=int(pwords.shape[0]),
-                doc_cap=int(dead.shape[0]), tcap=int(pmax.shape[0]))
+                k=kk, row_bits=row_bits)
             with store._lock:
                 store.prune_rounds += 1
                 for i, it in enumerate(items):
@@ -2816,9 +2798,6 @@ class _QueryBatcher:
                     s = host[:, :kk]
                     d = host[:, kk:]
                     wall = time.perf_counter() - t0k
-                    with self._ms_lock:
-                        self.query_kernel_ms.extend([wall * 1000.0]
-                                                    * len(chunk))
                     for it in chunk:
                         it["kernel_ms"] = wall * 1000.0
                     PROFILER.record(
@@ -2869,16 +2848,13 @@ class _QueryBatcher:
                 def finish(host, chunk=chunk, nb=nb, t0k=t0k, fwd=fwd,
                            bs=bs):
                     wall = time.perf_counter() - t0k
-                    with self._ms_lock:
-                        self.query_kernel_ms.extend([wall * 1000.0]
-                                                    * len(chunk))
                     for it in chunk:
                         it["kernel_ms"] = wall * 1000.0
                     PROFILER.record(
                         "_rerank_fwd_batch_packed_kernel",
                         max(wall - store.dispatch_rt_ms / 1e3, 1e-6),
                         queries=len(chunk), bs=bs, nb=nb,
-                        dim=int(fwd.shape[1]), cap=int(fwd.shape[0]))
+                        dim=int(fwd.shape[1]))
                     results = [("ok", host[i, :it["n"]].copy(),
                                 host[i, nb:nb + it["n"]].copy())
                                for i, it in enumerate(chunk)]
@@ -2960,17 +2936,13 @@ class _QueryBatcher:
                 def finish(host, chunk=chunk, nb=nb, kk=kk, t0k=t0k,
                            bs=bs):
                     wall = time.perf_counter() - t0k
-                    with self._ms_lock:
-                        self.query_kernel_ms.extend([wall * 1000.0]
-                                                    * len(chunk))
                     for it in chunk:
                         it["kernel_ms"] = wall * 1000.0
                     PROFILER.record(
                         "_ann_fuse_batch_packed_kernel",
                         max(wall - store.dispatch_rt_ms / 1e3, 1e-6),
                         queries=len(chunk), bs=bs, nb=nb,
-                        dim=store._ann.dim,
-                        cap=int(store._ann._hot_cap), k=kk)
+                        dim=store._ann.dim, k=kk)
                     results = [("ok",) + store._ann_finish_slot(
                         it, (host[i, :kk], host[i, kk:2 * kk]), kk)
                         for i, it in enumerate(chunk)]
@@ -3069,9 +3041,6 @@ class _QueryBatcher:
                         s = host[:, :half]
                         d = host[:, half:]
                         wall = time.perf_counter() - t0k
-                        with self._ms_lock:
-                            self.query_kernel_ms.extend(
-                                [wall * 1000.0] * len(chunk))
                         for it in chunk:
                             it["kernel_ms"] = wall * 1000.0
                         windows = tuple(m for m in inc_ms + exc_ms if m)
@@ -3142,9 +3111,9 @@ class DeviceSegmentStore:
         self._pblocks: dict[tuple, dict] = {}
         self._warm_bytes = 0                # non-hot entries' packed bytes
         self._promote_inflight: set = set()
-        # the idle-path A/B switch (bench --tier-overhead): off skips the
-        # per-query LRU touch + miss-path tier lookups; serving itself is
-        # unchanged (hot answers stay hot)
+        # off skips the per-query LRU touch + miss-path tier lookups;
+        # serving itself is unchanged (hot answers stay hot) —
+        # tests/test_packed_residency.py holds that split
         self._tiering_enabled = True
         self.tier_hot_hits = 0              # packed-resident answers
         self.tier_warm_hits = 0             # host-RAM block found on miss
@@ -3206,7 +3175,7 @@ class DeviceSegmentStore:
         self._topk_cache = _TopkCache()
         # device round trips on the serving path (one kernel-call+fetch
         # cycle each); rt_per_query = round trips / queries served is
-        # the bench's pipelining/caching surface (BASELINE.md)
+        # what pipelining and the top-k cache show up in (DeviceStore_p)
         self.device_round_trips = 0
         self.prune_rounds = 0    # pruned-kernel dispatches (incl. escalations)
         self.pruned_tiles = 0    # tiles skipped by bound verification
@@ -3226,8 +3195,8 @@ class DeviceSegmentStore:
         #   (every exclusion was a nonexistent term)
         # batched dense rerank (the hybrid second stage as a pipeline
         # kernel family — ROADMAP item 1): dispatches vs queries gives
-        # the mean coalescing factor the bench gate asserts (>1 under
-        # concurrent hybrid load); cache hits serve with ZERO device
+        # the mean coalescing factor (>1 under concurrent hybrid load:
+        # tests/test_rerank_batching.py); cache hits serve with ZERO device
         # work; fallbacks took the host-gather legacy path
         self.rerank_dispatches = 0
         self.rerank_queries = 0
@@ -4340,7 +4309,7 @@ class DeviceSegmentStore:
         """Block until the background prewarm covers the CURRENT arena
         shapes (or timeout). Serving-before-warm is only a latency
         hazard, never a correctness one — but a deployment (and the
-        bench) that can afford to warm at startup should: a compile
+        benchmark) that can afford to warm at startup should: a compile
         landing mid-traffic stalls the wave that needs it."""
         if not getattr(self, "_prewarm_on", False):
             return True
@@ -4379,13 +4348,6 @@ class DeviceSegmentStore:
         except Exception:
             log.exception("dispatch RT measurement failed")
         return self.dispatch_rt_ms
-
-    @staticmethod
-    def _pctl(series, q: float) -> float:
-        sv = sorted(series)
-        if not sv:
-            return 0.0
-        return round(sv[min(len(sv) - 1, int(len(sv) * q))], 1)
 
     def tier_bytes(self) -> dict:
         """Byte occupancy per residency tier: hot = device bytes the
@@ -4433,44 +4395,20 @@ class DeviceSegmentStore:
         return round(orig / packed, 3) if packed else 1.0
 
     def counters(self) -> dict:
-        """Serving-health counters (the headline bench emits these —
-        VERDICT r3 #1: a silent stall must never hide again).
-
-        `dispatch_ms_p50/p95` are per-QUERY walls of the batch dispatch
-        each query rode in; `kernel_ms_p50/p95` are the kernel-call+fetch
-        walls minus the measured trivial round trip (`dispatch_rt_ms`) —
-        i.e. the device-time share that survives on locally-attached
-        hardware, making p50_local = host_ms + kernel_ms_p50 a
-        computable claim rather than arithmetic-by-assertion."""
+        """Serving-health counters (a silent stall must never hide):
+        integers and gauges only. Dispatch and kernel WALLS are the span
+        families `devstore.batch` / `kernel.*` on the tracing clock."""
         b = self._batcher
-        if b:
-            with b._ms_lock:
-                dseries = list(b.query_dispatch_ms)
-                kraw = list(b.query_kernel_ms)
-        else:
-            dseries, kraw = [], []
-        kseries = [max(0.0, v - self.dispatch_rt_ms) for v in kraw]
-        # per-query silicon accounting (ISSUE 1): each served query's
-        # utilization vs the device peak, and the dominant roofline
-        # verdict — the hardware-relative numbers every perf claim rides
-        util = PROFILER.query_util()
         tb = self.tier_bytes()
         self._lock.acquire()     # reentrant: one consistent counter view
         try:
-            return self._counters_locked(b, util, tb, dseries, kseries)
+            return self._counters_locked(b, tb)
         finally:
             self._lock.release()
 
-    def _counters_locked(self, b, util, tb, dseries, kseries) -> dict:
+    def _counters_locked(self, b, tb) -> dict:
         return {
             "dispatch_rt_ms": self.dispatch_rt_ms,
-            "util_pct_p50": util["util_pct_p50"],
-            "util_pct_p95": util["util_pct_p95"],
-            "bound": util["bound"],
-            "dispatch_ms_p50": self._pctl(dseries, 0.50),
-            "dispatch_ms_p95": self._pctl(dseries, 0.95),
-            "kernel_ms_p50": self._pctl(kseries, 0.50),
-            "kernel_ms_p95": self._pctl(kseries, 0.95),
             "queries_served": self.queries_served,
             "fallbacks": self.fallbacks,
             # device-loss recovery (ISSUE 10c): 0/1 lost flag, declared
@@ -4483,8 +4421,7 @@ class DeviceSegmentStore:
             "transfer_failures": self.transfer_failures,
             "transfer_retries": self.transfer_retries,
             # read-side integrity (ISSUE 10a): corruption detections and
-            # torn-tail recoveries ride the headline artifact through
-            # these totals (asserted zero on a healthy soak)
+            # torn-tail recoveries (zero on a healthy node)
             "storage_corruptions": integrity.corruption_total(),
             "journal_torn_tails": sum(
                 integrity.torn_tail_counts().values()),
@@ -4498,7 +4435,7 @@ class DeviceSegmentStore:
             "rank_cache_stale_served": self._topk_cache.stale_served,
             "arena_epoch": self.arena_epoch,
             # serving-path kernel-call+fetch cycles; ÷ queries_served =
-            # rt_per_query (the bench's pipelining/caching surface)
+            # rt_per_query
             "device_round_trips": self.device_round_trips,
             "prune_rounds": self.prune_rounds,
             "pruned_tiles": self.pruned_tiles,
@@ -4541,7 +4478,7 @@ class DeviceSegmentStore:
             "dense_fwd_bytes": self._dense_fwd_bytes(),
             # compressed residency + tier ladder (ISSUE 8): per-tier
             # hit/promotion/eviction counters and byte occupancy — the
-            # paging behavior must be attributable in every artifact
+            # paging behavior must be attributable
             "tier_hot_hits": self.tier_hot_hits,
             "tier_warm_hits": self.tier_warm_hits,
             "tier_cold_hits": self.tier_cold_hits,
@@ -4709,8 +4646,8 @@ class DeviceSegmentStore:
                   from_days: int | None = None, to_days: int | None = None):
         """Coverage-counting wrapper around the device conjunction: every
         eligible-shaped query lands in join_served, join_fallbacks, or
-        join_degraded_plain (the mixed-load coverage surface bench
-        config 8 reports)."""
+        join_degraded_plain (the mixed-load coverage the benchmark's
+        `device_answer_pct` / `join_declined_pct` read)."""
         if self.device_lost:
             # device lost (ISSUE 10c): host conjunction serves, counted
             with self._lock:
@@ -5178,8 +5115,7 @@ class DeviceSegmentStore:
         PROFILER.record(
             "_rerank_fwd_batch_packed_kernel",
             max(time.perf_counter() - t0 - self.dispatch_rt_ms / 1e3, 1e-6),
-            queries=1, bs=bs, nb=nb, dim=int(fwd.shape[1]),
-            cap=int(fwd.shape[0]))
+            queries=1, bs=bs, nb=nb, dim=int(fwd.shape[1]))
         with self._lock:
             self.rerank_dispatches += 1
             self.rerank_queries += 1
@@ -5474,8 +5410,7 @@ class DeviceSegmentStore:
                 "_ann_fuse_batch_packed_kernel",
                 max(time.perf_counter() - t0 - self.dispatch_rt_ms / 1e3,
                     1e-6),
-                queries=1, bs=bs, nb=nb, dim=self._ann.dim,
-                cap=int(self._ann._hot_cap), k=kk)
+                queries=1, bs=bs, nb=nb, dim=self._ann.dim, k=kk)
             res = self._ann_finish_slot(slot, (host[0, :kk],
                                                host[0, kk:2 * kk]), kk)
             with self._lock:
@@ -5551,8 +5486,7 @@ class DeviceSegmentStore:
             "_rank_pruned_batch1_bp_kernel",
             max(time.perf_counter() - t0 - self.dispatch_rt_ms / 1e3, 1e-6),
             queries=1, bs=1, tile=TILE, maxt=maxt, k=kk,
-            row_bits=sp.row_bits, pw_cap=int(pwords.shape[0]),
-            doc_cap=int(dead.shape[0]), tcap=int(pmax.shape[0]))
+            row_bits=sp.row_bits)
         return (host[0, :kk], host[0, kk:2 * kk],
                 bool(host[0, 2 * kk]))
 
@@ -5587,8 +5521,7 @@ class DeviceSegmentStore:
         PROFILER.record(
             "_rank_scan_batch_bp_kernel",
             max(time.perf_counter() - t0 - self.dispatch_rt_ms / 1e3, 1e-6),
-            queries=1, rows=rows, k=kk, bs=bs, row_bits=sp.row_bits,
-            pw_cap=int(pwords.shape[0]), doc_cap=int(dead.shape[0]))
+            queries=1, rows=rows, k=kk, bs=bs, row_bits=sp.row_bits)
         return host[0, :kk], host[0, kk:]
 
     def _rank_term_packed(self, termhash: bytes, profile, language: str,
@@ -5875,10 +5808,7 @@ class DeviceSegmentStore:
                     PROFILER.record(
                         "_rank_pruned_batch1_packed_kernel", wall,
                         queries=1 if ok else 0, bs=1, tile=TILE,
-                        maxt=_pmax_window(self._max_tcount), k=kk,
-                        cap=int(feats16.shape[0]),
-                        doc_cap=int(dead.shape[0]),
-                        tcap=int(pmax.shape[0]))
+                        maxt=_pmax_window(self._max_tcount), k=kk)
                 else:
                     PROFILER.record("_rank_pruned_kernel", wall,
                                     queries=1 if ok else 0,

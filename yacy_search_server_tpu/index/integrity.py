@@ -15,9 +15,10 @@ shared substrate:
   per-column checksums in colstore segment headers (verified once per
   reader, on first touch), and crc-prefixed journal lines
   (``<crc8hex> <payload>``) on the metadata/webgraph/rwi journals.
-- **verify switch**: :data:`VERIFY_ON_READ` is the global A/B toggle
-  ``bench.py --integrity-overhead`` measures (gate: <2% p50).  Writers
-  ALWAYS emit checksums; only read-side verification toggles.
+- **verify switch**: :data:`VERIFY_ON_READ` turns read-side
+  verification off (tests/test_integrity.py, tests/test_dense.py: a
+  corrupt-crc file then still loads).  Writers ALWAYS emit checksums;
+  only read-side verification toggles.
 - **corruption counters**: every detection increments
   ``yacy_storage_corruption_total{kind,action}`` via
   :func:`note_corruption`; quarantine actions (a corrupt run pulled
@@ -43,8 +44,8 @@ import os
 import threading
 import zlib
 
-# the read-side verification switch (bench --integrity-overhead A/B);
-# checksums are always WRITTEN — only verification toggles
+# the read-side verification switch; checksums are always WRITTEN —
+# only verification toggles
 VERIFY_ON_READ = True
 
 

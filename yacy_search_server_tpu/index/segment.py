@@ -157,7 +157,7 @@ class Segment:
         """Multi-chip serving: partition the arena over a ('term','doc')
         mesh and run eligible queries as one SPMD program
         (index/meshstore.py — VERDICT r2 #1: multi-chip is the product
-        path, not a bench demo; reference DHT axes
+        path, not a demo; reference DHT axes
         cora/federate/yacy/Distribution.java:35-93)."""
         from .meshstore import MeshSegmentStore
         if self.devstore is None:

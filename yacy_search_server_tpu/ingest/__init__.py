@@ -26,12 +26,11 @@ read path earned over rounds 6–16:
   node is healthy again, with pinned series, breadcrumbs and the
   no-dead-actuators hygiene gate.
 
-``bench.py --ingest-soak`` proves the whole loop: sustained indexing at
-N docs/s under the standard query soak, gating serving p95 regression,
-crawl-to-searchable p95 per tier, the deferral actuator engaging under
-an injected burst, and zero acked-doc loss across mid-soak kill−9
-crash points (committed as INGEST_r01.json; ``--smoke`` is the tier-1
-variant).
+Held by tests/test_ingest.py (tracker, scheduler, deferral, SLO rule)
+and tests/test_crash_consistency.py (no acknowledged write lost across
+kill−9 crash points).  The timed soak of writes under reads — serving
+p95 and crawl-to-searchable p95 per tier while indexing at N docs/s —
+has no benchmark cell yet: ROADMAP R2 carries its gate list.
 
 Import discipline: this package root (and :mod:`slo` / :mod:`scheduler`)
 stays jax-free — the crash-chaos subprocess harness imports the RWI

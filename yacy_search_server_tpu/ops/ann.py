@@ -48,8 +48,8 @@ from jax import lax
 from .dense import DENSE_BOOST_SCALE
 
 # default probe width: clusters scored per query. The serving knob is
-# index.ann.nprobe (devstore.ann_nprobe); this is the bench/test anchor
-# the recall gate is stated at.
+# index.ann.nprobe (devstore.ann_nprobe); this is the anchor the
+# recall gate (tests/test_ann.py) is stated at.
 ANN_DEFAULT_NPROBE = 8
 # per-query probe lane budget (pow2): bounds the gather width of one
 # fuse dispatch — the index.ann.probeLanes knob. Probes past the budget

@@ -1,29 +1,22 @@
 """Roofline cost accounting — every serving kernel gets a silicon number.
 
-The perf story so far measured kernels against CPU twins (bench.py
-`vs_baseline`); nothing said how far from the HARDWARE's ceiling a kernel
-runs (VERDICT r5 weak #7: "no hardware-relative utilization number exists
-anywhere"). This module is the analytical half of that accounting:
+How far from the HARDWARE's ceiling does a kernel run? This module is
+the analytical half of that accounting:
 
 - a **cost model registry**: for each named serving kernel, closed-form
-  FLOPs / bytes-moved as functions of its shape parameters. Two byte
-  models per kernel, because they answer different questions:
+  FLOPs / bytes-moved as functions of its shape parameters.
 
-  * ``bytes``  — COMPULSORY traffic: operands that must stream from HBM
+  * ``bytes`` — COMPULSORY traffic: operands that must stream from HBM
     plus results written back, assuming perfect fusion (the roofline
     denominator — achieved GB/s against the HBM peak is only meaningful
-    over bytes that physically must move).
-  * ``xla_bytes`` — fusion-boundary traffic as XLA's HloCostAnalysis
-    models it (operand + output bytes of each fusion, whole operand
-    arrays counted for dynamic-slice reads). Coefficients are calibrated
-    against ``jax.jit(...).lower().compile().cost_analysis()`` on the CPU
+    over bytes that physically must move). Counted from array shapes;
+    it does not move with the compiler.
+  * ``flops`` follows XLA's arithmetic-op counting (elementwise int ops
+    count as flops). The coefficients are calibrated against
+    ``jax.jit(...).lower().compile().cost_analysis()`` on the CPU
     backend and PINNED BY TEST (tests/test_roofline.py: within 10% on 3
     representative shapes per kernel) — a kernel edit that changes the
-    dataflow breaks the pin and forces the model to be re-derived.
-
-  ``flops`` follows XLA's arithmetic-op counting (elementwise int ops
-  count as flops), so one number serves both the cross-check and the
-  achieved-FLOP/s roofline axis.
+    arithmetic breaks the pin and forces the model to be re-derived.
 
 - a **per-device peak table** (TPU generations + the CPU test backend),
   overridable via config/env — utilization is stated against a DECLARED
@@ -65,7 +58,6 @@ class Cost:
 
     flops: float       # arithmetic ops (XLA counting conventions)
     bytes: float       # compulsory HBM traffic (roofline denominator)
-    xla_bytes: float   # fusion-boundary traffic (cost_analysis parity)
 
     @property
     def intensity(self) -> float:
@@ -167,9 +159,9 @@ def roofline_point(kernel: str, cost: Cost, wall_s: float,
 # ---------------------------------------------------------------------------
 # Per-row coefficient provenance: compulsory bytes are counted from the
 # arrays the kernel streams (ROW_BYTES per candidate row, plus gathers /
-# side-tables / outputs); flops and xla_bytes coefficients are calibrated
-# against the CPU-backend HloCostAnalysis (jax 0.4.37) and pinned by
-# tests/test_roofline.py — each entry's comment records the fit.
+# side-tables / outputs); flops coefficients are calibrated against the
+# CPU-backend HloCostAnalysis and pinned by tests/test_roofline.py —
+# each entry's comment records the fit.
 #
 # Loop-carried kernels (lax.scan / fori_loop / lax.map bodies) are modeled
 # PER EXECUTED STEP × trip count; HloCostAnalysis counts a loop body once
@@ -178,71 +170,52 @@ def roofline_point(kernel: str, cost: Cost, wall_s: float,
 
 # cardinal scorer over a compact block (ops/ranking.cardinal_scores16):
 # stats + normalize + shifted sum + tf + flags. XLA (no-authority trace):
-# 529 flops/row, 438.3 xla-bytes/row, constant over n in [4k, 131k]
+# 529 flops/row, constant over n in [4k, 131k]
 _CARDINAL_FLOPS_ROW = 529.0
-_CARDINAL_XBYTES_ROW = 438.3
-# + fused lax.top_k (score_topk16): 544 / 454.3 per row at serving k's
+# + fused lax.top_k (score_topk16): 544 per row at serving k's
 _TOPK16_FLOPS_ROW = 544.0
-_TOPK16_XBYTES_ROW = 454.3
-# int32 twin (score_topk): 456 flops/row, 587.6 xla-bytes/row (wider
-# reads, no int16 widening ops)
+# int32 twin (score_topk): 456 flops/row (no int16 widening ops)
 _TOPK32_FLOPS_ROW = 456.0
-_TOPK32_XBYTES_ROW = 587.6
-# scan_score_topk loop body (stats precomputed; score + merge per tile):
-# 439 flops/row; 59 xla-bytes/row on a >=2-step trace
+# scan_score_topk loop body (stats precomputed; score + merge per tile)
 _SCAN_FLOPS_ROW = 439.0
-_SCAN_XBYTES_ROW = 59.0
 # streaming stats pass (ops/ranking.local_stats, no host counts)
 _STATS_FLOPS_ROW = 113.0
-_STATS_XBYTES_ROW = 387.3
 # devstore streamed spans kernel: stats + score passes per tile plus the
-# constraint mask; each span's fori body counts once: 673 flops and
-# 587 xla-bytes per (span, TILE-row)
+# constraint mask; each span's fori body counts once: 673 flops per
+# (span, TILE-row)
 _SPANS_FLOPS_ROW = 673.0
-_SPANS_XBYTES_ROW = 587.0
 # b=1 vmapped pruned kernel: one scored tile per slot; vmap (unlike
-# lax.map) scales the count with bs: 453 flops/row; xla bytes are a
-# 36.4/row slope over the scored tiles plus the whole-operand arena
-# arrays (dynamic_slice reads charge the full operand in the XLA model)
+# lax.map) scales the count with bs: 453 flops/row
 _PRUNED1_FLOPS_ROW = 453.0
-_PRUNED1_XBYTES_ROW = 36.4
 # pruned escalation kernel body (lax.map slot × fori tile, counted once)
 _PRUNEDB_FLOPS_ROW = 449.0
-_PRUNEDB_XBYTES_ROW = 64.6
 # sort-merge join: fit over (r, m) at n_inc=1/n_exc=0, bs=1:
-# flops = 560·r + 34·m; xla_bytes = 762·r + 90·m
+# flops = 560·r + 34·m
 _JOIN_FLOPS_R, _JOIN_FLOPS_M = 560.0, 34.0
-_JOIN_XBYTES_R, _JOIN_XBYTES_M = 762.2, 90.1
-# bitmap-membership join: 607 flops/row·slot; 747 xla-bytes/row·slot
-# plus the side-table operands
+# bitmap-membership join: 607 flops/row·slot
 _JOINBM_FLOPS_ROW = 607.0
-_JOINBM_XBYTES_ROW = 747.2
 
 
 def _c_cardinal_scores16(n: int) -> Cost:
     return Cost(flops=_CARDINAL_FLOPS_ROW * n,
-                bytes=ROW_BYTES * n + 4 * n,      # feats+flags + i32 out
-                xla_bytes=_CARDINAL_XBYTES_ROW * n)
+                bytes=ROW_BYTES * n + 4 * n)      # feats+flags + i32 out
 
 
 def _c_score_topk16(n: int, k: int = 16) -> Cost:
     return Cost(flops=_TOPK16_FLOPS_ROW * n,
-                bytes=ROW_BYTES * n + 8 * k,
-                xla_bytes=_TOPK16_XBYTES_ROW * n)
+                bytes=ROW_BYTES * n + 8 * k)
 
 
 def _c_score_topk(n: int, k: int = 16) -> Cost:
     return Cost(flops=_TOPK32_FLOPS_ROW * n,
-                bytes=(P.NF * 4 + 8) * n + 8 * k,
-                xla_bytes=_TOPK32_XBYTES_ROW * n)
+                bytes=(P.NF * 4 + 8) * n + 8 * k)
 
 
 def _c_scan_score_topk(n: int, k: int = 16, tile: int = 1 << 20) -> Cost:
     steps = max(1, -(-n // tile))
     rows = steps * tile
     return Cost(flops=_SCAN_FLOPS_ROW * rows,
-                bytes=ROW_BYTES * rows + 8 * k,
-                xla_bytes=_SCAN_XBYTES_ROW * rows)
+                bytes=ROW_BYTES * rows + 8 * k)
 
 
 def _c_stream_score_topk(n: int, k: int = 100, chunk: int = 1 << 21) -> Cost:
@@ -250,8 +223,7 @@ def _c_stream_score_topk(n: int, k: int = 100, chunk: int = 1 << 21) -> Cost:
     # score+merge) over every chunk — the composition of the calibrated
     # local_stats and scan-body coefficients
     return Cost(flops=(_STATS_FLOPS_ROW + _SCAN_FLOPS_ROW) * n,
-                bytes=2 * ROW_BYTES * n + 8 * k,
-                xla_bytes=(_STATS_XBYTES_ROW + _SCAN_XBYTES_ROW) * n)
+                bytes=2 * ROW_BYTES * n + 8 * k)
 
 
 def _c_rank_spans(rows: int, n_spans: int = 8, k: int = 16,
@@ -263,27 +235,20 @@ def _c_rank_spans(rows: int, n_spans: int = 8, k: int = 16,
     cached-ext-stats twin: pass 1 skipped, half the streamed reads
     (673 = 113 stats + 560 score per row — the coefficients compose)."""
     if with_stats_pass:
-        flops, xbytes, passes = _SPANS_FLOPS_ROW, _SPANS_XBYTES_ROW, 2
+        flops, passes = _SPANS_FLOPS_ROW, 2
     else:
-        flops = _SPANS_FLOPS_ROW - _STATS_FLOPS_ROW
-        xbytes = _SPANS_XBYTES_ROW - _STATS_XBYTES_ROW
-        passes = 1
+        flops, passes = _SPANS_FLOPS_ROW - _STATS_FLOPS_ROW, 1
     return Cost(flops=flops * rows,
-                bytes=passes * ROW_BYTES_DEAD * rows + 8 * k,
-                xla_bytes=xbytes * rows)
+                bytes=passes * ROW_BYTES_DEAD * rows + 8 * k)
 
 
 def _c_rank_pruned_batch1(bs: int, tile: int = 32_768, maxt: int = 64,
-                          k: int = 16, cap: int = 0, doc_cap: int = 0,
-                          tcap: int = 0) -> Cost:
+                          k: int = 16) -> Cost:
     """The steady-state b=1 batched pruned kernel: each slot scores ONE
-    proxy-best tile and bound-walks its pmax tail. cap/doc_cap/tcap are
-    the arena capacities (whole-operand terms in the XLA byte model)."""
+    proxy-best tile and bound-walks its pmax tail."""
     rows = bs * tile
     return Cost(flops=_PRUNED1_FLOPS_ROW * rows,
-                bytes=ROW_BYTES_DEAD * rows + 4 * bs * maxt + 8 * bs * k,
-                xla_bytes=_PRUNED1_XBYTES_ROW * rows
-                + ROW_BYTES * cap + doc_cap + 4 * tcap)
+                bytes=ROW_BYTES_DEAD * rows + 4 * bs * maxt + 8 * bs * k)
 
 
 def _c_rank_pruned(b: int, tile: int = 32_768, bs: int = 1,
@@ -292,8 +257,7 @@ def _c_rank_pruned(b: int, tile: int = 32_768, bs: int = 1,
     over slots; unit-trip cost = one tile body)."""
     rows = bs * b * tile
     return Cost(flops=_PRUNEDB_FLOPS_ROW * rows,
-                bytes=ROW_BYTES_DEAD * rows + 8 * bs * k,
-                xla_bytes=_PRUNEDB_XBYTES_ROW * rows)
+                bytes=ROW_BYTES_DEAD * rows + 8 * bs * k)
 
 
 def _c_rank_join(r: int, m: int = 0, n_inc: int = 1, n_exc: int = 0,
@@ -307,72 +271,57 @@ def _c_rank_join(r: int, m: int = 0, n_inc: int = 1, n_exc: int = 0,
     # compulsory: rare rows once; per partner 12 B of gathered columns
     # per lane + the (docid, pos) segment streamed for the sort
     comp = bs * (ROW_BYTES_DEAD * r + partners * (12 * r + 8 * m) + 8 * k)
-    return Cost(flops=flops, bytes=comp,
-                xla_bytes=bs * (_JOIN_XBYTES_R * r
-                                + 292.0 * r * (partners - 1)
-                                + _JOIN_XBYTES_M * m * partners))
+    return Cost(flops=flops, bytes=comp)
 
 
 def _c_rank_join_bm(r: int, n_inc: int = 1, n_exc: int = 0, bs: int = 1,
-                    k: int = 16, doc_cap: int = 0, jcap: int = 0,
-                    nslots: int = 0, nwords: int = 0) -> Cost:
+                    k: int = 16) -> Cost:
     """Bitmap-membership conjunction: 2 gathers per lane per partner
     instead of the (r+m) sort — O(r) regardless of partner size."""
     partners = max(n_inc + n_exc, 1)
     flops = bs * r * (_JOINBM_FLOPS_ROW + 160.0 * (partners - 1))
     comp = bs * (ROW_BYTES_DEAD * r + partners * 20 * r + 8 * k)
-    side = doc_cap + 8 * jcap + 8 * nslots * nwords
-    return Cost(flops=flops, bytes=comp,
-                xla_bytes=bs * (_JOINBM_XBYTES_ROW
-                                + 300.0 * (partners - 1)) * r + side)
+    return Cost(flops=flops, bytes=comp)
 
 
 def _c_bm25_topk(n: int, t: int = 3, k: int = 16) -> Cost:
-    # XLA fit: flops = (6t + 10)/row and xla_bytes = (4t + 43.5)/row,
-    # exact at t in {3, 5, 8}
+    # XLA fit: flops = (6t + 10)/row, exact at t in {3, 5, 8}
     return Cost(flops=(6.0 * t + 10.0) * n,
-                bytes=(4 * t + 8) * n + 8 * k,
-                xla_bytes=(4.0 * t + 43.5) * n)
+                bytes=(4 * t + 8) * n + 8 * k)
 
 
 def _c_hybrid_rerank(n: int, dim: int = 256, k: int = 100) -> Cost:
     # matvec (2·dim) + normalize/blend/top_k; XLA: (4·dim + 11) flops
-    # and (4·dim + 43.5) bytes per row at dim 256. Compulsory traffic is
-    # the f32 doc-matrix read (bf16 cast happens in registers)
+    # per row at dim 256. Compulsory traffic is the f32 doc-matrix read
+    # (bf16 cast happens in registers)
     return Cost(flops=(4.0 * dim + 11.0) * n,
-                bytes=4 * n * dim + 5 * n + 8 * k,
-                xla_bytes=(4.0 * dim + 43.5) * n)
+                bytes=4 * n * dim + 5 * n + 8 * k)
 
 
 def _c_hybrid_rerank_batch(n: int, b: int = 16, dim: int = 256,
                            k: int = 100) -> Cost:
     """The MXU case: B queries amortize one doc-matrix read. XLA fit:
-    flops = 2·b·n·dim + 11·b·n + 2·dim·n; bytes = 12·dim·n + 43.6·b·n."""
+    flops = 2·b·n·dim + 11·b·n + 2·dim·n."""
     return Cost(flops=2.0 * b * n * dim + 11.0 * b * n + 2.0 * dim * n,
-                bytes=4 * n * dim + b * (5 * n + 8 * k),
-                xla_bytes=12.0 * dim * n + 43.6 * b * n)
+                bytes=4 * n * dim + b * (5 * n + 8 * k))
 
 
 def _c_dense_boost(n: int, dim: int = 256, k: int = 100) -> Cost:
     return Cost(flops=(4.0 * dim + 22.0) * n,
-                bytes=4 * n * dim + 9 * n + 8 * k,
-                xla_bytes=(4.0 * dim + 29.0) * n)
+                bytes=4 * n * dim + 9 * n + 8 * k)
 
 
 # batched forward-index rerank (the hybrid second stage as a batcher
 # kernel family): per candidate lane one dim-wide bf16 dot (2·dim) +
 # blend/round + the two-key (score, docid) tie sort ≈ 545, plus a
-# per-slot descriptor decode ≈ 650. XLA bytes: the whole-operand
-# forward index (gather charges the full array) + 2128/lane + 3086/slot
-# — exact at (nb, bs) in {16..1024}×{4..16}, dim 256 (jax 0.4.37 CPU)
+# per-slot descriptor decode ≈ 650 — exact at (nb, bs) in
+# {16..1024}×{4..16}, dim 256
 _RERANK_FLOPS_LANE_EXTRA = 545.0
 _RERANK_FLOPS_SLOT = 650.0
-_RERANK_XBYTES_LANE = 2128.0
-_RERANK_XBYTES_SLOT = 3086.0
 
 
-def _c_rerank_fwd_batch(bs: int = 16, nb: int = 128, dim: int = 256,
-                        cap: int = 0) -> Cost:
+def _c_rerank_fwd_batch(bs: int = 16, nb: int = 128,
+                        dim: int = 256) -> Cost:
     """_rerank_fwd_batch_packed_kernel: bs slots × nb candidate lanes
     gathering from a [cap, dim] f16 forward index. Compulsory traffic:
     the gathered doc vectors (2·dim B/lane), the fused descriptor in,
@@ -381,56 +330,44 @@ def _c_rerank_fwd_batch(bs: int = 16, nb: int = 128, dim: int = 256,
     return Cost(flops=(2.0 * dim + _RERANK_FLOPS_LANE_EXTRA) * lanes
                 + _RERANK_FLOPS_SLOT * bs,
                 bytes=2 * dim * lanes + 4 * (2 + 2 * nb + dim) * bs
-                + 8 * lanes,
-                xla_bytes=2 * cap * dim + _RERANK_XBYTES_LANE * lanes
-                + _RERANK_XBYTES_SLOT * bs)
+                + 8 * lanes)
 
 
 # bit-packed (*_bp) fused-decode scorers: the compulsory HBM stream is
 # the PACKED bytes (row_bits/8 per row — the whole point of the format)
 # plus the tombstone gather and outputs; decode adds ~6 int ops per
 # value (two word reads folded by shifts/masks) on top of the scoring
-# flops. XLA model: per-row slope + per-pw-word slope (each decode
-# gather charges the packed-words operand in HloCostAnalysis, so the
-# arena capacity enters with a multi-gather coefficient) + the dead/
-# pmax operands. Fits exact to <0.5% over bs in {1..16} × pw_cap in
-# {2^18, 2^20} (jax 0.4.x CPU); pinned by tests/test_roofline.py.
+# flops.  Both coefficients are fits to the module AS LOWERED (see
+# `xla_cost`): after the CPU pipeline has expanded the decode gathers,
+# HloCostAnalysis charges each one per element of the packed-words
+# operand (5 flops per arena word at jax 0.4.37, 26 at 0.9.0) — a cost
+# that follows arena capacity and the host's XLA build, not the work.
+# Lowered, the gather's charge is 1 per arena word (0.2-0.5% of the
+# pinned shapes) and is left out.
 _PRUNED1_BP_FLOPS_ROW = 890.0
-_PRUNED1_BP_FLOPS_PW = 5.0
-_PRUNED1_BP_XBYTES_ROW = 56.5
-_PRUNED1_BP_XBYTES_PW = 28.0
-_SCAN_BP_FLOPS_ROW = 1775.0
-_SCAN_BP_XBYTES_ROW = 847.0
-_SCAN_BP_XBYTES_PW = 88.0
+_SCAN_BP_FLOPS_ROW = 1491.0
 
 
 def _c_rank_pruned_batch1_bp(bs: int, tile: int = 32_768, maxt: int = 64,
-                             k: int = 16, row_bits: float = 160.0,
-                             pw_cap: int = 0, doc_cap: int = 0,
-                             tcap: int = 0) -> Cost:
+                             k: int = 16,
+                             row_bits: float = 160.0) -> Cost:
     """The b=1 pruned kernel over bit-packed spans: each slot decodes +
     scores ONE tile straight from the packed words. Compulsory bytes =
     packed payload (row_bits/8 per row) — compression is throughput on
     a memory-bound roofline."""
     rows = bs * tile
-    return Cost(flops=_PRUNED1_BP_FLOPS_ROW * rows
-                + _PRUNED1_BP_FLOPS_PW * pw_cap,
+    return Cost(flops=_PRUNED1_BP_FLOPS_ROW * rows,
                 bytes=(row_bits / 8.0 + 1) * rows + 4 * bs * maxt
-                + 8 * bs * k,
-                xla_bytes=_PRUNED1_BP_XBYTES_ROW * rows
-                + _PRUNED1_BP_XBYTES_PW * pw_cap + doc_cap + 4 * tcap)
+                + 8 * bs * k)
 
 
 def _c_rank_scan_batch_bp(rows: int, k: int = 16, bs: int = 1,
-                          row_bits: float = 160.0, pw_cap: int = 0,
-                          doc_cap: int = 0) -> Cost:
+                          row_bits: float = 160.0) -> Cost:
     """Exact two-pass scan over bit-packed spans (stats, then score):
     the packed payload streams twice, like the int16 scan's two passes
     over ROW_BYTES."""
-    return Cost(flops=_SCAN_BP_FLOPS_ROW * rows + pw_cap,
-                bytes=2 * (row_bits / 8.0 + 1) * rows + 8 * k,
-                xla_bytes=_SCAN_BP_XBYTES_ROW * rows
-                + _SCAN_BP_XBYTES_PW * pw_cap + 2 * doc_cap)
+    return Cost(flops=_SCAN_BP_FLOPS_ROW * rows,
+                bytes=2 * (row_bits / 8.0 + 1) * rows + 8 * k)
 
 
 # device-side index build (ingest/devbuild.py, ISSUE 13b): the vmapped
@@ -438,20 +375,14 @@ def _c_rank_scan_batch_bp(rows: int, k: int = 16, bs: int = 1,
 # derivation, offset/shift math and the two scatter-add lanes — ~43.5
 # flops/value × NCOLS values/row ≈ 826 flops/row, plus per-ROW reduce
 # setup XLA amortizes across lanes (76/row) and per-LANE meta/clz work
-# (5277/lane).  XLA bytes: the int16+int32 operand reads and the uint32
-# word-stream carried through 2·NCOLS scatter fusions (1165.5 B/row)
-# plus the per-lane meta build (4718 B/lane).  Both fits <1% over bs in
-# {2..16} × rows in {256..4096} (jax 0.4.x CPU); pinned by
-# tests/test_roofline.py.  Compulsory traffic: the block rows once in
-# (ROW_BYTES + 8) and the PACKED payload out (row_bits/8 per row) —
-# the same accounting the *_bp scorers state their reads in.
+# (5277/lane); <1% over bs in {2..16} × rows in {256..4096}.
+# Compulsory traffic: the block rows once in (ROW_BYTES + 8) and the
+# PACKED payload out (row_bits/8 per row) — the same accounting the
+# *_bp scorers state their reads in.
 _PACK_FLOPS_ROW = 826.0
 _PACK_FLOPS_ROWS = 76.0
 _PACK_FLOPS_LANE = 5277.0
 _PACK_FLOPS_CONST = 418.0
-_PACK_XBYTES_ROW = 1165.5
-_PACK_XBYTES_LANE = 4718.0
-_PACK_XBYTES_CONST = 6474.0
 
 
 def _c_pack_block_batch(bs: int, rows: int,
@@ -462,56 +393,43 @@ def _c_pack_block_batch(bs: int, rows: int,
     return Cost(flops=_PACK_FLOPS_ROW * n + _PACK_FLOPS_ROWS * rows
                 + _PACK_FLOPS_LANE * bs + _PACK_FLOPS_CONST,
                 bytes=(ROW_BYTES + 8) * n + (row_bits / 8.0) * n
-                + 4.0 * (3 * (P.NF + 2) + 1) * bs,
-                xla_bytes=_PACK_XBYTES_ROW * n + _PACK_XBYTES_LANE * bs
-                + _PACK_XBYTES_CONST)
+                + 4.0 * (3 * (P.NF + 2) + 1) * bs)
 
 
 # dense-first IVF ANN family (ops/ann.py, ISSUE 11).  Assignment is
 # the (B,dim)×(dim,C) bf16 matmul (+ per-element top-k overhead XLA
 # counts as 2·dim·(C+bs)); fuse is per-lane work (int8 gather + dequant
-# matmul + fused boost + two-key sort — the per-lane constants fit jax
-# 0.4.x CPU to <0.5% at dim 256 over bs in {4..16} × nb in {1k..16k} ×
-# cap in {2^16, 2^20}; pinned by tests/test_roofline.py) plus the slab
-# operands (cap·(dim+6): int8 rows + f16 scale + int32 docid — the
-# quantized residency IS the byte win, arxiv 1406.3170 applied to
+# matmul + fused boost + two-key sort — the per-lane constant fits to
+# <0.5% at dim 256 over bs in {4..16} × nb in {1k..16k}).  The
+# quantized residency IS the byte win (arxiv 1406.3170 applied to
 # vectors).
 _ANN_FUSE_FLOPS_LANE = 1078.0
-_ANN_FUSE_XBYTES_LANE = 2120.0
 
 
 def _c_ann_assign(bs: int, dim: int = 256, C: int = 1024,
                   np_: int = 8) -> Cost:
     """Centroid assignment: ONE (B,dim)×(dim,C) bf16 matmul per wave."""
     return Cost(flops=2.0 * dim * (bs * C + C + bs),
-                bytes=2 * C * dim + 4 * bs * dim + 4 * bs * np_,
-                xla_bytes=10.0 * C * dim + 4.0 * bs * C
-                + 12.0 * bs * dim)
+                bytes=2 * C * dim + 4 * bs * dim + 4 * bs * np_)
 
 
-def _c_ann_fuse(bs: int, nb: int, dim: int = 256, cap: int = 0,
-                k: int = 16) -> Cost:
+def _c_ann_fuse(bs: int, nb: int, dim: int = 256, k: int = 16) -> Cost:
     """IVF probe + dense/sparse fusion: batched int8 gathers over the
     hot slab with dequant fused into the scoring matmul. Compulsory
     bytes = the gathered quantized lanes + packed descriptors + fused
-    top-k out; the XLA model charges the whole slab operand set per
-    dispatch (gather semantics in HloCostAnalysis)."""
+    top-k out."""
     lanes = bs * nb
     desc = 4.0 * (2 + 3 * nb + dim) * bs
     return Cost(flops=_ANN_FUSE_FLOPS_LANE * lanes,
-                bytes=(dim + 6.0) * lanes + desc + 8.0 * bs * k,
-                xla_bytes=_ANN_FUSE_XBYTES_LANE * lanes
-                + (dim + 6.0) * cap)
+                bytes=(dim + 6.0) * lanes + desc + 8.0 * bs * k)
 
 
 # fused all-gather+top-k fusion collective (parallel/mesh.py, ISSUE 12b):
 # each shard ships its exact local top-k — the wire payload is
 # 8 B x k x n_shards (score+docid), never full score rows — and the
-# tie-pinned two-key merge sorts the G = n_shards*k gathered rows.  The
-# XLA model is the empirical CPU fit (exact over k in {16..128} x ndev
-# in {4,8} x rows in {256..4096}; pinned by tests/test_roofline.py):
-# local two-key sort streams ~24 B/row, the gathered merge ~32 B/row,
-# both with the n*log2(n) comparison count a sort costs.
+# tie-pinned two-key merge sorts the G = n_shards*k gathered rows, both
+# sorts with the n*log2(n) comparison count a sort costs (empirical CPU
+# fit over k in {16..128} x ndev in {4,8} x rows in {256..4096}).
 
 
 def _log2(n: float) -> float:
@@ -523,18 +441,17 @@ def _c_all_gather_topk(k: int, ndev: int, rows: int = 256) -> Cost:
     g = ndev * k
     return Cost(flops=1.08 * rows * _log2(rows) + 1.1 * g * _log2(g)
                 + 120.0,
-                bytes=8.0 * rows + 8.0 * g + 8.0 * k,
-                xla_bytes=24.0 * rows + 32.0 * g + 40.0 * k + 80.0)
+                bytes=8.0 * rows + 8.0 * g + 8.0 * k)
 
 
 def _c_power_iterate(n: int, edges: int, iters: int = 1) -> Cost:
     """BlockRank power iteration (ops/blockrank._power_iterate_sparse):
     per-iteration segment-sum over the edge list, × the trip count (the
     while body counts once in the XLA model; iters=1 is the cross-check
-    shape). Fit: flops = 4·e + 11·n + 13; bytes = 20·e + 57.5·n + 366."""
-    return Cost(flops=(4.0 * edges + 11.0 * n + 13.0) * iters,
-                bytes=(12 * edges + 8 * n) * iters,
-                xla_bytes=(20.0 * edges + 57.5 * n + 366.0) * iters)
+    shape). Fit to the module as lowered (the CPU pipeline's scatter
+    expansion moves the optimised count by host): flops = 5·e + 8·n + 5."""
+    return Cost(flops=(5.0 * edges + 8.0 * n + 5.0) * iters,
+                bytes=(12 * edges + 8 * n) * iters)
 
 
 # kernel name -> cost fn; names match the python symbol the kernel is
@@ -612,23 +529,25 @@ def registered() -> list[str]:
     return sorted(KERNELS)
 
 
-def xla_cost(jitfn, *args, **kwargs) -> tuple[float, float]:
-    """(flops, bytes accessed) from XLA's compiled cost analysis, or
-    (nan, nan) when the backend doesn't expose it."""
+def xla_cost(jitfn, *args, lowered: bool = False, **kwargs) -> float:
+    """XLA's flop count for one call of `jitfn`: the independent count
+    of operations the cost models are pinned to (nan when the backend
+    doesn't expose it). By default the compiled module's; `lowered`
+    reads the module before any backend's optimisation, which no CPU
+    feature or XLA pipeline can change — the pin for kernels whose
+    optimised count follows how a backend expands gathers/scatters."""
     try:
-        analysis = jitfn.lower(*args, **kwargs).compile().cost_analysis()
+        low = jitfn.lower(*args, **kwargs)
+        analysis = (low if lowered else low.compile()).cost_analysis()
     except Exception:
-        return float("nan"), float("nan")
+        return float("nan")
     if isinstance(analysis, (list, tuple)):
         analysis = analysis[0] if analysis else {}
-    if not analysis:
-        return float("nan"), float("nan")
-    return (float(analysis.get("flops", float("nan"))),
-            float(analysis.get("bytes accessed", float("nan"))))
+    return float((analysis or {}).get("flops", float("nan")))
 
 
 def ascii_table(points: list[RooflinePoint], peak: DevicePeak) -> str:
-    """The achieved-vs-peak table (BASELINE/README artifact form)."""
+    """The achieved-vs-peak table as plain text."""
     head = (f"device peak: {peak.name} — "
             f"{peak.flops_per_s / 1e12:.1f} TFLOP/s, "
             f"{peak.bytes_per_s / 1e9:.0f} GB/s, "

@@ -137,7 +137,7 @@ def partition_fingerprint(n_term: int, n_doc: int,
 
 def build_corpus(sb, ndocs: int, seed: int, n_doc: int) -> None:
     """Identical on every process for a given (ndocs, seed): metadata
-    rows + ONE frozen RWI run with the bench terms and the constructed
+    rows + ONE frozen RWI run with the CORPUS_TERMS and the constructed
     tie term (two identical feature rows whose docids land in DIFFERENT
     doc columns — equal scores must cross a process boundary and still
     fuse as (score DESC, docid ASC))."""
@@ -509,8 +509,7 @@ class MeshMember:
             # one rotation and stop classifying exactly the queries the
             # game day must attribute.  mesh.serve roots gate on the
             # fixed `tail.minMs` floor; deployments whose healthy
-            # collective wall exceeds the default floor raise the knob
-            # (the game-day bench does).
+            # collective wall exceeds the default floor raise the knob.
             s, d, considered = lrec["result"] or \
                 (np.empty(0, np.int32), np.empty(0, np.int32), 0)
             return {"seq": seq, "mode": lrec["mode"], "go": bool(go),
@@ -583,7 +582,7 @@ class MeshMember:
             histogram.reset_windows()
         if tick_health and eng is not None:
             # node switchboards under the mesh runtime do not run the
-            # 15_health busy thread; the wire caller (bench/test) drives
+            # 15_health busy thread; the wire caller (a drill or a test) drives
             # evaluation explicitly so burn-rate rules and the flight
             # recorder fire on the member's real histograms
             eng.tick()
@@ -615,8 +614,8 @@ class MeshMember:
         strag_wf = None
         if self.timeline is not None:
             # the assembled waterfall OF an over-threshold straggled
-            # query (the ISSUE 15 acceptance artifact's exhibit), not
-            # just the newest complete step
+            # query (what tools/tail_report.py shows), not just the
+            # newest complete step
             for v in verdicts:
                 if v.cause == "collective_straggler":
                     strag_wf = self.timeline.waterfall(

@@ -221,7 +221,7 @@ class MeshRanker:
     """Sharded CardinalRanker: pad to shard tiles, place, run, trim.
 
     The mesh analog of ops/ranking.CardinalRanker; used by the sharded
-    segment store and by bench config #3 (8-way sharded BM25/cardinal).
+    segment store.
     """
 
     def __init__(self, mesh: Mesh, profile: R.RankingProfile | None = None,

@@ -2,23 +2,21 @@
 
 Performance_Tail_p explains WHY individual queries were slow;
 Performance_Health_p shows THAT the SLO is burning.  This panel closes
-the loop on the chaos drill itself: for the most recent ``bench.py
---game-day`` run it renders one row per SCHEDULED fault — was it
-detected, was the incident attributed to the RIGHT cause label and
-member, did the SLO recover inside the bound after the clear, was
-every request during the window answered (degraded + counted, never a
-5xx), and did the recovered fleet rank bit-identically to the pre-fault
-baseline.  The in-process view (:data:`~...utils.gameday.LAST_RUN`)
-wins; with no run this process, the newest committed ``CHAOS_r*.json``
-artifact at the repo root is served instead, so the panel is useful on
-a fresh operator node too.  ``format=json`` exports the full artifact.
+the loop on the chaos drill itself: for the most recent
+:class:`~...utils.gameday.Conductor` run IN THIS PROCESS
+(:data:`~...utils.gameday.LAST_RUN`) it renders one row per SCHEDULED
+fault — was it detected, was the incident attributed to the RIGHT cause
+label and member, did the SLO recover inside the bound after the clear,
+was every request during the window answered (degraded + counted, never
+a 5xx), and did the recovered fleet rank bit-identically to the
+pre-fault baseline.  With no run in this process the panel says so
+(``source`` ``none``, no rows): a verdict from another machine is not
+this node's.  ``format=json`` exports the full result.
 """
 
 from __future__ import annotations
 
-import glob
 import json
-import os
 
 from ...utils import gameday
 from ..objects import ServerObjects, escape_json
@@ -28,38 +26,10 @@ GATES = ("detected", "attributed", "answered", "slo_recovery",
          "bit_identical")
 
 
-def _newest_artifact() -> str | None:
-    """Newest committed ``CHAOS_r*.json`` that actually has a fault
-    schedule (every --game-day run commits the next round; pre-M90
-    residues without a schedule don't qualify)."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(here))))
-    for path in sorted(glob.glob(os.path.join(root, "CHAOS_r*.json")),
-                       reverse=True):
-        try:
-            with open(path, encoding="utf-8") as f:
-                if json.load(f).get("schedule"):
-                    return path
-        except (OSError, ValueError):
-            continue
-    return None
-
-
 def gameday_view() -> dict:
-    """The newest game-day result: this process's LAST_RUN if a run
-    happened here, else the newest committed artifact, else an empty
-    shell."""
+    """This process's last game-day result, else an empty shell."""
     if gameday.LAST_RUN is not None:
         return {"source": "live", **gameday.LAST_RUN}
-    path = _newest_artifact()
-    if path is not None:
-        try:
-            with open(path, encoding="utf-8") as f:
-                return {"source": os.path.basename(path),
-                        **json.load(f)}
-        except (OSError, ValueError):
-            pass
     return {"source": "none", "schedule": [], "overlaps": [],
             "verdict_summary": {}, "workload": {}}
 
@@ -75,6 +45,8 @@ def respond_gameday(header: dict, post: ServerObjects,
         return prop
     prop = ServerObjects()
     prop.put("source", escape_json(view.get("source", "none")))
+    prop.put("note", "" if view.get("source") != "none"
+             else "no drill has run in this process")
     summary = view.get("verdict_summary", {})
     prop.put("faults", summary.get("faults", 0))
     prop.put("passed", summary.get("passed", 0))
@@ -84,11 +56,6 @@ def respond_gameday(header: dict, post: ServerObjects,
     wl = view.get("workload", {})
     prop.put("queries_total", wl.get("queries_total", 0))
     prop.put("duration_s", wl.get("duration_s", 0))
-    trend = view.get("trend") or {}
-    prop.put("trend_prev", escape_json(
-        str(trend.get("prev_artifact", "-"))))
-    prop.put("trend_regressions", trend.get("regressions", 0))
-    prop.put("trend_improvements", trend.get("improvements", 0))
 
     overlaps = view.get("overlaps", [])
     prop.put("overlaps", len(overlaps))

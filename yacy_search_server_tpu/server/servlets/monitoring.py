@@ -642,11 +642,12 @@ def prometheus_text(sb, include_buckets: bool = True,
              {"queue": "inflight"})
     if ds is not None:
         p.family("yacy_device_latency_ms", "gauge",
-                 "per-query dispatch/kernel wall percentiles")
-        for key in ("dispatch_ms_p50", "dispatch_ms_p95",
-                    "kernel_ms_p50", "kernel_ms_p95", "dispatch_rt_ms"):
-            if key in c:
-                p.sample("yacy_device_latency_ms", c[key], {"stat": key})
+                 "the trivial device round trip measured at start "
+                 "(dispatch and kernel walls: the devstore.batch / "
+                 "kernel.* span families)")
+        if "dispatch_rt_ms" in c:
+            p.sample("yacy_device_latency_ms", c["dispatch_rt_ms"],
+                     {"stat": "dispatch_rt_ms"})
 
     p.family("yacy_crawler_queue_depth", "gauge",
              "frontier stack depths")

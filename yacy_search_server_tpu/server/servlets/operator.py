@@ -604,7 +604,9 @@ def device_store(header, post, sb):
     """The serving-store dashboard: arena occupancy, prune/batch/join
     coverage, mesh layout (observability for the device path — the
     reference's PerformanceMemory table-tracker idea applied to the
-    TPU arena)."""
+    TPU arena). Counts and bytes only: the per-kernel cost-model table
+    is Performance_Roofline_p, dispatch and kernel walls are the
+    `devstore.batch` / `kernel.*` stages of Performance_Trace_p."""
     prop = ServerObjects()
     ds = sb.index.devstore
     if ds is None:
@@ -642,11 +644,6 @@ def device_store(header, post, sb):
             ("rt_per_query",
              round(c["device_round_trips"]
                    / max(c["queries_served"], 1), 3)),
-            # silicon accounting (Performance_Roofline_p has the full
-            # per-kernel table; these are the per-query headline fields)
-            ("util_pct_p50", c["util_pct_p50"]),
-            ("util_pct_p95", c["util_pct_p95"]),
-            ("bound", c["bound"]),
             # compressed residency + tier ladder (ISSUE 8): per-tier
             # occupancy, hit attribution and the promotion flow
             ("packed_residency", 1 if ds.packed_residency else 0),

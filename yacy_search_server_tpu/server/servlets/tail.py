@@ -20,8 +20,8 @@ from . import servlet
 
 
 def tail_view(sb) -> dict:
-    """The full forensics view as one JSON-serializable dict (shared by
-    the servlet's format=json export and the bench artifact)."""
+    """The full forensics view as one JSON-serializable dict (the
+    servlet's format=json export, which tools/tail_report.py renders)."""
     # finalize any owed mesh verdicts whose segments never fully
     # arrived (lull after a burst): the operator asking is exactly
     # when a pending verdict must stop waiting

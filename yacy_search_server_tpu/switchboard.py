@@ -74,8 +74,8 @@ class Switchboard:
                  transport=None, pipeline_workers: int = 2):
         self.config = config or Config()
         self.data_dir = data_dir
-        # tracing is on by default (the <2% overhead contract is pinned
-        # by bench.py --trace-overhead). The flag is process-global
+        # tracing is on by default (what it costs: PERF.md section 5).
+        # The flag is process-global
         # (co-hosted loopback nodes share one spine), so only an
         # EXPLICIT config setting touches it — a default-config
         # switchboard must not clobber another node's choice or an
@@ -561,8 +561,8 @@ class Switchboard:
             "search.verify.delete", True)
         # degradation ladder (ISSUE 9): the actuator's current rung
         # rides the query explicitly — every downstream stage decision
-        # (snippets, rerank, cache-only) reads THIS value, and the
-        # per-level histogram in the headline artifact counts it
+        # (snippets, rerank, cache-only) reads THIS value, and
+        # `yacy_degraded_queries_total{level}` counts it
         act = getattr(self, "actuators", None)
         if act is not None:
             q.degrade_level = act.effective_level()
@@ -802,8 +802,8 @@ class Switchboard:
                 # compressed residency + tier ladder: bit-packed
                 # blocks with fused on-device decode; corpus
                 # size becomes a tiering decision instead of an
-                # HBM ceiling (off by default — the capacity
-                # bench and parity tests drive it)
+                # HBM ceiling (off by default — the parity tests
+                # drive it; no benchmark cell does yet, ROADMAP R1)
                 packed_residency=self.config.get_bool(
                     "index.device.packedResidency", False),
                 warm_budget_bytes=self.config.get_int(
@@ -831,9 +831,9 @@ class Switchboard:
                     "index.device.completerDepth", 2),
                 # batch hybrid dense reranks through the same
                 # pipeline (on by default — the last solo
-                # kernel; bench --rerank-overhead pins the
-                # gate); off = solo dispatches of the same
-                # packed kernel, the parity-test A/B switch
+                # kernel); off = solo dispatches of the same
+                # packed kernel, bit-identical
+                # (tests/test_rerank_batching.py)
                 rerank_batching=self.config.get_bool(
                     "index.device.rerankBatching", True))
         # dense-first serving knobs (ISSUE 11): probe width and

@@ -39,7 +39,7 @@ loop, with the same declarative discipline as `utils/health.py` rules
   `meshstore._MeshQueryBatcher`) within configured bounds from the same
   queue-depth gauges the backlog rule reads.  Bounded step-per-window:
   at most ±1 per tick, and only on a `recoverTicks`-sustained signal —
-  a healthy soak must show ZERO transitions (the bench gate).  The
+  a healthy soak must show ZERO transitions.  The
   floor (1 dispatcher, depth 1) can never deadlock the pipeline.
 - **remote_peer_guard** — writes the ``remotesearch.avoidPeers`` knob
   from the fleet table's digest-reported health: peers reporting
@@ -610,8 +610,8 @@ class ActuatorEngine:
         return local
 
     def note_query(self, level: int) -> None:
-        """Per-level served-query accounting — the degrade_level
-        histogram the headline artifact carries."""
+        """Per-level served-query accounting
+        (`yacy_degraded_queries_total{level}`)."""
         with self._lock:
             self.degraded_queries[min(max(level, 0), N_LEVELS - 1)] += 1
 
@@ -633,10 +633,6 @@ class ActuatorEngine:
             for key, v in self._transitions.items():
                 out[key] = v
         return out
-
-    def transitions_total(self) -> int:
-        with self._lock:
-            return sum(self._transitions.values())
 
     def recent_breadcrumbs(self, n: int = 64) -> list:
         with self._lock:

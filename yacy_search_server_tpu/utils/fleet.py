@@ -59,7 +59,7 @@ DIGEST_VERSION = 1
 # DHT transfer wall (the P2P surface)
 DIGEST_FAMILIES = ("servlet.serving", "kernel.device", "dht.transfer")
 
-DEFAULT_BYTE_BUDGET = 2048          # the <2 KiB wire budget (bench-pinned)
+DEFAULT_BYTE_BUDGET = 2048          # the <2 KiB wire budget
 DEFAULT_STALE_S = 300.0
 DEFAULT_SEND_INTERVAL_S = 10.0
 DEFAULT_RENDER_TTL_S = 2.0
@@ -312,8 +312,8 @@ class FleetTable:
         }
         # wire budget: a digest must never bloat the exchanges it rides.
         # Dropping the largest family degrades the mesh view gracefully
-        # (absent merges as absent); the bench pins that real serving
-        # load never trims.
+        # (absent merges as absent); tests/test_fleet.py pins that a
+        # full digest fits and that over budget families go, not the wire.
         size = digest_bytes(digest)
         while size > self.byte_budget and digest["hist"]:
             fat = max(digest["hist"],

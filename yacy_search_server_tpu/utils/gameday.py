@@ -57,8 +57,11 @@ tests/test_gameday.py):
   could corrupt, so it stays with the crash-consistency harness.)
 
 Jax-free by contract (the conductor talks HTTP to the fleet; the
-verdict engine is pure joins), so ``bench.py --game-day`` and the
+verdict engine is pure joins), so a drill runner and the
 ``Performance_GameDay_p`` servlet can import this from any process.
+Nothing in the tree runs the :class:`Conductor` over a live fleet
+today (ROADMAP R6 carries the drill's gate list); tests/test_gameday.py
+holds the schedule, the bookkeeping and every verdict join.
 """
 
 from __future__ import annotations
@@ -68,8 +71,8 @@ import random
 import time
 from dataclasses import dataclass, field
 
-# the last completed run's result (the Performance_GameDay_p servlet
-# serves this in-process view, falling back to the committed artifact)
+# the last completed run's result in THIS process (what the
+# Performance_GameDay_p servlet serves)
 LAST_RUN: dict | None = None
 
 # every fault the conductor may schedule, with its detection contract —
@@ -680,7 +683,7 @@ class Conductor:
         # must name its injected cause.  Outside the windows a
         # CPU-contended environment can legitimately produce slow-but-
         # uniform queries with nothing to attribute; the run-wide
-        # cumulative count stays in the artifact (unattributed_total)
+        # cumulative count stays in the result (unattributed_total)
         # for diagnosability but does not gate.
         def _in_fault_window(ts: float) -> bool:
             return any(f.armed_ts <= ts <= f.cleared_ts
@@ -690,7 +693,7 @@ class Conductor:
         unattr_in_window = [v for v in unattr_all
                             if _in_fault_window(v.get("ts", 0.0))]
         result = {
-            "bench": "game_day",
+            "drill": "game_day",
             "workload": {
                 "terms": self.terms,
                 "zipf_s": self.zipf.s,
@@ -717,7 +720,7 @@ class Conductor:
                     .get("unattributed", 0)),
                 # any unattributed verdict the probes caught, verbatim
                 # (in-window ones first) — the zero-unattributed gate
-                # must be diagnosable from the artifact alone when it
+                # must be diagnosable from the result alone when it
                 # trips
                 "unattributed_sample":
                     (unattr_in_window or unattr_all)[:10],

@@ -27,9 +27,8 @@ gives every hot wall ONE cheap recording surface (ISSUE 4 tentpole):
   `merge_counts` + `percentile_from_counts` serve cross-store and
   cross-window aggregation.
 
-`pctl` here is THE nearest-rank percentile convention — tracing,
-profiler, devstore and bench all delegate to it (one implementation,
-satellite of ISSUE 4).
+`pctl` here is THE nearest-rank percentile convention — tracing and
+the profiler delegate to it (one implementation, satellite of ISSUE 4).
 """
 
 from __future__ import annotations
@@ -78,7 +77,7 @@ def bucket_index(ms: float) -> int:
 
 def pctl(sorted_values: list, q: float) -> float:
     """Nearest-rank percentile over a SORTED list — the one convention
-    shared by tracing, the profiler, the batcher counters and bench."""
+    shared by tracing and the profiler."""
     if not sorted_values:
         return 0.0
     return sorted_values[min(len(sorted_values) - 1,
@@ -340,7 +339,7 @@ _enabled = True
 
 
 def set_enabled(on: bool) -> None:
-    """Global record gate (the bench --health-overhead A/B switch)."""
+    """Global record gate: off, `observe` records nothing."""
     global _enabled
     _enabled = bool(on)
 
@@ -404,7 +403,7 @@ def rotate_due() -> None:
 
 
 def reset() -> None:
-    """Drop every family's data (tests/bench isolation).  The canonical
+    """Drop every family's data (test isolation).  The canonical
     families are re-registered empty: health rules and the exposition
     reference them unconditionally."""
     with _reg_lock:

@@ -4,8 +4,10 @@ The measurement half of the silicon accounting (ops/roofline.py is the
 analytical half): serving paths report (kernel, wall, shape) here; the
 profiler converts each report into achieved FLOP/s, achieved GB/s and a
 %-of-peak number against the device's declared ceiling, and keeps bounded
-per-kernel series so the rank-service stats, the Performance_Roofline_p
-servlet and bench artifacts can all read one surface.
+per-kernel series for the Performance_Roofline_p servlet. The walls are
+HOST walls around a dispatch, so `util_pct` is a cost model over a host
+clock: the chip's own roofline share is read from the device trace
+(`join_roofline` / `join_sm_roofline`, PERF.md section 3).
 
 Design constraints:
 
@@ -18,7 +20,7 @@ Design constraints:
 - **Per-query attribution**: a batched dispatch serving `queries` slots
   records the batch once for kernel aggregates AND per-query utilization
   samples (each query's share of the dispatch), which is what
-  `util_pct` p50/p95 in the rank-service counters summarizes.
+  `query_util()`'s `util_pct` p50/p95 summarizes.
 """
 
 from __future__ import annotations
@@ -52,9 +54,6 @@ class RooflineProfiler:
         if self._peak is None:
             self._peak = roofline.device_peak()
         return self._peak
-
-    def set_peak(self, peak: DevicePeak) -> None:
-        self._peak = peak
 
     # -- recording -----------------------------------------------------------
 
@@ -116,7 +115,7 @@ class RooflineProfiler:
     _pctl = staticmethod(tracing._pctl)
 
     def query_util(self) -> dict:
-        """Per-query utilization summary for the rank-service stats."""
+        """Per-query utilization summary (Performance_Roofline_p)."""
         with self._lock:
             samples = list(self._query_util)
         if not samples:
@@ -141,9 +140,8 @@ class RooflineProfiler:
             wall = sum(w for w, _ in rows)
             fl = sum(c.flops for _, c in rows)
             by = sum(c.bytes for _, c in rows)
-            xb = sum(c.xla_bytes for _, c in rows)
             points.append(roofline_point(
-                kernel, Cost(fl, by, xb), wall, self.peak))
+                kernel, Cost(fl, by), wall, self.peak))
         return points
 
     def clear(self) -> None:

@@ -653,7 +653,7 @@ def decode_role(i) -> str:
 
 
 def reset() -> None:
-    """Test/bench isolation: drop windows, captures and counters (the
+    """Test isolation: drop windows, captures and counters (the
     sampler thread itself survives — it is process-global)."""
     global samples_total, capture_windows_total, holder_captures_total
     s = _SAMPLER

@@ -72,7 +72,7 @@ only: steering/shedding on a conviction stays future work.
 
 Jax-free by contract (imported by the wire layer and the chaos
 children); zero-alloc when disabled — every product hook bails on one
-module-flag read, the ``bench.py --tail-overhead`` A/B switch.
+module-flag read (:func:`set_enabled`).
 """
 
 from __future__ import annotations
@@ -179,8 +179,8 @@ _enabled = True
 
 
 def set_enabled(on: bool) -> None:
-    """Global gate (the bench --tail-overhead A/B switch): disables
-    classification AND the batchers' wave stamping in one flag."""
+    """Global gate: disables classification AND the batchers' wave
+    stamping in one flag."""
     global _enabled
     _enabled = bool(on)
 
@@ -926,7 +926,7 @@ def conviction_breadcrumbs(n: int = 20) -> list:
 
 
 def reset() -> None:
-    """Test/bench isolation: drop verdicts, waves and mesh records."""
+    """Test isolation: drop verdicts, waves and mesh records."""
     ATTR.reset()
     MESH.reset()
     CONVICTIONS.reset()

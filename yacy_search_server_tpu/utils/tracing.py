@@ -792,7 +792,7 @@ def merge_remote_spans(trace_id: str, spans, source: str) -> int:
 
 # the one nearest-rank convention across the observability layer lives
 # in utils/histogram.py; this alias survives for the callers that
-# learned it here (profiler, bench).  The per-stage p50/p95 summary
+# learned it here (the profiler).  The per-stage p50/p95 summary
 # (formerly stage_summary, a full ring walk per call) lives in
 # histogram.stage_table now: every span feeds the windowed histograms
 # at record time, so the table is maintained incrementally and covers
