@@ -34,6 +34,34 @@ class TestCodec:
         data = b"The quick brown fox jumps over the lazy dog"
         assert standard_coder.encode(data) == base64.b64encode(data)
 
+    @pytest.mark.parametrize("rfc", (False, True))
+    def test_encode_matches_the_per_byte_loop(self, rfc):
+        """The reference's loop (Base64Order.java encode), as this module
+        had it until PR 31, over every tail length and both alphabets:
+        url and word hashes must not move by a bit."""
+        coder = Base64Order(rfc)
+
+        def loop(data: bytes) -> bytes:
+            out, n, i = bytearray(), len(data), 0
+            while i + 3 <= n:
+                x = (data[i] << 16) | (data[i + 1] << 8) | data[i + 2]
+                out += coder.encode_long(x, 4)
+                i += 3
+            if n - i == 2:
+                x = (data[i] << 16) | (data[i + 1] << 8)
+                out += coder.encode_long(x, 4)[:3] + (b"=" if rfc else b"")
+            elif n - i == 1:
+                out += coder.encode_long(data[i] << 16, 4)[:2] \
+                    + (b"==" if rfc else b"")
+            return bytes(out)
+
+        rng = np.random.default_rng(31)
+        for n in list(range(0, 34)) + [255, 1000]:
+            for _ in range(20):
+                data = rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+                assert coder.encode(data) == loop(data), (n, data)
+        assert coder.encode(b"\xff" * 16) == loop(b"\xff" * 16)
+
     def test_zero_is_capital_a(self):
         assert enhanced_coder.encode_long(0, 3) == b"AAA"
 

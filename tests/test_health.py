@@ -124,9 +124,12 @@ def test_every_rule_references_only_live_metric_series(sb):
 
 
 def test_every_registered_histogram_appears_in_the_exposition(sb):
+    # list first, then render: a family registered in between (the
+    # sampler's first tick) is in a later exposition, not in this one
+    listed = hg.all_histograms()
     text = _metrics_text(sb)
     samples = parse_exposition(text)
-    for h in hg.all_histograms():
+    for h in listed:
         fam = hg.prom_name(h.name)
         assert f"{fam}_count" in samples, fam
         assert f"{fam}_sum" in samples, fam

@@ -990,13 +990,15 @@ class SearchEvent:
         """Fill missing snippets; returns how many entries were evicted
         (reference: concurrent snippet workers + deleteIfSnippetFail,
         SearchEvent.java:1862-1948)."""
-        with tracing.span("search.snippets", n=len(entries)):
-            return self._produce_snippets_inner(entries)
+        with tracing.span("search.snippets", n=len(entries)) as sp:
+            return self._produce_snippets_inner(entries, sp)
 
-    def _produce_snippets_inner(self, entries: list[ResultEntry]) -> int:
+    def _produce_snippets_inner(self, entries: list[ResultEntry],
+                                sp) -> int:
         from .snippet import (SNIPPET_DEAD, SNIPPET_OK, SnippetProducer)
         q = self.query
         words = q.goal.include_words
+        sp.set(inline=0, pooled=0)
         live_jobs: list[ResultEntry] = []
         for e in entries:
             if e.snippet_done or e.snippet:
@@ -1027,6 +1029,15 @@ class SearchEvent:
             return 0
         producer = SnippetProducer(self.loader, q.snippet_strategy)
         outcomes = producer.produce_many([e.url for e in live_jobs], words)
+        # where the jobs ran: on this thread (cacheonly) or through the
+        # pool (a strategy that may go to the network):
+        # yacy_stage_events_total SNIPPETS_INLINE / SNIPPETS_POOLED
+        inline = len(live_jobs) - producer.pooled
+        sp.set(inline=inline, pooled=producer.pooled)
+        if inline:
+            track(EClass.SEARCH, "SNIPPETS_INLINE", inline)
+        if producer.pooled:
+            track(EClass.SEARCH, "SNIPPETS_POOLED", producer.pooled)
         evicted = 0
         # eviction applies only when verification was REQUESTED: under
         # cacheonly a missing cache entry proves nothing (the reference

@@ -65,9 +65,9 @@ SNIPPET_DEAD = "dead"        # the fetch proved the URL gone (4xx/5xx)
 
 MAX_SNIPPET_WORKERS = 4
 
-# ONE shared pool for all page renders: per-query ThreadPoolExecutor
-# construction + join cost ~2 ms/query on the serving path (profiled in
-# r4) — more than the snippet lookups themselves under CACHEONLY
+# ONE shared pool for all page renders, for the strategies that may
+# wait for the network: per-query ThreadPoolExecutor construction + join
+# cost ~2 ms/query on the serving path (profiled in r4)
 _POOL: ThreadPoolExecutor | None = None
 
 
@@ -84,11 +84,19 @@ class SnippetProducer:
 
     One per SearchEvent page render; `produce_many` fetches the page's
     missing snippets with a small worker pool (the reference's
-    concurrent snippet workers, SearchEvent.java:1862-1930)."""
+    concurrent snippet workers, SearchEvent.java:1862-1930) where the
+    strategy may go to the network, and on the calling thread where it
+    cannot. Under CACHEONLY a miss is a set lookup, and a hit is some
+    file reads around a parse in pure Python: ten hits a page read the
+    same from the pool and from the caller, 1 to 8 threads (PERF.md,
+    PR 31), so a hand-off buys neither anything and costs two switches
+    a URL. `pooled` says how many jobs of the last `produce_many` went
+    through the pool."""
 
     def __init__(self, loader, strategy: str = "cacheonly"):
         self.loader = loader
         self.strategy = strategy
+        self.pooled = 0
 
     def produce(self, url: str, words: list[str]) -> tuple[str, str]:
         """(snippet, outcome) for one URL under the cacheStrategy."""
@@ -129,6 +137,9 @@ class SnippetProducer:
 
     def produce_many(self, urls: list[str],
                      words: list[str]) -> list[tuple[str, str]]:
-        if len(urls) <= 1:
+        # one job never needed a worker
+        if self.strategy == "cacheonly" or len(urls) <= 1:
+            self.pooled = 0
             return [self.produce(u, words) for u in urls]
+        self.pooled = len(urls)
         return list(_pool().map(lambda u: self.produce(u, words), urls))

@@ -398,8 +398,9 @@ class YaCyHttpServer:
                 else:
                     # lint: tail-ok(a child span of servlet.serving,
                     # which the classifier reaches)
-                    with tracing.timed("servlet.render", sv.ctx):
-                        body = self._render(name, ext, prop).encode("utf-8")
+                    with tracing.timed("servlet.render", sv.ctx) as rs:
+                        body = self._render(name, ext, prop,
+                                            rs).encode("utf-8")
                     ctype = prop.raw_ctype or _CONTENT_TYPES.get(
                         ext, "text/html; charset=utf-8")
             # any downgraded answer is stamped (ISSUE 9 satellite): a
@@ -439,27 +440,18 @@ class YaCyHttpServer:
             self._i18n = cached
         return cached
 
-    def _translate_source(self, source: str, section: str) -> str:
-        """Shared expand-includes + translate pipeline: includes expand
-        FIRST so the shared header chrome translates too; properties
-        substitute later, so crawled content is never rewritten."""
-        source = self.templates._expand_includes(source, 0)
-        i18n = self._translation()
-        if not i18n.is_empty():
-            source = i18n.translate(source, section)
-        return source
-
-    def _render(self, name: str, ext: str, prop: ServerObjects) -> str:
+    def _render(self, name: str, ext: str, prop: ServerObjects,
+                span=None) -> str:
         if prop.raw_body is not None:
             return prop.raw_body
-        tmpl = f"{name}.{ext}"
-        path = self.templates.resolve(tmpl)
-        if path is not None:
-            with open(path, encoding="utf-8") as f:
-                source = f.read()
-            if ext == "html":
-                source = self._translate_source(source, tmpl)
-            return self.templates.render(source, prop)
+        # the template's tree, compiled once and held while its files
+        # stay as they are (.html: per translation table as well)
+        got = self.templates.lookup(
+            f"{name}.{ext}", self._translation() if ext == "html" else None)
+        if got is not None:
+            if span is not None:
+                span.set(template=got[1])
+            return self.templates.render_tree(got[0], prop)
         if ext == "html":
             # no bespoke template: render the GENERIC admin page — real
             # chrome + nav + a live property table, so every registered
@@ -469,9 +461,12 @@ class YaCyHttpServer:
             # servlet pre-escaped show entity text here (cosmetic); the
             # alternative — trusting every servlet to have escaped —
             # would turn one unescaped put() into stored XSS.
-            gen = self.templates.resolve("env/generic_page.html")
+            gen = self.templates.lookup("env/generic_page.html",
+                                        self._translation(), f"{name}.html")
             if gen is not None:
                 from .objects import escape_html
+                if span is not None:
+                    span.set(template=gen[1])
                 page = ServerObjects()
                 page.put("servletname", escape_html(name))
                 items = sorted(prop.items())
@@ -479,10 +474,7 @@ class YaCyHttpServer:
                 for i, (k, v) in enumerate(items):
                     page.put(f"rows_{i}_key", escape_html(str(k)))
                     page.put(f"rows_{i}_value", escape_html(str(v)))
-                with open(gen, encoding="utf-8") as f:
-                    source = f.read()
-                source = self._translate_source(source, f"{name}.html")
-                return self.templates.render(source, page)
+                return self.templates.render_tree(gen[0], page)
         # No template: serialize the property map directly. Values follow
         # the template contract — the servlet already escaped them for the
         # output medium — so insert them verbatim (json.dumps would
@@ -721,20 +713,20 @@ class YaCyHttpServer:
         if ext == "html" and (b"#%" in data
                               or not self._translation().is_empty()):
             # static html that uses template includes (the shared
-            # chrome), or any page under a non-default locale, runs the
-            # expand -> translate -> render pipeline. Plain static pages
-            # under the default locale are served BYTE-FOR-BYTE — an
-            # operator-dropped file must not be re-encoded or have
-            # literal template-syntax text stripped.
+            # chrome), or any page under a non-default locale, is a
+            # template like any other (expand -> translate -> expand ->
+            # parse, once). Plain static pages under the default locale
+            # are served BYTE-FOR-BYTE — an operator-dropped file must
+            # not be re-encoded or have literal template-syntax text
+            # stripped.
             try:
-                source = data.decode("utf-8")
+                got = self.templates.lookup(relpath, self._translation(),
+                                            os.path.basename(relpath))
             except UnicodeDecodeError:
-                source = None       # not UTF-8: serve verbatim
-            if source is not None:
-                source = self._translate_source(
-                    source, os.path.basename(relpath))
-                data = self.templates.render(
-                    source, ServerObjects()).encode("utf-8")
+                got = None          # not UTF-8: serve verbatim
+            if got is not None:
+                data = self.templates.render_tree(
+                    got[0], ServerObjects()).encode("utf-8")
         self._send(handler, 200, _CONTENT_TYPES.get(ext, "application/octet-stream"), data)
 
     @staticmethod

@@ -446,6 +446,13 @@ def prometheus_text(sb, include_buckets: bool = True,
         p.sample("yacy_stage_duration_ms_total", ms,
                  {"class": ecl.value, "label": label})
 
+    from ..templates import render_counts
+    p.family("yacy_template_renders_total", "counter",
+             "template-file renders by what the engine held: a compiled "
+             "tree (hit) or none yet / an edited file (compiled)")
+    for how, n in sorted(render_counts().items()):
+        p.sample("yacy_template_renders_total", n, {"template": how})
+
     util = PROFILER.query_util()
     p.family("yacy_roofline_util_pct", "gauge",
              "per-query achieved utilization vs device peak")

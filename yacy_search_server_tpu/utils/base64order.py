@@ -18,6 +18,8 @@ ring in one shot — that array then feeds device-side partition routing.
 
 from __future__ import annotations
 
+import base64
+
 import numpy as np
 
 ALPHA_STANDARD = b"ABCDEFGHIJKLMNOPQRSTUVWXYZabcdefghijklmnopqrstuvwxyz0123456789+/"
@@ -64,26 +66,13 @@ class Base64Order:
         return c
 
     def encode(self, data: bytes) -> bytes:
-        """Encode bytes to base64. Non-rfc variant emits no '=' padding."""
-        out = bytearray()
-        n = len(data)
-        i = 0
-        while i + 3 <= n:
-            x = (data[i] << 16) | (data[i + 1] << 8) | data[i + 2]
-            out += self.encode_long(x, 4)
-            i += 3
-        rem = n - i
-        if rem == 2:
-            x = (data[i] << 16) | (data[i + 1] << 8)
-            out += self.encode_long(x, 4)[:3]
-            if self.rfc1521compliant:
-                out += b"="
-        elif rem == 1:
-            x = data[i] << 16
-            out += self.encode_long(x, 4)[:2]
-            if self.rfc1521compliant:
-                out += b"=="
-        return bytes(out)
+        """Encode bytes to base64. Non-rfc variant emits no '=' padding.
+        Both alphabets group bits as rfc1521 does, so the stdlib's codec
+        gives the reference's bytes (every url and word hash is three
+        or one of these; the per-byte loop was a third of `url2hash`)."""
+        if self.rfc1521compliant:
+            return base64.b64encode(data)
+        return base64.b64encode(data, b"-_").rstrip(b"=")
 
     def encode_substring(self, data: bytes, length: int) -> bytes:
         """First `length` chars of the base64 encoding (hash truncation)."""
