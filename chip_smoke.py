@@ -511,15 +511,16 @@ def run(args) -> int:
                   f"device-eligible queries")
             zero = ["fallbacks", "batch_timeouts", "device_lost",
                     "transfer_failures", "transfer_retries"]
-            if "join_served" in c1:      # DeviceSegmentStore families
+            if "join_served" in c1:      # both stores count their joins
                 check(d["join_served"] > 0,
                       f"join_served +{d['join_served']}")
+                zero.append("join_fallbacks")
+            if "stream_scans" in c1:     # DeviceSegmentStore families
                 check(d["stream_scans"] > 0,
                       f"stream_scans +{d['stream_scans']}")
                 check(d["rerank_queries"] > 0,
                       f"rerank_queries +{d['rerank_queries']}")
-                zero += ["join_fallbacks", "rerank_fallbacks",
-                         "prewarm_failures"]
+                zero += ["rerank_fallbacks", "prewarm_failures"]
             for k in zero:
                 check(d[k] == 0, f"{k} == 0 (+{d[k]})")
             check(c1["device_losses"] == 0, "no device loss declared "

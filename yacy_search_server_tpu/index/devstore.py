@@ -254,17 +254,24 @@ def _pmax_window(max_tcount: int) -> int:
 
 
 def _emit_rt_spans(issue_ms: float, fetch_ms: float,
-                   device_ms: float = 0.0) -> None:
+                   device_ms: float = 0.0,
+                   kernel: str | None = None) -> None:
     """Record the issue/device/fetch round-trip decomposition of a SOLO
     dispatch: child spans under the active trace, the families alone
     outside one (`tracing.record` does either) — the kernel-stage
     p50/p95 on /metrics covers every dispatch (ISSUE 4). Solo dispatches
     fetch immediately after issuing, so their in-flight `device` window
     is ~0 and the device time rides inside `fetch`; the pipelined batch
-    path stamps a real in-flight window (see _QueryBatcher._complete)."""
+    path stamps a real in-flight window (see _QueryBatcher._complete).
+    `kernel` (the mesh store's solo SPMD programs): the dispatch's whole
+    wall, issue to fetched, also lands in the family `kernel.<kernel>`,
+    so a reader can tell one program's walls from another's."""
     tracing.record("kernel.issue", issue_ms)
     tracing.record("kernel.device", device_ms)
     tracing.record("kernel.fetch", fetch_ms)
+    if kernel is not None:
+        tracing.record(f"kernel.{kernel}",
+                       issue_ms + device_ms + fetch_ms)
 
 
 # ---------------------------------------------------------------------------

@@ -620,6 +620,10 @@ class MeshSegmentStore:
         self._garbage_rows = 0
         self.queries_served = 0
         self.fallbacks = 0
+        # the join coverage partition (devstore parity): every
+        # join-shaped query rank_join is asked lands in exactly one
+        self.join_served = 0
+        self.join_fallbacks = 0
         # device-loss recovery (ISSUE 10c, devstore parity): a streak of
         # retry-exhausted transfers declares the MESH lost (any one chip
         # or its interconnect failing fails the whole SPMD program);
@@ -1003,6 +1007,8 @@ class MeshSegmentStore:
             return {
                 "queries_served": self.queries_served,
                 "fallbacks": self.fallbacks,
+                "join_served": self.join_served,
+                "join_fallbacks": self.join_fallbacks,
                 "device_lost": 1 if self.device_lost else 0,
                 "device_losses": self.device_losses,
                 "device_loss_recoveries": self.device_loss_recoveries,
@@ -1011,6 +1017,7 @@ class MeshSegmentStore:
                 "transfer_retries": self.transfer_retries,
                 "rank_cache_hits": self._topk_cache.hits,
                 "rank_cache_stale": self._topk_cache.stale,
+                "rank_cache_stale_served": self._topk_cache.stale_served,
                 "arena_epoch": self.arena_epoch,
                 "device_round_trips": self.device_round_trips,
                 "prune_rounds": self.prune_rounds,
@@ -1370,7 +1377,8 @@ class MeshSegmentStore:
                 s, d, ok = self.device_fetch(out)
                 self.count_round_trip()
                 _emit_rt_spans((t1s - t0s) * 1e3,
-                               (time.perf_counter() - t1s) * 1e3)
+                               (time.perf_counter() - t1s) * 1e3,
+                               kernel="_mesh_pruned_shard")
                 # solo SPMD program wall: one mesh.collective record per
                 # dispatch (the batched path records in _complete)
                 histogram.observe("mesh.collective",
@@ -1419,7 +1427,8 @@ class MeshSegmentStore:
         s, d = self.device_fetch(out)
         self.count_round_trip()
         _emit_rt_spans((t1f - t0f) * 1e3,
-                       (time.perf_counter() - t1f) * 1e3)
+                       (time.perf_counter() - t1f) * 1e3,
+                       kernel="_mesh_rank_shard")
         histogram.observe("mesh.collective",
                           (time.perf_counter() - t0f) * 1e3,
                           tracing.current_trace_id())
@@ -1486,14 +1495,17 @@ class MeshSegmentStore:
         here the shipment is ~20 bytes/candidate over ICI instead of an
         HTTP round trip (VERDICT r3 #3). Host fallback remains only for
         multi-span terms, unflushed RAM deltas — and a lost mesh
-        (ISSUE 10c: counted, never an exception)."""
+        (ISSUE 10c: counted, never an exception). Every join-shaped
+        query lands in exactly one of join_served / join_fallbacks, as
+        ``DeviceSegmentStore.rank_join`` documents it; `fallbacks`
+        keeps counting the same declines beside them."""
         # lint: unlocked-ok(racy bool read by design: a stale False
         # costs one failed transfer that re-classifies; locking here
         # would serialize every rank entry behind store mutations)
         if self.device_lost:
-            with self._lock:
+            with self._lock:   # one consistent counter view
                 self.device_lost_queries += 1
-                self.fallbacks += 1
+                self._join_declined()
             return None
         try:
             return self._rank_join_impl(include_hashes, exclude_hashes,
@@ -1501,10 +1513,16 @@ class MeshSegmentStore:
                                         lang_filter, flag_bit,
                                         from_days, to_days)
         except DeviceTransferError:
-            with self._lock:
+            with self._lock:   # one consistent counter view
                 self.device_lost_queries += 1
-                self.fallbacks += 1
+                self._join_declined()
             return None
+
+    def _join_declined(self) -> None:
+        """An eligible-shaped conjunction goes to the host join."""
+        with self._lock:
+            self.fallbacks += 1
+            self.join_fallbacks += 1
 
     def _rank_join_impl(self, include_hashes, exclude_hashes, profile,
                         language: str = "en", k: int = 100,
@@ -1525,7 +1543,7 @@ class MeshSegmentStore:
             for th in include_hashes:
                 spans = self.spans_for(th)
                 if spans is None or len(spans) != 1:
-                    self.fallbacks += 1
+                    self._join_declined()
                     return None
                 rows.add(term_shard(th, self.n_term))
                 inc_spans.append(spans[0])
@@ -1534,11 +1552,11 @@ class MeshSegmentStore:
                 spans = self.spans_for(th)
                 if spans is None:
                     if self.rwi.has_term(th):
-                        self.fallbacks += 1
+                        self._join_declined()
                         return None
                     continue
                 if len(spans) > 1:
-                    self.fallbacks += 1
+                    self._join_declined()
                     return None
                 if spans:
                     rows.add(term_shard(th, self.n_term))
@@ -1553,8 +1571,7 @@ class MeshSegmentStore:
             ram_delta = any(self.rwi._ram.get(th)
                             for th in include_hashes + exclude_hashes)
         if ram_delta:
-            with self._lock:
-                self.fallbacks += 1
+            self._join_declined()
             return None
 
         rare_i = min(range(len(inc_spans)),
@@ -1565,8 +1582,7 @@ class MeshSegmentStore:
 
         r = _bucket_rows(max(int(rare.counts.max()), 1))
         if int((rare.starts + r).max()) > C:
-            with self._lock:
-                self.fallbacks += 1
+            self._join_declined()
             return None
 
         def window(sp):
@@ -1576,8 +1592,7 @@ class MeshSegmentStore:
         inc_ms = tuple(window(sp) for sp in partners)
         exc_ms = tuple(window(sp) for sp in exc_spans)
         if any(m is None for m in inc_ms + exc_ms):
-            with self._lock:
-                self.fallbacks += 1
+            self._join_declined()
             return None
 
         n_inc, n_exc = len(partners), len(exc_spans)
@@ -1615,13 +1630,16 @@ class MeshSegmentStore:
         s, d = self.device_fetch(out)
         self.count_round_trip()
         _emit_rt_spans((t1j - t0j) * 1e3,
-                       (time.perf_counter() - t1j) * 1e3)
+                       (time.perf_counter() - t1j) * 1e3,
+                       kernel="_mesh_xjoin_shard" if cross_row
+                       else "_mesh_join_shard")
         histogram.observe("mesh.collective",
                           (time.perf_counter() - t0j) * 1e3,
                           tracing.current_trace_id())
         keep = (d >= 0) & (s > NEG_INF32)
         with self._lock:   # exact under concurrency
             self.queries_served += 1
+            self.join_served += 1
         return s[keep][:k], d[keep][:k], considered
 
 

@@ -489,8 +489,11 @@ def prometheus_text(sb, include_buckets: bool = True,
                 # (must stay 0: a refused shape fails at first live use)
                 "prewarm_failures",
                 # versioned top-k result cache (hits serve with zero
-                # device work; stale = correct epoch invalidations)
+                # device work; stale = correct epoch invalidations;
+                # stale_served = answers the ladder let through past
+                # their epoch, which must stay 0 outside rung 3)
                 "rank_cache_hits", "rank_cache_stale",
+                "rank_cache_stale_served",
                 # batched hybrid rerank: queries/dispatches = mean
                 # coalescing factor; cache hits = full hybrid answers
                 # served without touching the device

@@ -677,14 +677,27 @@ def device_store(header, post, sb):
             ("dense_fwd_bytes", c["dense_fwd_bytes"]),
         ]
     elif kind == "MeshSegmentStore":
+        from ...utils import histogram
+        c = ds.counters()
         rows += [
             ("mesh_term_axis", ds.n_term),
             ("mesh_doc_axis", ds.n_doc),
             ("mesh_cells", ds.n_cells),
             ("live_rows", ds.live_rows()),
-            ("cell_rows_max", max((c.used for c in ds._cells),
+            ("cell_rows_max", max((cb.used for cb in ds._cells),
                                   default=0)),
+            ("rank_cache_hits", c["rank_cache_hits"]),
+            ("rank_cache_stale", c["rank_cache_stale"]),
+            ("rank_cache_stale_served", c["rank_cache_stale_served"]),
+            ("arena_epoch", c["arena_epoch"]),
+            ("device_round_trips", c["device_round_trips"]),
         ]
+        # dispatches per solo SPMD program (the families' walls are on
+        # Performance_Trace_p and /metrics)
+        for fam in ("kernel._mesh_join_shard", "kernel._mesh_xjoin_shard",
+                    "kernel._mesh_pruned_shard", "kernel._mesh_rank_shard"):
+            h = histogram.get(fam)
+            rows.append((fam, h.count if h is not None else 0))
     prop.put("rows", len(rows))
     for i, (name, v) in enumerate(rows):
         prop.put(f"rows_{i}_key", name)
