@@ -15,6 +15,9 @@
 //     gather indices into both sides (the conjunctive join primitive,
 //     index/segment.join_constructive).
 //   - ytn_remove_docids   : tombstone mask over sorted dead-id array.
+//   - ytn_cardinal_scores : the host gate's ranking of a small candidate
+//     block in ONE call (ops/ranking.cardinal_scores_host bit for bit,
+//     plus its stable top-k order).
 //
 // Exposed as a plain C ABI for ctypes (no pybind11 in this image). Every
 // entry point is pure (no globals, no allocation ownership transfer): the
@@ -206,7 +209,125 @@ void ytn_remove_docids(const int32_t* docids, int64_t n, const int32_t* dead,
     }
 }
 
-// Library identity probe for the loader.
-int32_t ytn_abi_version() { return 1; }
+// ---------------------------------------------------------------------------
+// Cardinal ranking of a small candidate block
+// ---------------------------------------------------------------------------
+
+// The int64 scores of ops/ranking.cardinal_scores_host (compact_feats +
+// pack_stats_host + cardinal_from_stats_host, authority term apart) over
+// feats[n, nf], bit for bit, in two passes over the rows; then the first k
+// of its order (score DESC, input index ASC: np.argsort(-s, kind="stable")).
+// `consts` is the profile as ops/ranking._native_consts lays it out:
+//   [0..6)   columns: flags, hitcount, words_in_text, words_in_title,
+//            language, domlength
+//   [6..9)   coefficients: domlength, tf, language
+//   [9]      number of flag terms F
+//   [10..)   nf column shifts, nf column modes (0 none, 1 direct,
+//            2 inverted), F flag bits, F flag shifts
+// Every shift is 0..15 (the caller's guard). Returns 0, or -1 for more than
+// MAX_NF columns or flag terms (the caller then scores with NumPy).
+namespace {
+constexpr int MAX_NF = 32;
+inline int32_t clip16(int32_t v) {
+    return v < -32768 ? -32768 : (v > 32767 ? 32767 : v);
+}
+inline int32_t shl32(int32_t v, int32_t s) {   // int32 `<<` as NumPy wraps it
+    return (int32_t)((uint32_t)v << s);
+}
+}  // namespace
+
+int32_t ytn_cardinal_scores(const int32_t* feats, int64_t n, int32_t nf,
+                            const int32_t* consts, int32_t language_pref,
+                            int64_t k, int64_t* scores_out,
+                            int64_t* order_out) {
+    if (nf > MAX_NF || consts[9] > MAX_NF) return -1;
+    const int32_t c_flags = consts[0], c_hit = consts[1], c_text = consts[2],
+                  c_title = consts[3], c_lang = consts[4], c_dom = consts[5];
+    const int32_t k_dom = consts[6], k_tf = consts[7], k_lang = consts[8];
+    const int32_t nflag = consts[9];
+    const int32_t* col_shift = consts + 10;
+    const int32_t* col_mode = col_shift + nf;
+    const int32_t* flag_bit = col_mode + nf;
+    const int32_t* flag_shift = flag_bit + nflag;
+
+    auto tf_of = [&](const int32_t* row) {    // float32, as the twin's
+        return (float)clip16(row[c_hit]) /
+            (float)(clip16(row[c_text]) + clip16(row[c_title]) + 1);
+    };
+
+    // pass 1: the block's statistics over the int16-clipped rows, the flags
+    // column read as 0; float32 tf, a NaN carried as np.min / np.max carry it
+    int32_t col_min[MAX_NF], col_max[MAX_NF];
+    for (int32_t c = 0; c < nf; c++) {
+        col_min[c] = INT32_MAX;
+        col_max[c] = INT32_MIN;
+    }
+    float tf_min = 0.0f, tf_max = 0.0f;
+    bool tf_nan = false;
+    for (int64_t i = 0; i < n; i++) {
+        const int32_t* row = feats + i * nf;
+        for (int32_t c = 0; c < nf; c++) {
+            const int32_t v = clip16(row[c]);
+            col_min[c] = v < col_min[c] ? v : col_min[c];
+            col_max[c] = v > col_max[c] ? v : col_max[c];
+        }
+        const float tf = tf_of(row);
+        if (tf != tf) tf_nan = true;
+        if (i == 0 || tf < tf_min) tf_min = tf;
+        if (i == 0 || tf > tf_max) tf_max = tf;
+    }
+    col_min[c_flags] = col_max[c_flags] = 0;
+    const float tf_span = tf_max - tf_min;
+    const bool tf_on = !tf_nan && tf_span > 0.0f;
+    const float tf_div = 1e-9f > tf_span ? 1e-9f : tf_span;
+    // span 0 where the column adds nothing: all rows equal, or no term
+    int32_t span[MAX_NF];
+    for (int32_t c = 0; c < nf; c++)
+        span[c] = col_mode[c] == 0 ? 0 : col_max[c] - col_min[c];
+    const int64_t lang_term = (int64_t)255 << k_lang;
+    int64_t flag_term[MAX_NF];
+    for (int32_t j = 0; j < nflag; j++)
+        flag_term[j] = shl32(255, flag_shift[j]);
+
+    // pass 2: the score of every row
+    for (int64_t i = 0; i < n; i++) {
+        const int32_t* row = feats + i * nf;
+        int64_t score = 0;
+        for (int32_t c = 0; c < nf; c++) {
+            if (span[c] == 0) continue;
+            const int32_t norm =
+                ((clip16(row[c]) - col_min[c]) * 256) / span[c];
+            score += shl32(col_mode[c] == 1 ? norm : 256 - norm,
+                           col_shift[c]);
+        }
+        score += shl32(256 - clip16(row[c_dom]), k_dom);
+        if (tf_on) {
+            score += (int64_t)(int32_t)(
+                (tf_of(row) - tf_min) * 256.0f / tf_div)
+                * ((int64_t)1 << k_tf);
+        }
+        if (clip16(row[c_lang]) == language_pref) score += lang_term;
+        const int32_t flags = row[c_flags];
+        for (int32_t j = 0; j < nflag; j++)
+            score += (int64_t)((flags >> flag_bit[j]) & 1) * flag_term[j];
+        scores_out[i] = score;
+    }
+
+    if (k > n) k = n;
+    if (k > 0) {
+        std::vector<int64_t> idx(n);
+        for (int64_t i = 0; i < n; i++) idx[i] = i;
+        std::partial_sort(idx.begin(), idx.begin() + k, idx.end(),
+                          [&](int64_t x, int64_t y) {
+            return scores_out[x] != scores_out[y]
+                ? scores_out[x] > scores_out[y] : x < y;
+        });
+        std::copy(idx.begin(), idx.begin() + k, order_out);
+    }
+    return 0;
+}
+
+// Library identity probe for the loader (utils/native.ABI_VERSION).
+int32_t ytn_abi_version() { return 2; }
 
 }  // extern "C"

@@ -16,7 +16,11 @@ from yacy_search_server_tpu.utils.health import parse_exposition
 
 
 @pytest.fixture(autouse=True)
-def _fresh_observability():
+def _fresh_observability(monkeypatch):
+    # the corruption counters are the process's: what another test file
+    # of this worker counted would fire storage_corruption on a first tick
+    from yacy_search_server_tpu.index import integrity
+    monkeypatch.setattr(integrity, "_corruption", {})
     hg.reset()
     hg.set_enabled(True)
     tracing.set_enabled(True)

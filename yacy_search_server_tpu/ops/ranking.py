@@ -33,12 +33,14 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field, fields
 from functools import partial
+from operator import attrgetter
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..index import postings as P
+from ..utils import native
 from ..utils.bitfield import (
     FLAG_APP_DC_CREATOR, FLAG_APP_DC_DESCRIPTION, FLAG_APP_DC_IDENTIFIER,
     FLAG_APP_DC_SUBJECT, FLAG_APP_DC_TITLE, FLAG_APP_EMPHASIZED,
@@ -462,10 +464,12 @@ def hostid_array(docids: np.ndarray, hosthashes: list[bytes] | np.ndarray) -> np
     return ids.astype(np.int32)
 
 
-# below this candidate count the kernel dispatch overhead and the device
-# round trip dwarf the scoring work: score on the host instead. 4096×NF
-# int64 numpy ops run in ~0.1ms. The value was set against a dispatch
-# floor that no longer exists; retuning it on the chip is ROADMAP S1.
+# below this candidate count the host ranks: a device round trip costs
+# more than scoring the block. Measured on the chip's host (PERF.md 5,
+# ledger PR 33): the NumPy twin took 4.3-8.8 ms a query there, sixty array
+# calls that each hand the interpreter lock away; the fused native scorer
+# (CardinalRanker.rank, PR 34) is one call. Where the crossover to the
+# device lies now is ROADMAP R11.
 SMALL_RANK_N = 4096
 
 
@@ -546,6 +550,43 @@ def cardinal_scores_host(feats: np.ndarray, profile: "RankingProfile",
                                     P.pack_language(language), hostids)
 
 
+# the profile fields the native scorer's constants are made of
+_NATIVE_FIELDS = attrgetter(
+    "date", "wordsintitle", "wordsintext", "phrasesintext", "llocal",
+    "lother", "urllength", "urlcomps", "hitcount", "posintext",
+    "posinphrase", "posofphrase", "worddistance", "domlength", "tf",
+    "language", "appurl", "appdescr", "appauthor", "apptags", "appref",
+    "appemph", "catindexof", "cathasimage", "cathasaudio", "cathasvideo",
+    "cathasapp")
+_native_consts_cache: dict[tuple, np.ndarray | None] = {}
+
+
+def _native_consts(prof: RankingProfile) -> np.ndarray | None:
+    """The profile as native/yacytpu.cpp ytn_cardinal_scores reads it
+    (layout there), built once per distinct profile; None for a shift
+    outside 0..15, which only the NumPy twin defines."""
+    key = _NATIVE_FIELDS(prof)
+    try:
+        return _native_consts_cache[key]
+    except KeyError:
+        pass
+    consts = None
+    if 0 <= min(key) and max(key) <= 15:
+        coeffs = prof.norm_coeffs()
+        bits, shifts = prof.flag_coeffs()
+        consts = np.concatenate([
+            [P.F_FLAGS, P.F_HITCOUNT, P.F_WORDS_IN_TEXT, P.F_WORDS_IN_TITLE,
+             P.F_LANGUAGE, P.F_DOMLENGTH,
+             prof.domlength, prof.tf, prof.language, len(bits)],
+            np.abs(coeffs), np.where(_ACTIVE_COLS,
+                                     np.where(_NORM_DIRECT, 1, 2), 0),
+            bits, shifts]).astype(np.int32)
+    if len(_native_consts_cache) >= 64:   # profiles arrive on the wire
+        _native_consts_cache.clear()
+    _native_consts_cache[key] = consts
+    return consts
+
+
 class CardinalRanker:
     """Host-side wrapper: pad → upload → score_topk, profile baked in."""
 
@@ -606,19 +647,36 @@ class CardinalRanker:
     def _lang(self):
         return self._device_consts()[7]
 
-    def rank(self, plist, hosthashes=None, k: int = 10):
-        """(scores, docids) best-first over a PostingsList."""
+    def rank(self, plist, hosthashes=None, k: int = 10,
+             how: dict | None = None):
+        """(scores, docids) best-first over a PostingsList. `how`, if
+        given, is filled with the ranker that answered: `native` / `numpy`
+        (the host's two, equal bit for bit) or `device`."""
         n = len(plist)
         if n == 0:
             return np.empty(0, np.int32), np.empty(0, np.int32)
+        if how is None:
+            how = {}
         if n <= SMALL_RANK_N:
-            # host fast path: no kernel dispatch for tiny candidate sets
-            hostids = (hostid_array(plist.docids, hosthashes)
-                       if hosthashes is not None else None)
-            s = cardinal_scores_host(plist.feats, self.profile,
-                                     self._lang_str, hostids)
-            order = np.argsort(-s, kind="stable")[:k]
+            # host fast path: no kernel dispatch for tiny candidate sets.
+            # ONE native call where the library is loaded; the authority
+            # term needs the host counts and stays on the NumPy twin
+            consts = (_native_consts(self.profile)
+                      if self.profile.authority <= 12 else None)
+            got = None if consts is None else native.cardinal_topk(
+                plist.feats, consts, P.pack_language(self._lang_str), k)
+            if got is not None:
+                how["ranker"] = "native"
+                s, order = got
+            else:
+                how["ranker"] = "numpy"
+                hostids = (hostid_array(plist.docids, hosthashes)
+                           if hosthashes is not None else None)
+                s = cardinal_scores_host(plist.feats, self.profile,
+                                         self._lang_str, hostids)
+                order = np.argsort(-s, kind="stable")[:k]
             return s[order], plist.docids[order]
+        how["ranker"] = "device"
         npad = pad_to(n)
         feats = np.zeros((npad, P.NF), np.int32)
         feats[:n] = plist.feats

@@ -17,6 +17,7 @@ ReferenceContainer.java:397-489). Loading is best-effort:
 
 from __future__ import annotations
 
+import _ctypes
 import ctypes
 import logging
 import os
@@ -40,6 +41,13 @@ log = logging.getLogger("yacy.native")
 _load_lock = threading.Lock()
 _loaded = False
 LIB: ctypes.CDLL | None = None
+# the same library through a handle whose calls KEEP the interpreter lock:
+# for a kernel of some 10-100 us on a request's thread, where letting go of
+# the lock means waiting a switch interval to have it back (tools/
+# hostrank_harness.py; PERF.md section 6, PR 34)
+LIB_HELD: ctypes.PyDLL | None = None
+# what ytn_abi_version() of a library built from this tree's source says
+ABI_VERSION = 2
 
 
 # below these sizes the ctypes call overhead beats the kernel win; wrappers
@@ -56,7 +64,7 @@ def _build() -> None:
     try:
         res = subprocess.run(
             ["g++", "-O3", "-fPIC", "-shared", "-std=c++17",
-             "-o", tmp, _SRC_PATH],
+             "-ffp-contract=off", "-o", tmp, _SRC_PATH],
             capture_output=True, timeout=120)
         if res.returncode != 0 or not os.path.exists(tmp):
             raise OSError(
@@ -74,7 +82,6 @@ def _build() -> None:
 
 
 def _bind(lib: ctypes.CDLL) -> None:
-    lib.ytn_abi_version.restype = ctypes.c_int32
     lib.ytn_word_hash_batch.argtypes = [_u8p, _i64p, ctypes.c_int64, _u8p]
     lib.ytn_word_hash_batch.restype = None
     lib.ytn_sort_dedupe.argtypes = [_i32p, ctypes.c_int64, _i64p]
@@ -85,11 +92,36 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.ytn_remove_docids.argtypes = [_i32p, ctypes.c_int64, _i32p,
                                       ctypes.c_int64, _u8p]
     lib.ytn_remove_docids.restype = None
+    _bind_scorer(lib)
+
+
+def _bind_scorer(lib: ctypes.CDLL) -> None:
+    lib.ytn_cardinal_scores.argtypes = [
+        _i32p, ctypes.c_int64, ctypes.c_int32, _i32p, ctypes.c_int32,
+        ctypes.c_int64, _i64p, _i64p]
+    lib.ytn_cardinal_scores.restype = ctypes.c_int32
+
+
+def _open() -> ctypes.CDLL:
+    """dlopen, check the version, bind. A library that fails either is
+    closed again: for the same path the loader would hand the same
+    mapping back after a rebuild."""
+    lib = ctypes.CDLL(_SO_PATH)
+    try:
+        lib.ytn_abi_version.restype = ctypes.c_int32
+        got = lib.ytn_abi_version()
+        if got != ABI_VERSION:
+            raise OSError(f"abi version {got}, expected {ABI_VERSION}")
+        _bind(lib)
+    except (OSError, AttributeError):   # AttributeError: missing symbol
+        _ctypes.dlclose(lib._handle)
+        raise
+    return lib
 
 
 def load() -> ctypes.CDLL | None:
     """Load (building if needed) the native library; None on any failure."""
-    global _loaded, LIB
+    global _loaded, LIB, LIB_HELD
     if _loaded:
         return LIB
     with _load_lock:
@@ -99,21 +131,31 @@ def load() -> ctypes.CDLL | None:
             _loaded = True
             return None
         try:
+            built = False
             if not os.path.exists(_SO_PATH) or (
                     os.path.exists(_SRC_PATH)
                     and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_SO_PATH)):
                 if not os.path.exists(_SRC_PATH):
                     raise OSError(f"{_SRC_PATH} missing")
                 _build()
-            lib = ctypes.CDLL(_SO_PATH)
-            _bind(lib)
-            if lib.ytn_abi_version() != 1:
-                raise OSError("abi mismatch")
-            LIB = lib
-        except (OSError, AttributeError) as e:  # AttributeError: missing symbol
+                built = True
+            try:
+                LIB = _open()
+            except (OSError, AttributeError) as e:
+                # a stale library NEWER than the source (a checkout that
+                # moved back, a copied tree) would put every kernel on
+                # NumPy: build once from the source that is here
+                if built or not os.path.exists(_SRC_PATH):
+                    raise
+                log.info("native library stale (%s): rebuilding", e)
+                _build()
+                LIB = _open()
+            LIB_HELD = ctypes.PyDLL(_SO_PATH)   # the mapping _open() made
+            _bind_scorer(LIB_HELD)
+        except (OSError, AttributeError) as e:
             log.warning("native library unavailable (%s): the numpy "
-                        "fallbacks serve hashing/sort/join", e)
-            LIB = None
+                        "fallbacks serve hashing/sort/join/ranking", e)
+            LIB = LIB_HELD = None
         _loaded = True
         return LIB
 
@@ -203,3 +245,24 @@ def alive_mask(docids: np.ndarray, dead_sorted: np.ndarray) -> np.ndarray | None
                           dd.ctypes.data_as(_i32p), ctypes.c_int64(len(dd)),
                           out.ctypes.data_as(_u8p))
     return out.view(bool)
+
+
+def cardinal_topk(feats: np.ndarray, consts: np.ndarray, language_pref: int,
+                  k: int) -> tuple[np.ndarray, np.ndarray] | None:
+    """(int64 [n] scores, int64 [min(k, n)] best-first row order) of a
+    candidate block, in one call: ops/ranking.cardinal_scores_host and its
+    stable argsort. `consts` is the profile as ops/ranking._native_consts
+    packs it. None when the library is unavailable."""
+    if load() is None:
+        return None
+    f = _as_i32(feats)
+    n, nf = f.shape
+    if len(consts) != 10 + 2 * nf + 2 * int(consts[9]):
+        return None         # not the layout the library reads
+    scores = np.empty(n, dtype=np.int64)
+    order = np.empty(min(k, n), dtype=np.int64)
+    rc = LIB_HELD.ytn_cardinal_scores(
+        f.ctypes.data_as(_i32p), n, nf, consts.ctypes.data_as(_i32p),
+        language_pref, len(order), scores.ctypes.data_as(_i64p),
+        order.ctypes.data_as(_i64p))
+    return (scores, order) if rc == 0 else None
