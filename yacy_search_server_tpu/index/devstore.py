@@ -1382,6 +1382,10 @@ class DeviceArena:
         self._bm_cap = 0
         self._bm_used = 0
         self._bm_refused = 0     # segments append_join_bitmaps gave no slot
+        # join static keys (k, n_inc, n_exc, r, inc_ms, exc_ms, inc_bm,
+        # exc_bm) the store dispatched against this arena: a gauge of
+        # its compile families, gone with the arena
+        self.join_shapes: set = set()
         self._bmtab = self._dev(np.zeros((1, 1, 2), np.int32))
         # packed-words store (compressed residency): bit-packed blocks
         # (ops/packed.py) appended as flat int32 word extents; the *_bp
@@ -1916,8 +1920,9 @@ class _QueryBatcher:
         re-emitted here as child spans."""
         sp = tracing.timed("devstore.batch", kind=item.get("kind", "term"))
         with sp:
-            if "membership" in item:        # a conjunction: which join
-                sp.set(membership=item["membership"])
+            if "membership" in item:        # a conjunction: which join,
+                sp.set(membership=item["membership"],   # how many partners
+                       partners=item["partners"])
             # one (epoch, perf_counter) pair places the batcher's
             # perf_counter stamps on the waterfall's clock
             epoch0 = time.time() - time.perf_counter()
@@ -1931,10 +1936,21 @@ class _QueryBatcher:
                 # the span record
                 shape = {"kernel": item.get("kernel_name", "?"),
                          "batch_n": item.get("batch_n", 0)}
+                # a conjunction's walls say how many partners it joined
+                joined = ({"partners": item["partners"]}
+                          if "partners" in item else {})
                 t_issue = item.get("issue_t0", 0.0)
                 t_fetch = item.get("fetch_t0", 0.0)
                 tracing.emit(f"kernel.{shape['kernel']}", km,
-                             ts=epoch0 + t_issue, batch=shape["batch_n"])
+                             ts=epoch0 + t_issue, batch=shape["batch_n"],
+                             **joined)
+                if joined.get("partners", 0) >= 2:
+                    # a windowed family cannot be read by attr: the
+                    # dispatches that loop over partners get their own
+                    tracing.record("kernel.join_multi", km,
+                                   ts=epoch0 + t_issue,
+                                   batch=shape["batch_n"], **joined)
+                shape.update(joined)
                 # enqueue -> a dispatcher takes the part, then the
                 # round-trip decomposition (pipelined dispatch): issue =
                 # host-side async dispatch of the jitted call; device =
@@ -2089,6 +2105,7 @@ class _QueryBatcher:
                 "joincap": (self.max_batch if all_bm
                             else self.MAX_JOIN_BATCH),
                 "membership": "bitmap" if all_bm else "sortmerge",
+                "partners": n_inc,
                 "profile": profile, "lang": language,
                 "ev": threading.Event(), "res": ("ineligible",),
                 "lk": threading.Lock(), "taken": False}
@@ -3197,6 +3214,8 @@ class DeviceSegmentStore:
         self.join_served = 0
         self.join_sm_served = 0   # of join_served: >= 1 sort-merge
         #   membership (a partner without a join bitmap)
+        self.join_partners = 0    # include partners, summed over served joins
+        self.join_multi_served = 0   # of join_served: >= 2 include partners
         self.join_fallbacks = 0
         self.join_degraded_plain = 0  # join-shaped, served by rank_term
         #   (every exclusion was a nonexistent term)
@@ -4453,11 +4472,16 @@ class DeviceSegmentStore:
             "prewarm_failures": self.prewarm_failures,
             "join_served": self.join_served,
             "join_sm_served": self.join_sm_served,
+            "join_partners": self.join_partners,
+            "join_multi_served": self.join_multi_served,
             "join_fallbacks": self.join_fallbacks,
             # gauges of the arena that serves now: bitmap slots in use,
             # and lists of >= JOIN_BITMAP_MIN rows that were refused one
             "join_bitmap_slots": self.arena.bitmap_slots,
             "join_bitmap_refused": self.arena.bitmap_refused,
+            # distinct join static keys dispatched since that arena was
+            # built: each is a compile family of its own
+            "join_shapes": len(self.arena.join_shapes),
             "join_degraded_plain": self.join_degraded_plain,
             # batched hybrid rerank: queries / dispatches is the mean
             # coalescing factor (the --rerank-overhead gate asserts > 1
@@ -4879,14 +4903,20 @@ class DeviceSegmentStore:
             host = self.device_fetch(out)
             self.count_round_trip()
             _emit_rt_spans((t1j - t0j) * 1e3,
-                           (time.perf_counter() - t1j) * 1e3)
+                           (time.perf_counter() - t1j) * 1e3,
+                           kernel="join_multi" if len(partners) >= 2
+                           else None)
             half = host.shape[1] // 2
             s, d = host[0, :half], host[0, half:]
         keep = (d >= 0) & (s > NEG_INF32)
         with self._lock:   # exact under concurrency
             self.queries_served += 1
+            self.join_partners += len(partners)
+            if len(partners) >= 2:
+                self.join_multi_served += 1
             if not all_bm:
                 self.join_sm_served += 1
+            self.arena.join_shapes.add(statics)
         return s[keep][:k], d[keep][:k], considered
 
     def _prewarm_join_shapes(self, arrays, join, dead, statics, profile,

@@ -388,18 +388,21 @@ class Segment:
         term is probed only where the cheap bounds (rwi.count_bounds)
         prove it longer than the list the rows are taken from. `how`,
         if given, receives the path taken (`single` term, `probe`,
-        `merge`) and the posting rows read."""
+        `merge`), the posting rows read and how many lists were
+        probed."""
         inc = list(include_hashes or []) + [word2hash(w) for w in (include_words or [])]
         exc = list(exclude_hashes or []) + [word2hash(w) for w in (exclude_words or [])]
         if how is None:
             how = {}
-        how.update(path="single" if len(inc) == 1 else "merge", rows=0)
+        how.update(path="single" if len(inc) == 1 else "merge", rows=0,
+                   probes=0)
         if not inc:
             return PostingsList.empty()
         rwi = self.rwi
 
         def probe_of(th, want_feats=True):
             how["path"] = "probe"       # one probed term names the path
+            how["probes"] += 1
 
             def probe(docids):
                 found, rows = rwi.probe(th, docids, want_feats)

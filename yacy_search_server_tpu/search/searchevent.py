@@ -52,6 +52,11 @@ from .snippet import extract_snippet
 # rechecks still fill the page (reference pulls from an unbounded-ish heap)
 TOPK_OVERSAMPLE = 8
 
+# a conjunction's word count is a stage-counter label (JOIN_TERMS_<n>) up
+# to the device join's term cap (devstore.MAX_JOIN_TERMS); more words
+# share JOIN_TERMS_MANY, so a hostile query mints no series
+JOIN_TERMS_LABELS = 6
+
 # the columns every ResultEntry is built from (SearchEvent._make_entry)
 ENTRY_FIELDS = ("sku", "title", "host_s", "url_file_ext_s", "language_s",
                 "size_i", "wordcount_i", "last_modified_days_i",
@@ -262,14 +267,28 @@ class SearchEvent:
         # long lists probed from the short one, or lists of a size
         # merged) and how many posting rows that took; each path is a
         # stage counter of its own (yacy_stage_events_total JOIN_<PATH>)
+        # So is the number of lists the conjunction had (JOIN_TERMS_<n>);
+        # a join that probed two lists or more is a family of its own
+        # beside `search.join`, which a windowed reader cannot split by
+        # attr
         how: dict = {}
+        n_terms = len(q.goal.include_hashes)
+        t0 = time.perf_counter()
         with StageTimer(EClass.SEARCH, "JOIN") as stage:
             joined = self.segment.term_search(
                 include_hashes=q.goal.include_hashes or None,
                 exclude_hashes=q.goal.exclude_hashes or None, how=how)
             stage.count = how["rows"]
-            stage.set(path=how["path"], rows=how["rows"])
+            stage.set(path=how["path"], rows=how["rows"], terms=n_terms,
+                      probes=how["probes"])
+        if how["probes"] >= 2:
+            tracing.record("search.join.multiprobe",
+                           (time.perf_counter() - t0) * 1e3,
+                           terms=n_terms, probes=how["probes"])
         track(EClass.SEARCH, "JOIN_" + how["path"].upper(), how["rows"])
+        track(EClass.SEARCH, "JOIN_TERMS_" + (
+            str(n_terms) if n_terms <= JOIN_TERMS_LABELS else "MANY"),
+            how["rows"])
         self.local_rwi_considered = len(joined)
         if len(joined) == 0:
             return None

@@ -482,7 +482,7 @@ def prometheus_text(sb, include_buckets: bool = True,
              "device store serving counters")
     for key in ("queries_served", "fallbacks", "stream_scans",
                 "filtered_served", "join_served", "join_sm_served",
-                "join_fallbacks",
+                "join_partners", "join_multi_served", "join_fallbacks",
                 "batch_dispatches", "batch_exceptions",
                 "batch_ineligible", "prune_rounds",
                 # kernel shapes the start-up prewarm could not compile
@@ -512,6 +512,11 @@ def prometheus_text(sb, include_buckets: bool = True,
              {"state": "slots"})
     p.sample("yacy_devstore_join_bitmaps", c.get("join_bitmap_refused", 0),
              {"state": "refused"})
+    p.family("yacy_devstore_join_shapes", "gauge",
+             "distinct join static keys (partners, rare bucket, "
+             "membership modes) dispatched since the serving arena was "
+             "built: a compile family each")
+    p.sample("yacy_devstore_join_shapes", c.get("join_shapes", 0))
     # HBM accounting for the fleet (ISSUE 8 satellite): per-tier byte
     # occupancy and the promotion/demotion flow — always emitted (zeros
     # without a devstore) so the fleet digest's tier fields and any

@@ -627,6 +627,9 @@ def device_store(header, post, sb):
         c = ds.counters()
         rows += [
             ("join_sm_served", c["join_sm_served"]),
+            ("join_partners", c["join_partners"]),
+            ("join_multi_served", c["join_multi_served"]),
+            ("join_shapes", c["join_shapes"]),
             ("join_bitmap_slots", c["join_bitmap_slots"]),
             ("join_bitmap_refused", c["join_bitmap_refused"]),
             ("arena_rows_used", ds.arena.used_rows),
