@@ -38,7 +38,15 @@ class QueryLogEntry:
 
 
 class AccessTracker:
-    """Bounded query history with optional file dump + per-host counters."""
+    """Bounded query history with optional file dump + per-host counters.
+
+    Two states with a lock each: the front door's window
+    (`track_access`, every request's first step) never waits for a
+    query being logged (`add`).  The dump file has a third lock of its
+    own and is never opened under either of them: file I/O lets go of
+    the interpreter, and whoever then runs into a lock held across it
+    blocks, and needs the interpreter back from whoever runs (ISSUE 38).
+    """
 
     def __init__(self, dump_path: str | None = None):
         self.dump_path = dump_path
@@ -46,40 +54,65 @@ class AccessTracker:
         self._undumped: list[str] = []
         self._host_access: dict[str, deque[float]] = {}
         self._access_calls = 0
-        self._lock = threading.Lock()
+        self._log_lock = threading.Lock()
+        self._host_lock = threading.Lock()
+        self._file_lock = threading.Lock()
         if dump_path:
             os.makedirs(os.path.dirname(dump_path), exist_ok=True)
 
     # -- query log -----------------------------------------------------------
 
     def add(self, entry: QueryLogEntry) -> None:
-        with self._lock:
+        line = entry.dump_line() if self.dump_path else None
+        with self._log_lock:
             self._finished.append(entry)
-            if self.dump_path:
-                self._undumped.append(entry.dump_line())
-                if len(self._undumped) >= DUMP_BATCH:
-                    self._dump_locked()
+            if line is None:
+                return
+            self._undumped.append(line)
+            full = len(self._undumped) >= DUMP_BATCH
+        if full:
+            self._write(wait=False)
 
     def latest(self, n: int = 50) -> list[QueryLogEntry]:
-        with self._lock:
+        with self._log_lock:
             return list(self._finished)[-n:][::-1]
 
     def size(self) -> int:
-        with self._lock:
+        with self._log_lock:
             return len(self._finished)
 
-    def _dump_locked(self) -> None:
-        lines, self._undumped = self._undumped, []
-        try:
-            with open(self.dump_path, "a", encoding="utf-8") as f:
-                f.write("\n".join(lines) + "\n")
-        except OSError:
-            pass
+    def _write(self, wait: bool) -> None:
+        """Append the buffered lines to the file.  They are taken out
+        under the file's lock, so batches land in arrival order; an
+        `add` that finds a writer at work (`wait` false) leaves its
+        lines to it and does not wait for the file.
+
+        What makes that safe is the order of the writer's last two
+        steps: it looks at the buffer again AFTER it has let go of the
+        file's lock.  A batch that filled during the write either found
+        the lock free again (and writes itself) or filled before that
+        look (and the writer goes round for it): a full batch is never
+        left with nobody to write it."""
+        while self._file_lock.acquire(blocking=wait):
+            try:
+                with self._log_lock:
+                    lines, self._undumped = self._undumped, []
+                path = self.dump_path
+                if lines and path:
+                    try:
+                        with open(path, "a", encoding="utf-8") as f:
+                            f.write("\n".join(lines) + "\n")
+                    except OSError:
+                        pass
+            finally:
+                self._file_lock.release()
+            # a batch that filled meanwhile found the file's lock taken
+            with self._log_lock:
+                if len(self._undumped) < DUMP_BATCH:
+                    return
 
     def dump(self) -> None:
-        with self._lock:
-            if self._undumped:
-                self._dump_locked()
+        self._write(wait=True)
 
     # -- host access (abuse control surface) ---------------------------------
 
@@ -87,7 +120,7 @@ class AccessTracker:
         """Record one access from `client_host`; returns accesses within the
         window (callers throttle above a threshold)."""
         now = time.time()
-        with self._lock:
+        with self._host_lock:
             # maxlen bounds a flooding client's memory; the window prune
             # below keeps the COUNT honest for throttling decisions
             times = self._host_access.setdefault(
@@ -122,7 +155,7 @@ class AccessTracker:
         out, not `over` — an off-by-one here 429s the very client that
         honored the header exactly."""
         now = time.time()
-        with self._lock:
+        with self._host_lock:
             times = self._host_access.get(client_host)
             if not times:
                 return 0.0
@@ -137,7 +170,7 @@ class AccessTracker:
             return max(0.0, times[i] + window_s - now + 0.001)
 
     def access_hosts(self, window_s: float = 600.0) -> list[tuple[str, int]]:
-        with self._lock:
+        with self._host_lock:
             self._prune_hosts_locked(time.time() - window_s)
             return sorted(((h, len(t)) for h, t in self._host_access.items()),
                           key=lambda x: -x[1])

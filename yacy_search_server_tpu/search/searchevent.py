@@ -144,6 +144,10 @@ class SearchEvent:
 
     def __init__(self, query: QueryParams, segment: Segment, loader=None):
         self.query = query
+        # the id the event cache keeps this event under and the page
+        # hands out as `eventID`: taken once, here (`query` is shared by
+        # every request the event answers and is not changed after)
+        self.event_id = query.query_id()
         self.segment = segment
         # crawler loader for LIVE snippet production (None: cache-local
         # extraction only — embedded/federated events have no crawler)

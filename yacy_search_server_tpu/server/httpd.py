@@ -285,8 +285,12 @@ class YaCyHttpServer:
                 # drain time (when the oldest over-limit hit ages out)
                 # or the bucket's refill ETA; both tripping takes the
                 # longer wait
+                # (loopback is counted and never denied: its wait is
+                # not asked for, which would take the window's lock a
+                # second time for a number nobody reads)
+                exempt = client_ip in ("127.0.0.1", "::1")
                 over, retry_s = hits > limit, 0.0
-                if over:
+                if over and not exempt:
                     retry_s = max(1.0, tracker.retry_after_s(
                         client_ip, limit))
                 if act is not None:
@@ -294,7 +298,7 @@ class YaCyHttpServer:
                     if not admitted:
                         over = True
                         retry_s = max(retry_s, bucket_retry)
-                if over and client_ip not in ("127.0.0.1", "::1"):
+                if over and not exempt:
                     # ceil, never truncate: a client honoring the
                     # header exactly must be admitted on its retry
                     self._send(handler, 429, "text/plain",

@@ -11,6 +11,7 @@ loop grammar).
 from __future__ import annotations
 
 import html
+import re
 from typing import Any, Iterator
 
 
@@ -24,24 +25,20 @@ def escape_xml(s: str) -> str:
             .replace("'", "&apos;"))
 
 
+# what JSON cannot carry raw inside a string: the 32 control characters
+# (three by name, the rest as \u00xx), the quote and the backslash; every
+# other code point, lone surrogates and astral ones included, passes
+_JSON_ESCAPES = {i: "\\u%04x" % i for i in range(0x20)}
+_JSON_ESCAPES.update({ord('"'): '\\"', ord("\\"): "\\\\", ord("\n"): "\\n",
+                      ord("\r"): "\\r", ord("\t"): "\\t"})
+_JSON_UNSAFE = re.compile('[\x00-\x1f"\\\\]')
+
+
 def escape_json(s: str) -> str:
-    out = []
-    for ch in str(s):
-        if ch == '"':
-            out.append('\\"')
-        elif ch == "\\":
-            out.append("\\\\")
-        elif ch == "\n":
-            out.append("\\n")
-        elif ch == "\r":
-            out.append("\\r")
-        elif ch == "\t":
-            out.append("\\t")
-        elif ord(ch) < 0x20:
-            out.append("\\u%04x" % ord(ch))
-        else:
-            out.append(ch)
-    return "".join(out)
+    # one scan finds most strings clean (a title, a URL, a host), and a
+    # table lookup a character is what `translate` costs the others
+    s = str(s)
+    return s if _JSON_UNSAFE.search(s) is None else s.translate(_JSON_ESCAPES)
 
 
 class ServerObjects:
@@ -65,6 +62,11 @@ class ServerObjects:
         if isinstance(value, bool):
             value = "1" if value else "0"
         self._map[str(key)] = str(value)
+
+    def put_strings(self, ready: dict[str, str]) -> None:
+        """Many properties at once, every key and value a `str` already
+        (escaped for the medium by the caller): nothing is converted."""
+        self._map.update(ready)
 
     def put_html(self, key: str, value: Any) -> None:
         self._map[str(key)] = escape_html(value)

@@ -20,45 +20,50 @@ from . import servlet
 
 
 def _fill_items(prop: ServerObjects, results, esc) -> None:
-    prop.put("items", len(results))
+    # every value is a str when it is written: the map takes them as
+    # they are, in one update (ServerObjects.put_strings)
+    last = len(results) - 1
+    m = {"items": str(len(results))}
     for i, r in enumerate(results):
         p = f"items_{i}_"
-        prop.put(p + "title", esc(r.title or r.url))
-        prop.put(p + "link", esc(r.url))
-        prop.put(p + "description", esc(r.snippet))
-        prop.put(p + "urlhash", r.urlhash.decode("ascii", "replace"))
-        prop.put(p + "host", esc(r.host))
-        prop.put(p + "size", r.size)
-        prop.put(p + "sizename", _sizename(r.size))
-        prop.put(p + "ranking", int(r.score))
-        prop.put(p + "source", esc(str(r.source)))
-        prop.put(p + "filetype", esc(r.filetype))
-        prop.put(p + "eol", 1 if i < len(results) - 1 else 0)
+        m[p + "title"] = esc(r.title or r.url)
+        m[p + "link"] = esc(r.url)
+        m[p + "description"] = esc(r.snippet)
+        m[p + "urlhash"] = r.urlhash.decode("ascii", "replace")
+        m[p + "host"] = esc(r.host)
+        m[p + "size"] = str(r.size)
+        m[p + "sizename"] = _sizename(r.size)
+        m[p + "ranking"] = str(int(r.score))
+        m[p + "source"] = esc(str(r.source))
+        m[p + "filetype"] = esc(r.filetype)
+        m[p + "eol"] = "1" if i < last else "0"
+    prop.put_strings(m)
 
 
 def _fill_image_items(prop: ServerObjects, images, esc) -> None:
     """Image-mode item properties (own result shape: the image URL plus
     source-page attribution — reference yacysearchitem.java image
     branch)."""
-    prop.put("items", len(images))
+    last = len(images) - 1
+    m = {"items": str(len(images))}
     for i, im in enumerate(images):
         p = f"items_{i}_"
-        prop.put(p + "image", esc(im.image_url))
-        prop.put(p + "alt", esc(im.alt))
-        prop.put(p + "title", esc(im.alt or im.source_title))
-        prop.put(p + "link", esc(im.image_url))
-        prop.put(p + "description", esc(im.alt))
-        prop.put(p + "sourcelink", esc(im.source_url))
-        prop.put(p + "sourcetitle", esc(im.source_title))
-        prop.put(p + "urlhash",
-                 im.source_urlhash.decode("ascii", "replace"))
-        prop.put(p + "host", esc(im.host))
-        prop.put(p + "size", 0)
-        prop.put(p + "sizename", "")
-        prop.put(p + "ranking", int(im.score))
-        prop.put(p + "source", esc(str(im.source)))
-        prop.put(p + "filetype", esc(im.filetype))
-        prop.put(p + "eol", 1 if i < len(images) - 1 else 0)
+        m[p + "image"] = esc(im.image_url)
+        m[p + "alt"] = esc(im.alt)
+        m[p + "title"] = esc(im.alt or im.source_title)
+        m[p + "link"] = esc(im.image_url)
+        m[p + "description"] = esc(im.alt)
+        m[p + "sourcelink"] = esc(im.source_url)
+        m[p + "sourcetitle"] = esc(im.source_title)
+        m[p + "urlhash"] = im.source_urlhash.decode("ascii", "replace")
+        m[p + "host"] = esc(im.host)
+        m[p + "size"] = "0"
+        m[p + "sizename"] = ""
+        m[p + "ranking"] = str(int(im.score))
+        m[p + "source"] = esc(str(im.source))
+        m[p + "filetype"] = esc(im.filetype)
+        m[p + "eol"] = "1" if i < last else "0"
+    prop.put_strings(m)
 
 
 def _sizename(n: int) -> str:
@@ -91,22 +96,24 @@ def _fill_navigation(prop: ServerObjects, event, esc,
                      base_query: str = "", url_suffix: str = "") -> None:
     navs = [(name, nav.top(10)) for name, nav in event.navigators.items()
             if len(nav) > 0]
-    prop.put("navigation", len(navs))
+    m = {"navigation": str(len(navs))}
     for i, (name, entries) in enumerate(navs):
         p = f"navigation_{i}_"
-        prop.put(p + "facetname", esc(name))
-        prop.put(p + "elements", len(entries))
+        m[p + "facetname"] = esc(name)
+        m[p + "elements"] = str(len(entries))
         mod = _FACET_MODIFIER.get(name)
+        last = len(entries) - 1
         for j, (value, count) in enumerate(entries):
             q = f"{p}elements_{j}_"
-            prop.put(q + "name", esc(str(value)))
-            prop.put(q + "count", count)
+            m[q + "name"] = esc(str(value))
+            m[q + "count"] = str(count)
             refined = (f"{base_query} {mod(value)}".strip()
                        if mod and base_query else base_query)
-            prop.put(q + "url",
-                     "yacysearch.html?query=" + quote(refined) + url_suffix)
-            prop.put(q + "eol", 1 if j < len(entries) - 1 else 0)
-        prop.put(p + "eol", 1 if i < len(navs) - 1 else 0)
+            m[q + "url"] = ("yacysearch.html?query=" + quote(refined)
+                            + url_suffix)
+            m[q + "eol"] = "1" if j < last else "0"
+        m[p + "eol"] = "1" if i < len(navs) - 1 else "0"
+    prop.put_strings(m)
 
 
 def _esc_for(ext: str):
@@ -160,11 +167,12 @@ def _respond_search(header: dict, post: ServerObjects, sb) -> ServerObjects:
     t0 = time.time()
     contentdom = post.get("contentdom", "").lower()
     image_mode = contentdom == "image"
+    hybrid = post.get_bool("hybrid", False)
+    dense_first = post.get_bool("densefirst", False)
     event = sb.search(query, count=count, offset=offset,
-                      hybrid=post.get_bool("hybrid", False),
-                      contentdom=contentdom,
+                      hybrid=hybrid, contentdom=contentdom,
                       use_cache=not post.get_bool("nocache", False),
-                      dense_first=post.get_bool("densefirst", False))
+                      dense_first=dense_first)
     if post.get("resource", "") == "global":
         _remote_fanout(sb, event, count)
     if image_mode:
@@ -189,35 +197,30 @@ def _respond_search(header: dict, post: ServerObjects, sb) -> ServerObjects:
         prop.put("found", 1 if results else 0)
         _fill_items(prop, results, esc)
     prop.put("contentdom_image", 1 if image_mode else 0)
-    # page size + ranking mode must survive navigation, or page 2 would
-    # re-rank differently and repeat/skip results
-    suffix = f"&maximumRecords={count}"
-    if post.get_bool("hybrid", False):
-        suffix += "&hybrid=true"
-    if post.get_bool("densefirst", False):
-        # dense-first must survive paging like the hybrid flag — page 2
-        # under a different retrieval mode would repeat/skip results
-        suffix += "&densefirst=true"
+    # the ranking mode must survive a tab switch, and the page size and
+    # the content domain with it must survive navigation, or page 2
+    # would re-rank differently and repeat/skip results (dense-first
+    # sheds a rung of its own: it rides like the hybrid flag)
+    mode = ("&hybrid=true" if hybrid else "") \
+        + ("&densefirst=true" if dense_first else "")
+    suffix = f"&maximumRecords={count}{mode}"
     if contentdom:
         suffix += f"&contentdom={quote(contentdom)}"
     _fill_navigation(prop, event, esc, base_query=query, url_suffix=suffix)
-    # pagination (yacysearch paging over the cached event)
-    qq = quote(query)
-    # content-domain tabs (the reference's Text/Images/... search tabs);
-    # the hybrid flag must survive a tab switch like it survives paging
-    hybrid_part = "&hybrid=true" if post.get_bool("hybrid", False) else ""
-    if post.get_bool("densefirst", False):
-        hybrid_part += "&densefirst=true"
+    # pagination (yacysearch paging over the cached event) and the
+    # content-domain tabs (the reference's Text/Images/... search tabs)
+    here = "yacysearch.html?query=" + quote(query)
+    tabs = f"{here}&maximumRecords={count}{mode}"
+    active = contentdom or "text"
+    m = {}
     for name in ("text", "image", "audio", "video", "app"):
-        prop.put(f"tab_{name}_url",
-                 f"yacysearch.html?query={qq}&maximumRecords={count}"
-                 f"{hybrid_part}"
-                 + (f"&contentdom={name}" if name != "text" else ""))
-        prop.put(f"tab_{name}_active",
-                 1 if (contentdom or "text") == name else 0)
+        m[f"tab_{name}_url"] = \
+            tabs + (f"&contentdom={name}" if name != "text" else "")
+        m[f"tab_{name}_active"] = "1" if active == name else "0"
+    prop.put_strings(m)
     prop.put("hasprev", 1 if offset > 0 else 0)
-    prop.put("prevurl", f"yacysearch.html?query={qq}"
-                        f"&startRecord={max(0, offset - count)}{suffix}")
+    prop.put("prevurl",
+             f"{here}&startRecord={max(0, offset - count)}{suffix}")
     got_n = len(images) if image_mode else len(results)
     if image_mode:
         more = image_more
@@ -225,12 +228,11 @@ def _respond_search(header: dict, post: ServerObjects, sb) -> ServerObjects:
         # snippet-evicted heap slots never render: count live ones only
         more = event.results_available() > offset + got_n
     prop.put("hasnext", 1 if (more and got_n) else 0)
-    prop.put("nexturl", f"yacysearch.html?query={qq}"
-                        f"&startRecord={offset + count}{suffix}")
+    prop.put("nexturl", f"{here}&startRecord={offset + count}{suffix}")
     # progressive delivery handle: the page's script can pull items
     # one-by-one from /yacysearchitem.html?eventID=...&item=N while
     # remote feeders are still filling the event
-    prop.put("eventID", esc(event.query.query_id()))
+    prop.put("eventID", esc(event.event_id))
     # the request's trace id: paste into Performance_Trace_p?trace=...
     # to see this exact search's waterfall
     prop.put("traceID", esc(tracing.current_trace_id() or ""))

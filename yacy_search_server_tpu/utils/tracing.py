@@ -48,6 +48,7 @@ from __future__ import annotations
 import gc
 import itertools
 import json
+import os
 import secrets
 import sys
 import threading
@@ -137,8 +138,27 @@ def set_enabled(on: bool) -> None:
     _enabled = bool(on)
 
 
+# A trace id is what `do_tracefetch` (peers/server.py) asks of a peer
+# before it hands out a trace's spans, the query's text among them, so
+# it stays unguessable: 64 bits of the system's entropy.  They are read
+# 512 ids at a time: `os.urandom` lets go of the interpreter lock, and
+# once a request is where a request's threads queue (ISSUE 38).  A
+# forked child drops what it inherited, or it would hand out its
+# parent's ids.
+_ID_HEX = 16
+_id_pool: list[str] = []
+os.register_at_fork(after_in_child=_id_pool.clear)
+
+
 def new_trace_id() -> str:
-    return secrets.token_hex(8)
+    try:
+        return _id_pool.pop()
+    except IndexError:
+        raw = secrets.token_hex(512 * _ID_HEX // 2)
+        # (a list, not a generator: one `extend` that no `pop` cuts into)
+        _id_pool.extend([raw[i:i + _ID_HEX]
+                         for i in range(_ID_HEX, len(raw), _ID_HEX)])
+        return raw[:_ID_HEX]
 
 
 def valid_trace_id(tid) -> bool:
