@@ -339,3 +339,182 @@ def test_bitmap_repack_rebuilds_slots(seg_bm):
     assert all(s >= 0 for s in after.values()), after
     assert before  # repack kept every term bitmap-served
     _assert_join_matches(seg_bm, [word2hash("aa"), word2hash("cc")], [])
+
+
+# -- progressive intersection (the later memberships over the first one's
+# survivors, a bucket of r/4 lanes at a time) ------------------------------
+#
+# One rare list of 4,000 rows (window r = 4,096, bucket 1,024) and
+# partners of every density: the first membership (the SHORTEST partner,
+# whatever the word order) leaves 0 lanes (a disjoint list), ~500 (one
+# bucket), ~1,000 or ~3,200 (past one bucket: four buckets) for the later
+# ones. Every answer is held to the host join; the counters to the lanes
+# a host intersection of the first membership leaves, where the join
+# compacts (three include partners or more), and to nothing where it does
+# not.
+
+RARE_ROWS = 4_000
+# name -> (rows, docid pool): the pool is the rare list's 40,000 ids
+# but for `dz`, which shares none of them
+MANY = {"rr": (RARE_ROWS, None), "dz": (4_500, "disjoint"),
+        "p12": (5_000, None), "p25": (10_000, None), "x30": (12_000, None),
+        "p50": (20_000, None), "p80": (32_000, None), "p90": (36_000, None),
+        "p95": (38_000, None)}
+MODES = {"bitmap": 1_000, "sortmerge": 10 ** 9, "mixed": 15_000}
+
+
+@pytest.fixture(scope="module",
+                params=[(m, dead) for m in MODES for dead in (False, True)],
+                ids=[m + dead for m in MODES for dead in ("", "-dead")])
+def seg_many(request):
+    """(segment, {term: docids}, tombstoned docids): the partners of a
+    join on bitmaps, on the sort-merge, or both (the lists of 15,000 rows
+    or more on bitmaps); with or without a slice of the rare list's
+    docids tombstoned."""
+    from yacy_search_server_tpu.index.devstore import DeviceSegmentStore
+    mode, tombstones = request.param
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(DeviceSegmentStore, "JOIN_BITMAP_MIN", MODES[mode])
+        seg = Segment(max_ram_postings=10)
+        rng = np.random.default_rng(40)
+        pool = np.arange(40_000)
+        lists = {t: _plist(rng, n, pool if where is None
+                           else np.arange(10 ** 6, 10 ** 6 + 40_000))
+                 for t, (n, where) in MANY.items()}
+        seg.rwi.ingest_run({word2hash(t): pl for t, pl in lists.items()})
+        seg.enable_device_serving()
+        bm = {t: seg.devstore.spans_for(word2hash(t))[0].jslot >= 0
+              for t in MANY}
+        assert any(bm.values()) is (mode != "sortmerge")
+        if mode == "mixed":
+            assert bm["p80"] and not bm["p25"]
+        rare = lists["rr"].docids
+        dead = (rare[::97].tolist() + rare[-40:].tolist()
+                if tombstones else [])
+        for docid in dead:
+            seg.rwi.delete_doc(int(docid))
+        yield seg, {t: pl.docids for t, pl in lists.items()}, dead
+        seg.close()
+
+
+def _first_survivors(docids, inc, exc, dead=()):
+    """Lanes live after a join's first membership: the rare list's live
+    docids in its shortest partner, or, with no include partner, out of
+    its first exclude."""
+    rare, *partners = sorted(inc, key=lambda t: len(docids[t]))
+    live = set(docids[rare].tolist()) - set(dead)
+    if partners:
+        return len(live & set(docids[partners[0]].tolist()))
+    return len(live - set(docids[exc[0]].tolist()))
+
+
+def _compact_deltas(ds, before):
+    c = ds.counters()
+    return {k: c[k] - before[k] for k in (
+        "join_compacted", "join_survivors", "join_compact_rows",
+        "join_compact_overflow", "join_multi_served")}
+
+
+PROGRESSIVE = [
+    # (includes in word order, excludes): 1 to 5 partners, 2 to 6
+    # memberships, the shortest partner anywhere in the order
+    (["p50", "rr", "p12"], []),                   # 2 memberships
+    (["p80", "p90", "rr"], []),                   # two partners: no bucket
+    (["rr", "p90", "p80", "p50"], []),
+    (["p25", "p90", "rr", "p80", "p50"], []),
+    (["p90", "p50", "p80", "p25", "p12", "rr"], []),
+    (["p50", "dz", "rr", "p12"], []),             # 0 live after the first
+    (["rr", "p80"], ["x30"]),                     # 1 partner + 1 exclude
+    (["p90", "rr", "p25"], ["x30", "p12"]),
+    (["rr"], ["x30", "p12"]),                     # excludes only
+    (["p80", "rr", "p90", "p50"], ["x30", "p25"]),  # 6 memberships
+    (["rr", "p80"], ["x30", "p12"]),              # 1 partner + 2 excludes
+    (["p12", "rr", "p25"], ["dz"]),
+    (["p80", "p90", "rr", "p95"], ["x30"]),       # ~3,200 live, 3 partners
+]
+
+
+@pytest.mark.parametrize("inc,exc", PROGRESSIVE,
+                         ids=["-".join(i) + ("~" + "-".join(e) if e else "")
+                              for i, e in PROGRESSIVE])
+def test_progressive_join_parity(seg_many, inc, exc):
+    from yacy_search_server_tpu.index.devstore import _join_bucket
+    seg, docids, dead = seg_many
+    ds = seg.devstore
+    c0 = ds.counters()
+    _assert_join_matches(seg, [word2hash(t) for t in inc],
+                         [word2hash(t) for t in exc])
+    bucket = _join_bucket(4_096, len(inc) - 1, len(exc))
+    assert bool(bucket) is (len(inc) - 1 >= 3)
+    n = _first_survivors(docids, inc, exc, dead) if bucket else 0
+    assert _compact_deltas(ds, c0) == {
+        "join_compacted": int(bool(bucket)), "join_survivors": n,
+        "join_compact_rows": RARE_ROWS if bucket else 0,
+        "join_compact_overflow": int(bool(bucket) and n > bucket),
+        "join_multi_served": int(len(inc) >= 3)}
+
+
+def test_a_two_word_join_does_not_compact(seg_many):
+    seg, _docids, _mode = seg_many
+    ds = seg.devstore
+    for inc, exc in ((["p80", "rr"], []), (["rr"], ["x30"])):
+        c0 = ds.counters()
+        _assert_join_matches(seg, [word2hash(t) for t in inc],
+                             [word2hash(t) for t in exc])
+        assert set(_compact_deltas(ds, c0).values()) == {0}
+
+
+def test_the_matrix_meets_every_survivor_count(seg_many):
+    """Joins that compact with 0 lanes, one bucket's worth, and more
+    than two buckets."""
+    from yacy_search_server_tpu.index.devstore import _join_bucket
+    _seg, docids, dead = seg_many
+    n = sorted(_first_survivors(docids, i, e, dead) for i, e in PROGRESSIVE
+               if _join_bucket(4_096, len(i) - 1, len(e)))
+    bucket = _join_bucket(4_096, 3, 0)
+    assert n[0] == 0 and 0 < n[1] <= bucket and n[-1] > 2 * bucket
+
+
+WAVE = [["rr", "p12", "p50", "p95"], ["rr", "p80", "p90", "p95"],
+        ["rr", "dz", "p50", "p95"], ["rr", "p25", "p90", "p95"]]
+# ~500, ~3,200, 0 and ~1,000 live; three partners, so each compacts
+
+
+@pytest.mark.parametrize("seg_many", [("bitmap", False), ("bitmap", True)],
+                         indirect=True, ids=["bitmap", "bitmap-dead"])
+def test_a_wave_of_four_with_unequal_survivors(seg_many, monkeypatch):
+    """Four conjunctions of one compile shape in ONE vmapped dispatch
+    (all-bitmap joins are the ones that batch past four) run the program
+    a single query runs, each over its own number of buckets: each row
+    equals its query's solo answer bit for bit, survivors included."""
+    from yacy_search_server_tpu.index import devstore as DS
+    seg, docids, dead = seg_many
+    ds = seg.devstore
+    real = DS._rank_join_bm_batch_packed_kernel
+    calls = []
+
+    def spy(*args, **kw):
+        calls.append((args, kw))
+        return real(*args, **kw)
+    monkeypatch.setattr(DS, "_rank_join_bm_batch_packed_kernel", spy)
+    solo = []
+    for inc in WAVE:
+        c0 = ds.counters()
+        _assert_join_matches(seg, [word2hash(t) for t in inc], [])
+        solo.append(_compact_deltas(ds, c0)["join_survivors"])
+        assert solo[-1] == _first_survivors(docids, inc, [], dead)
+    kw = calls[0][1]
+    bucket = DS._join_bucket(kw["r"], kw["n_inc"], kw["n_exc"])
+    assert len(set(solo)) == 4 and 0 in solo and max(solo) > 2 * bucket
+    assert len(calls) == 4 and all(kw == calls[0][1] for _a, kw in calls)
+    args, kw = calls[0]
+    qpos = 7                                    # (..., bmtab, qargs, ...)
+    wave = np.concatenate([a[qpos] for a, _kw in calls])
+    out = np.asarray(real(*args[:qpos], wave, *args[qpos + 1:], **kw))
+    s, d, n = DS._split_join_out(out, bucket)
+    assert n.tolist() == solo
+    for i, (a, _kw) in enumerate(calls):
+        s1, d1, n1 = DS._split_join_out(np.asarray(real(*a, **kw)), bucket)
+        assert n1.tolist() == [solo[i]]
+        np.testing.assert_array_equal(s[i], s1[0])
+        np.testing.assert_array_equal(d[i], d1[0])

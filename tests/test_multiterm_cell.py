@@ -130,6 +130,16 @@ def test_a_class_equals_the_reference(node, cls):
         assert d["join_multi_served"] == len(queries)
         assert d["join_sm_served"] == (
             len(queries) if mode == "sortmerge" else 0)
+        # three partners or more: every later membership over the first
+        # one's survivors, ~1/8 of the rare list (a regular term holds
+        # 1/8 of its topic), one bucket of a quarter; two: none
+        compacts = partners >= 3
+        assert d["join_compacted"] == (len(queries) if compacts else 0)
+        assert d["join_compact_overflow"] == 0
+        if compacts:
+            assert 0.1 < d["join_survivors"] / d["join_compact_rows"] < 0.25
+        else:
+            assert d["join_survivors"] == d["join_compact_rows"] == 0
     else:                       # a rare word: the host gate keeps it
         assert d["join_served"] == 0
 
@@ -222,8 +232,10 @@ def test_a_device_join_says_how_many_partners_it_had(node, cls, partners):
         for i in range(int(table.get("stages")))}
     # two words: one partner, and no observation of the multi family
     histogram.reset_windows()
+    compacted = ds.counters()["join_compacted"]
     _rec, by = _trace_of(sb, "zm0 zm4")
     assert by["devstore.batch"][0].attrs["partners"] == 1
+    assert ds.counters()["join_compacted"] == compacted
     assert "kernel.join_multi" not in by
     assert histogram.get("kernel.join_multi").windowed_count() == 0
 
@@ -280,12 +292,17 @@ def test_the_partner_counters_are_scraped(node):
     assert c["join_multi_served"] > 0 and c["join_shapes"] > 0
     assert c["join_partners"] >= 2 * c["join_multi_served"]
     samples = parse_exposition(prometheus_text(sb))
-    for key in ("join_partners", "join_multi_served"):
+    assert 0 < c["join_compacted"] <= c["join_multi_served"]
+    assert c["join_survivors"] > 0 and c["join_compact_rows"] > 0
+    compact = ("join_compacted", "join_survivors", "join_compact_rows",
+               "join_compact_overflow")
+    for key in ("join_partners", "join_multi_served") + compact:
         assert samples[
             f'yacy_device_serving_total{{counter="{key}"}}'] == c[key]
     assert samples["yacy_devstore_join_shapes"] == c["join_shapes"]
     prop = device_store({}, ServerObjects(), sb)
     rows = {prop.get(f"rows_{i}_key"): prop.get(f"rows_{i}_value")
             for i in range(int(prop.get("rows", 0)))}
-    for key in ("join_partners", "join_multi_served", "join_shapes"):
+    for key in ("join_partners", "join_multi_served", "join_shapes") \
+            + compact:
         assert str(rows[key]) == str(c[key])

@@ -404,6 +404,50 @@ def test_xla_rank_join_bm(arena, r, bs):
     _close(c.flops, flops, f"join_bm[{r},{bs}] flops")
 
 
+# a join that compacts: the later memberships run over a bucket of r/4
+# lanes inside a loop whose body XLA counts once — the one-bucket cost
+@pytest.mark.parametrize("r,bs,n_inc,n_exc", ((65536, 1, 3, 0),
+                                              (131072, 1, 3, 0),
+                                              (65536, 1, 5, 0),
+                                              (65536, 1, 3, 2),
+                                              (65536, 4, 3, 0)))
+def test_xla_rank_join_bm_compacted(arena, r, bs, n_inc, n_exc):
+    from yacy_search_server_tpu.index import devstore as DS
+    n = n_inc + n_exc
+    qargs = np.zeros((bs, 6 + 3 * n), np.int32)
+    flops = _xla(DS._rank_join_bm_batch_kernel, arena["f16"],
+                 arena["fl"], arena["dd"], arena["dead"],
+                 arena["jd"], arena["jp"], arena["bmtab"], qargs,
+                 *_consts(), k=16, n_inc=n_inc, n_exc=n_exc, r=r,
+                 inc_ms=(0,) * n_inc, exc_ms=(0,) * n_exc,
+                 inc_bm=(True,) * n_inc, exc_bm=(True,) * n_exc)
+    bucket = DS._join_bucket(r, n_inc, n_exc)
+    assert bucket == r // 4
+    c = RF.cost("_rank_join_bm_batch_kernel", r=r, n_inc=n_inc,
+                n_exc=n_exc, bs=bs, k=16, bucket=bucket)
+    _close(c.flops, flops, f"join_bm[{r},{bs},{n_inc},{n_exc}] flops")
+    # the later memberships' bytes are the bucket's, not the window's;
+    # the sort that packs it reads and writes the window's keys
+    one = RF.cost("_rank_join_bm_batch_kernel", r=r, n_inc=1, bs=bs, k=16)
+    assert c.bytes - one.bytes == bs * (16 * r + (20 * (n - 1) + 28)
+                                        * bucket)
+
+
+@pytest.mark.parametrize("r,m,n_inc", ((65536, 65536, 3), (65536, 131072, 3),
+                                       (131072, 65536, 4)))
+def test_xla_rank_join_compacted(arena, r, m, n_inc):
+    from yacy_search_server_tpu.index import devstore as DS
+    qargs = np.zeros((1, 6 + 3 * n_inc), np.int32)
+    flops = _xla(DS._rank_join_batch_kernel, arena["f16"],
+                 arena["fl"], arena["dd"], arena["dead"],
+                 arena["jd"], arena["jp"], qargs, *_consts(),
+                 k=16, n_inc=n_inc, n_exc=0, r=r, inc_ms=(m,) * n_inc,
+                 exc_ms=())
+    c = RF.cost("_rank_join_batch_kernel", r=r, m=m, n_inc=n_inc, n_exc=0,
+                bs=1, k=16, bucket=DS._join_bucket(r, n_inc, 0))
+    _close(c.flops, flops, f"join[{r},{m},{n_inc}] compacted flops")
+
+
 @pytest.mark.parametrize("k", (16, 128))
 def test_xla_rank_spans(arena, k):
     from yacy_search_server_tpu.index import devstore as DS

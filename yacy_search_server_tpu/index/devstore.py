@@ -663,6 +663,20 @@ def _membership_bitmap(bmtab, slot, jpos, jstart, targets):
     return found, prow
 
 
+def _join_bucket(r: int, n_inc: int, n_exc: int) -> int:
+    """Lanes of the bucket a join of window `r` runs its later
+    memberships over (_join_topk's phase two); 0 where it runs every
+    membership over the whole window. A join compacts where it has three
+    include partners or more: the first runs over the window (a regular
+    term keeps ~1/8 of it, so one bucket of a quarter holds its
+    survivors), the later ones over the bucket. Packing the bucket costs
+    about what one more partner over the window does, so a single later
+    partner does not repay it, nor do excludes, which read one word a
+    lane and no partner row. A join that compacts returns its survivor
+    count beside its answer."""
+    return max(r // 4, 1) if n_inc >= 3 else 0
+
+
 def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
                qargs,
                norm_coeffs, flag_bits, flag_shifts,
@@ -672,17 +686,28 @@ def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
                inc_ms: tuple = (), exc_ms: tuple = (),
                bmtab=None, inc_bm: tuple = (), exc_bm: tuple = ()):
     """Device conjunction: slice the RAREST include term's whole span
-    (`r` = its statically bucketed row count), membership-test every
-    docid against the other include terms' docid-sorted side-tables via
-    ONE sort-merge membership per partner (and negated for excludes —
-    see _membership_sorted), gather partner rows, and merge features with the host join's
-    semantics (worddistance = position span across terms, hitcount =
-    min, flags = OR — segment.join_constructive). Then stats + score +
-    top-k over the merged rows.
+    (`r` = its statically bucketed row count), membership-test its
+    docids against the other include terms' side-tables (and negated
+    against the excludes'), gather partner rows, and merge features with
+    the host join's semantics (worddistance = position span across
+    terms, hitcount = min, flags = OR — segment.join_constructive). Then
+    stats + score + top-k over the merged rows.
 
-    Everything is single-pass big-tensor work, and every per-query
-    scalar rides in ONE packed int32 vector (`qargs`) — each separate
-    host scalar argument is its own host-to-device transfer. Layout:
+    Where _join_bucket gives a bucket, two phases, progressive as the
+    reference's join is (smallest side first, each step over the
+    previous result: ReferenceContainer.java:397-489). Phase one runs
+    the FIRST membership (the first include partner) over every lane of
+    the window; phase two (_join_later_memberships) runs every later
+    membership (the excludes too) and its partner gathers over the lanes
+    still live alone, a bucket at a time, and folds what they found into
+    the merged columns. Nothing is dropped, so the answer is the one-phase answer
+    bit for bit. Such a join returns (scores, docids, survivors after
+    the first membership); any other runs every membership over every
+    lane and returns (scores, docids).
+
+    Every per-query scalar rides in ONE packed int32 vector (`qargs`) —
+    each separate host scalar argument is its own host-to-device
+    transfer. Layout:
     [start, count, lang_filter, flag_bit, from_days, to_days,
      inc_jstart*n_inc, inc_jcount*n_inc, inc_jslot*n_inc,
      exc_jstart*n_exc, exc_jcount*n_exc, exc_jslot*n_exc]. This is the
@@ -696,6 +721,7 @@ def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
     lang_filter, flag_bit = qargs[2], qargs[3]
     from_days, to_days = qargs[4], qargs[5]
     base = 6
+    ebase = base + 3 * n_inc
     inc_bm = inc_bm or (False,) * n_inc
     exc_bm = exc_bm or (False,) * n_exc
     f = lax.dynamic_slice(feats16, (start, 0), (r, P.NF)).astype(jnp.int32)
@@ -713,15 +739,24 @@ def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
     # gather-bandwidth-bound at 1M-lane rare spans (r5 mix profile)
     pos_col = feats16[:, P.F_POSINTEXT]
     hit_col = feats16[:, P.F_HITCOUNT]
-    for t in range(n_inc):
-        lo = qargs[base + t]
-        cnt = qargs[base + n_inc + t]
-        if inc_bm[t]:
-            slot = qargs[base + 2 * n_inc + t]
-            found, prow = _membership_bitmap(bmtab, slot, jpos, lo, dd)
+
+    def member(j, targets, acc):
+        """Membership j (the includes, then the excludes) of `targets`,
+        folded into acc = (live, pos_min, pos_max, hit_min, flags_or)."""
+        v, pos_min, pos_max, hit_min, flags_or = acc
+        inc = j < n_inc
+        i, b, n, ms, bm = ((j, base, n_inc, inc_ms, inc_bm) if inc else
+                           (j - n_inc, ebase, n_exc, exc_ms, exc_bm))
+        lo = qargs[b + i]
+        cnt = qargs[b + n + i]
+        if bm[i]:
+            slot = qargs[b + 2 * n + i]
+            found, prow = _membership_bitmap(bmtab, slot, jpos, lo, targets)
         else:
-            found, prow = _membership_sorted(jdocids, jpos, lo, inc_ms[t],
-                                             dd, v, cnt)
+            found, prow = _membership_sorted(jdocids, jpos, lo, ms[i],
+                                             targets, v, cnt)
+        if not inc:
+            return v & ~found, pos_min, pos_max, hit_min, flags_or
         v &= found
         pp = pos_col[prow].astype(jnp.int32)
         pos_min = jnp.minimum(pos_min, pp)
@@ -729,17 +764,17 @@ def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
         hit_min = jnp.minimum(hit_min, hit_col[prow].astype(jnp.int32))
         # partner rows for misses gather row 0's flags — mask them out
         flags_or = flags_or | jnp.where(found, flags[prow], 0)
-    ebase = base + 3 * n_inc
-    for e in range(n_exc):
-        lo = qargs[ebase + e]
-        cnt = qargs[ebase + n_exc + e]
-        if exc_bm[e]:
-            slot = qargs[ebase + 2 * n_exc + e]
-            found, _prow = _membership_bitmap(bmtab, slot, jpos, lo, dd)
-        else:
-            found, _prow = _membership_sorted(jdocids, jpos, lo, exc_ms[e],
-                                              dd, v, cnt)
-        v &= ~found
+        return v, pos_min, pos_max, hit_min, flags_or
+
+    bucket = _join_bucket(r, n_inc, n_exc)
+    acc = (v, pos_min, pos_max, hit_min, flags_or)
+    for j in range(1 if bucket else n_inc + n_exc):
+        acc = member(j, dd, acc)
+    survivors = None
+    if bucket:
+        acc, survivors = _join_later_memberships(
+            acc, dd, member, range(1, n_inc + n_exc), bucket)
+    v, pos_min, pos_max, hit_min, flags_or = acc
 
     merged = f.at[:, P.F_WORDDISTANCE].set(pos_max - pos_min)
     merged = merged.at[:, P.F_HITCOUNT].set(hit_min)
@@ -754,7 +789,53 @@ def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
         tf_coeff, language_coeff, authority_coeff, language_pref,
         flags=flags_or)
     top_s, idx = lax.top_k(sc, min(k, r))
-    return top_s, dd[idx]
+    if survivors is None:
+        return top_s, dd[idx]
+    return top_s, dd[idx], survivors
+
+
+def _join_later_memberships(acc, dd, member, later, bucket: int):
+    """_join_topk's phase two: the memberships `later` over the lanes
+    that acc's live mask holds, `bucket` lanes at a time. One sort puts
+    the live lanes first, in lane (= docid) order, each beside its
+    docid. A bucket's memberships start from nothing found yet (the
+    identities of AND, min, max and OR); what they find is scattered to
+    the bucket's lanes and folded into acc there. AND, min, max and OR
+    do not care for order, so this is the one-phase merge.
+    Returns (acc, survivors)."""
+    r = dd.shape[0]
+    live0, pos_min, pos_max, hit_min, flags_or = acc
+    survivors = jnp.sum(live0, dtype=jnp.int32)
+    lanes = jnp.arange(r, dtype=jnp.int32)
+    # a dead lane sorts past the live ones, and its key is an index past
+    # the window, which a scatter drops
+    order, tdoc = lax.sort((jnp.where(live0, lanes, r + lanes), dd),
+                           num_keys=1)
+    pad = -r % bucket
+    if pad:
+        order = jnp.concatenate([order, jnp.full(pad, 2 * r, jnp.int32)])
+        tdoc = jnp.concatenate([tdoc, jnp.zeros(pad, tdoc.dtype)])
+    big, small = jnp.iinfo(jnp.int32).max, jnp.iinfo(jnp.int32).min
+
+    def nothing(n, live):
+        return (live, jnp.full(n, big, jnp.int32),
+                jnp.full(n, small, jnp.int32), jnp.full(n, big, jnp.int32),
+                jnp.zeros(n, flags_or.dtype))
+
+    def one_bucket(c, found):
+        lane = lax.dynamic_slice(order, (c * bucket,), (bucket,))
+        sub = nothing(bucket, lane < r)
+        targets = lax.dynamic_slice(tdoc, (c * bucket,), (bucket,))
+        for j in later:
+            sub = member(j, targets, sub)
+        return tuple(col.at[lane].set(x, mode="drop")
+                     for col, x in zip(found, sub))
+
+    found = lax.fori_loop(0, (survivors + bucket - 1) // bucket, one_bucket,
+                          nothing(r, jnp.zeros(r, bool)))
+    return ((found[0], jnp.minimum(pos_min, found[1]),
+             jnp.maximum(pos_max, found[2]), jnp.minimum(hit_min, found[3]),
+             flags_or | found[4]), survivors)
 
 
 @partial(jax.jit, static_argnames=("k", "n_inc", "n_exc", "r",
@@ -1069,13 +1150,32 @@ def _rank_join_batch_packed_kernel(feats16, flags, docids, dead, jdocids,
                                    language_pref,
                                    k: int, n_inc: int, n_exc: int, r: int,
                                    inc_ms: tuple = (), exc_ms: tuple = ()):
-    """_rank_join_batch_kernel with a packed [bs, 2*min(k,r)] output."""
-    s, d = _rank_join_batch_kernel(
+    """_rank_join_batch_kernel with a packed [bs, 2*min(k,r)] output,
+    and one column more, the survivors, where the join compacts."""
+    out = _rank_join_batch_kernel(
         feats16, flags, docids, dead, jdocids, jpos, qargs_batch,
         norm_coeffs, flag_bits, flag_shifts, domlength_coeff, tf_coeff,
         language_coeff, authority_coeff, language_pref,
         k=k, n_inc=n_inc, n_exc=n_exc, r=r, inc_ms=inc_ms, exc_ms=exc_ms)
-    return jnp.concatenate([s, d], axis=1)
+    return _pack_join_out(out)
+
+
+def _pack_join_out(out):
+    """(scores, docids[, survivors]) of a join batch as ONE int32 array:
+    [bs, 2*min(k,r)] (+1 column, the survivors)."""
+    return jnp.concatenate([out[0], out[1]] + [o[:, None] for o in out[2:]],
+                           axis=1)
+
+
+def _split_join_out(host, bucket: int):
+    """(scores, docids, survivors or None) of a fetched _pack_join_out
+    array of a join whose _join_bucket is `bucket`, each with the batch
+    on axis 0."""
+    if not bucket:
+        half = host.shape[1] // 2
+        return host[:, :half], host[:, half:], None
+    half = (host.shape[1] - 1) // 2
+    return host[:, :half], host[:, half:-1], host[:, -1]
 
 
 @partial(jax.jit, static_argnames=("k", "n_inc", "n_exc", "r",
@@ -1092,14 +1192,15 @@ def _rank_join_bm_batch_packed_kernel(feats16, flags, docids, dead,
                                       exc_ms: tuple = (),
                                       inc_bm: tuple = (),
                                       exc_bm: tuple = ()):
-    """_rank_join_bm_batch_kernel with a packed [bs, 2*min(k,r)] output."""
-    s, d = _rank_join_bm_batch_kernel(
+    """_rank_join_bm_batch_kernel with the output of
+    _rank_join_batch_packed_kernel."""
+    out = _rank_join_bm_batch_kernel(
         feats16, flags, docids, dead, jdocids, jpos, bmtab, qargs_batch,
         norm_coeffs, flag_bits, flag_shifts, domlength_coeff, tf_coeff,
         language_coeff, authority_coeff, language_pref,
         k=k, n_inc=n_inc, n_exc=n_exc, r=r, inc_ms=inc_ms, exc_ms=exc_ms,
         inc_bm=inc_bm, exc_bm=exc_bm)
-    return jnp.concatenate([s, d], axis=1)
+    return _pack_join_out(out)
 
 
 @partial(jax.jit,
@@ -3170,9 +3271,12 @@ class _QueryBatcher:
                                kk=kk, r=r, n_inc=n_inc, n_exc=n_exc,
                                any_bm=any_bm, inc_ms=inc_ms,
                                exc_ms=exc_ms):
-                        half = host.shape[1] // 2    # min(k, r) wide
-                        s = host[:, :half]
-                        d = host[:, half:]
+                        bucket = _join_bucket(r, n_inc, n_exc)
+                        s, d, survivors = _split_join_out(host, bucket)
+                        if bucket:
+                            store._count_survivors(
+                                bucket, [it["qargs"][1] for it in chunk],
+                                survivors[:len(chunk)])
                         wall = time.perf_counter() - t0k
                         for it in chunk:
                             it["kernel_ms"] = wall * 1000.0
@@ -3185,7 +3289,7 @@ class _QueryBatcher:
                                {"m": (sum(windows)
                                       // max(len(windows), 1))}),
                             n_inc=n_inc, n_exc=n_exc, bs=len(chunk),
-                            k=kk)
+                            k=kk, bucket=bucket)
                         for i, it in enumerate(chunk):
                             _deliver(it, ("ok", s[i], d[i]))
 
@@ -3324,6 +3428,14 @@ class DeviceSegmentStore:
         #   membership (a partner without a join bitmap)
         self.join_partners = 0    # include partners, summed over served joins
         self.join_multi_served = 0   # of join_served: >= 2 include partners
+        # joins of two memberships or more run the later ones over the
+        # first one's survivors (_join_topk): how many, the lanes live
+        # after the first membership and the rare rows they considered
+        # (both summed), and how many needed more than one bucket
+        self.join_compacted = 0
+        self.join_survivors = 0
+        self.join_compact_rows = 0
+        self.join_compact_overflow = 0
         self.join_fallbacks = 0
         self.join_degraded_plain = 0  # join-shaped, served by rank_term
         #   (every exclusion was a nonexistent term)
@@ -4598,6 +4710,10 @@ class DeviceSegmentStore:
             "join_sm_served": self.join_sm_served,
             "join_partners": self.join_partners,
             "join_multi_served": self.join_multi_served,
+            "join_compacted": self.join_compacted,
+            "join_survivors": self.join_survivors,
+            "join_compact_rows": self.join_compact_rows,
+            "join_compact_overflow": self.join_compact_overflow,
             "join_fallbacks": self.join_fallbacks,
             # gauges of the arena that serves now: bitmap slots in use,
             # and lists of >= JOIN_BITMAP_MIN rows that were refused one
@@ -4923,7 +5039,12 @@ class DeviceSegmentStore:
         rare_i = min(range(len(inc_spans)),
                      key=lambda i: inc_spans[i].count)
         rare = inc_spans[rare_i]
-        partners = [sp for i, sp in enumerate(inc_spans) if i != rare_i]
+        # the shortest partner first: its membership runs over the whole
+        # window, every later one only over its survivors (_join_topk).
+        # The merge is min / max / OR and AND, so the order leaves the
+        # answer as it is
+        partners = sorted((sp for i, sp in enumerate(inc_spans)
+                           if i != rare_i), key=lambda sp: sp.count)
         considered = rare.count
 
         # static span window: bucketed row count (bounded compile shapes),
@@ -5030,8 +5151,11 @@ class DeviceSegmentStore:
                            (time.perf_counter() - t1j) * 1e3,
                            kernel="join_multi" if len(partners) >= 2
                            else None)
-            half = host.shape[1] // 2
-            s, d = host[0, :half], host[0, half:]
+            bucket = _join_bucket(r, len(partners), len(exc_spans))
+            s, d, survivors = _split_join_out(host, bucket)
+            if bucket:
+                self._count_survivors(bucket, [considered], survivors)
+            s, d = s[0], d[0]
         keep = (d >= 0) & (s > NEG_INF32)
         with self._lock:   # exact under concurrency
             self.queries_served += 1
@@ -5042,6 +5166,17 @@ class DeviceSegmentStore:
                 self.join_sm_served += 1
             self.arena.join_shapes.add(statics)
         return s[keep][:k], d[keep][:k], considered
+
+    def _count_survivors(self, bucket: int, considered, survivors) -> None:
+        """Count compacting joins of one bucket size: their rare rows
+        (`considered`) and the lanes live after their first membership
+        (`survivors`), one of each a join."""
+        with self._lock:
+            self.join_compacted += len(considered)
+            self.join_compact_rows += int(sum(considered))
+            self.join_survivors += int(sum(survivors))
+            self.join_compact_overflow += sum(int(n) > bucket
+                                              for n in survivors)
 
     def _prewarm_join_shapes(self, arrays, join, dead, statics, profile,
                              language: str, qlen: int) -> None:

@@ -194,6 +194,19 @@ _PRUNEDB_FLOPS_ROW = 449.0
 _JOIN_FLOPS_R, _JOIN_FLOPS_M = 560.0, 34.0
 # bitmap-membership join: 607 flops/row·slot
 _JOINBM_FLOPS_ROW = 607.0
+# a join that compacts (index/devstore._join_bucket gives the caller its
+# bucket) runs its later memberships over a bucket of the first one's
+# survivors: the sort that packs them and the fold of what they found
+# into the window cost 47 flops a window lane, a later bitmap membership
+# 70 a bucket lane, a later sort-merge one 30 a bucket lane + 45 a
+# segment key (fit at r 65,536-131,072 and two to four later
+# memberships); compulsory bytes: the sort's key and docid in and out a
+# window lane, a bucket lane's key and docid read and its five merged
+# columns written
+_JOIN_COMPACT_FLOPS_ROW = 47.0
+_JOINBM_LATER_FLOPS_LANE = 70.0
+_JOIN_LATER_FLOPS_LANE, _JOIN_LATER_FLOPS_M = 30.0, 45.0
+_JOIN_COMPACT_BYTES_ROW, _JOIN_COMPACT_BYTES_LANE = 16, 28
 
 
 def _c_cardinal_scores16(n: int) -> Cost:
@@ -261,27 +274,49 @@ def _c_rank_pruned(b: int, tile: int = 32_768, bs: int = 1,
 
 
 def _c_rank_join(r: int, m: int = 0, n_inc: int = 1, n_exc: int = 0,
-                 bs: int = 1, k: int = 16) -> Cost:
+                 bs: int = 1, k: int = 16, bucket: int = 0) -> Cost:
     """Sort-merge device conjunction: rare span of `r` rows, one (r+m)
     sort-merge membership per partner segment of `m` rows (`n_inc` +
-    `n_exc` partner memberships, the kernel statics' counts)."""
+    `n_exc` partner memberships, the kernel statics' counts); with a
+    `bucket`, every membership after the first over (bucket+m) keys
+    (one bucket: the survivors fit it)."""
     partners = max(n_inc + n_exc, 1)
-    flops = bs * r * (_JOIN_FLOPS_R + 146.0 * (partners - 1)) \
-        + bs * _JOIN_FLOPS_M * m * partners
-    # compulsory: rare rows once; per partner 12 B of gathered columns
-    # per lane + the (docid, pos) segment streamed for the sort
-    comp = bs * (ROW_BYTES_DEAD * r + partners * (12 * r + 8 * m) + 8 * k)
-    return Cost(flops=flops, bytes=comp)
+    if not bucket:
+        flops = bs * r * (_JOIN_FLOPS_R + 146.0 * (partners - 1)) \
+            + bs * _JOIN_FLOPS_M * m * partners
+        # compulsory: rare rows once; per partner 12 B of gathered
+        # columns per lane + the (docid, pos) segment streamed for the
+        # sort
+        comp = bs * (ROW_BYTES_DEAD * r + partners * (12 * r + 8 * m)
+                     + 8 * k)
+        return Cost(flops=flops, bytes=comp)
+    later = partners - 1
+    flops = ((_JOIN_FLOPS_R + _JOIN_COMPACT_FLOPS_ROW) * r
+             + _JOIN_FLOPS_M * m
+             + later * (_JOIN_LATER_FLOPS_LANE * bucket
+                        + _JOIN_LATER_FLOPS_M * m))
+    comp = ((ROW_BYTES_DEAD + 12 + _JOIN_COMPACT_BYTES_ROW) * r
+            + (12 * later + _JOIN_COMPACT_BYTES_LANE) * bucket
+            + partners * 8 * m + 8 * k)
+    return Cost(flops=bs * flops, bytes=bs * comp)
 
 
 def _c_rank_join_bm(r: int, n_inc: int = 1, n_exc: int = 0, bs: int = 1,
-                    k: int = 16) -> Cost:
+                    k: int = 16, bucket: int = 0) -> Cost:
     """Bitmap-membership conjunction: 2 gathers per lane per partner
-    instead of the (r+m) sort — O(r) regardless of partner size."""
+    instead of the (r+m) sort — O(r) regardless of partner size; with a
+    `bucket`, every membership after the first over the bucket."""
     partners = max(n_inc + n_exc, 1)
-    flops = bs * r * (_JOINBM_FLOPS_ROW + 160.0 * (partners - 1))
-    comp = bs * (ROW_BYTES_DEAD * r + partners * 20 * r + 8 * k)
-    return Cost(flops=flops, bytes=comp)
+    if not bucket:
+        flops = bs * r * (_JOINBM_FLOPS_ROW + 160.0 * (partners - 1))
+        comp = bs * (ROW_BYTES_DEAD * r + partners * 20 * r + 8 * k)
+        return Cost(flops=flops, bytes=comp)
+    later = partners - 1
+    flops = ((_JOINBM_FLOPS_ROW + _JOIN_COMPACT_FLOPS_ROW) * r
+             + later * _JOINBM_LATER_FLOPS_LANE * bucket)
+    comp = ((ROW_BYTES_DEAD + 20 + _JOIN_COMPACT_BYTES_ROW) * r
+            + (20 * later + _JOIN_COMPACT_BYTES_LANE) * bucket + 8 * k)
+    return Cost(flops=bs * flops, bytes=bs * comp)
 
 
 def _c_bm25_topk(n: int, t: int = 3, k: int = 16) -> Cost:

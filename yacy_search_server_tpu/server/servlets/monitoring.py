@@ -482,7 +482,9 @@ def prometheus_text(sb, include_buckets: bool = True,
              "device store serving counters")
     for key in ("queries_served", "fallbacks", "stream_scans",
                 "filtered_served", "join_served", "join_sm_served",
-                "join_partners", "join_multi_served", "join_fallbacks",
+                "join_partners", "join_multi_served", "join_compacted",
+                "join_survivors", "join_compact_rows",
+                "join_compact_overflow", "join_fallbacks",
                 "batch_dispatches", "batch_exceptions",
                 "batch_ineligible", "prune_rounds",
                 # kernel shapes the start-up prewarm could not compile

@@ -629,6 +629,10 @@ def device_store(header, post, sb):
             ("join_sm_served", c["join_sm_served"]),
             ("join_partners", c["join_partners"]),
             ("join_multi_served", c["join_multi_served"]),
+            ("join_compacted", c["join_compacted"]),
+            ("join_survivors", c["join_survivors"]),
+            ("join_compact_rows", c["join_compact_rows"]),
+            ("join_compact_overflow", c["join_compact_overflow"]),
             ("join_shapes", c["join_shapes"]),
             ("join_bitmap_slots", c["join_bitmap_slots"]),
             ("join_bitmap_refused", c["join_bitmap_refused"]),
