@@ -454,6 +454,51 @@ def test_progressive_join_parity(seg_many, inc, exc):
         "join_multi_served": int(len(inc) >= 3)}
 
 
+def test_an_abandoned_wave_counts_its_join_once(monkeypatch):
+    """A query whose waiter gives up on its wave is served, and counted,
+    solo: the wave's late finish neither delivers it nor counts its
+    survivors a second time."""
+    import threading
+    import time
+    from yacy_search_server_tpu.index.devstore import DeviceSegmentStore
+    monkeypatch.setattr(DeviceSegmentStore, "JOIN_BITMAP_MIN",
+                        MODES["bitmap"])
+    seg = Segment(max_ram_postings=10)
+    rng = np.random.default_rng(40)
+    pool = np.arange(40_000)
+    seg.rwi.ingest_run({word2hash(t): _plist(rng, n, pool)
+                        for t, (n, where) in MANY.items() if where is None})
+    seg.enable_device_serving()
+    ds = seg.devstore
+    ds.enable_batching(max_batch=4, dispatchers=1, prewarm=False)
+    inc = [word2hash(t) for t in ("rr", "p90", "p80", "p50")]
+    try:
+        # compile the wave's and the solo shapes first
+        assert ds.rank_join(inc, [], RankingProfile(), k=10) is not None
+        b = ds._batcher
+        landed = threading.Event()
+        issue = b._submit_completion
+
+        def late(out, finish, *rest):
+            def slow(host):
+                time.sleep(0.5)
+                finish(host)
+                landed.set()
+            issue(out, slow, *rest)
+
+        monkeypatch.setattr(b, "_submit_completion", late)
+        monkeypatch.setattr(b, "WATCHDOG_S", 0.05)
+        c0 = ds.counters()
+        assert ds.rank_join(inc, [], RankingProfile(), k=10) is not None
+        assert landed.wait(10.0)
+        c = ds.counters()
+        assert b.timeouts >= 1
+        assert {k: c[k] - c0[k] for k in ("join_compacted", "join_served")
+                } == {"join_compacted": 1, "join_served": 1}
+    finally:
+        seg.close()
+
+
 def test_a_two_word_join_does_not_compact(seg_many):
     seg, _docids, _mode = seg_many
     ds = seg.devstore

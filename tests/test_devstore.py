@@ -407,3 +407,284 @@ def test_filtered_stats_cache_hit_is_bit_identical():
     idx.delete_doc(victim)
     after = ds.rank_term(TH, RankingProfile(), k=50, lang_filter=de)
     assert victim not in after[1].tolist()
+
+
+# -- the arena: admission and growth by one sizing rule ----------------------
+
+# block sequences whose rows end within a TILE of a power of two, with
+# the writes each takes: where a block's size bucket would cross the
+# capacity admission checked, it goes in pieces
+EDGE_SEQUENCES = {
+    "fits_to_the_edge": ([(1 << 19) - TILE], 1),
+    "two_halves": ([1 << 18, (1 << 18) - TILE], 2),
+    "crosses_in_two_writes": ([819_199, 196_609], 3),
+    "crosses_in_three_writes": ([524_289, 491_519], 4),
+    "the_full_configuration_at_1_64": ([32_768] * 14 + [4_096] * 50
+                                       + [319_488], 66),
+}
+
+
+def _rows(n, base, salt):
+    """A block of n rows whose every cell says where it belongs."""
+    docids = np.arange(base, base + n, dtype=np.int32)
+    feats = np.zeros((n, P.NF), np.int32)
+    feats[:, 0] = (docids + salt) % 30_000
+    feats[:, P.F_FLAGS] = docids ^ salt
+    return docids, feats
+
+
+def _chunk(n, base, salt):
+    """_rows as the arena takes them: (docids, f16, flags)."""
+    from yacy_search_server_tpu.ops.ranking import compact_feats
+    docids, feats = _rows(n, base, salt)
+    return (docids, *compact_feats(feats))
+
+
+def _append_admitted(arena, blocks):
+    """Append each block as the store does (would_fit first); returns
+    the capacity admission budgeted for each."""
+    admitted, base = [], 0
+    for i, n in enumerate(blocks):
+        assert arena.would_fit(n)
+        admitted.append(arena.cap_for(n))
+        assert arena.append_block([_chunk(n, base, 7 * i)]) == base
+        assert arena.capacity_rows == admitted[-1]
+        base += n
+    return admitted
+
+
+@pytest.mark.parametrize("name", sorted(EDGE_SEQUENCES))
+def test_admission_and_growth_agree_at_the_capacity_edge(name):
+    """An append that would_fit admitted never grows the arena past the
+    capacity admission checked, its padded writes included."""
+    from yacy_search_server_tpu.index.devstore import DeviceArena
+    blocks, _writes = EDGE_SEQUENCES[name]
+    arena = DeviceArena()
+    admitted = _append_admitted(arena, blocks)
+    used = sum(blocks)
+    assert arena.used_rows == used
+    # the final capacity is the least doubling that holds the rows and
+    # the TILE past the last span
+    want = 4 * TILE
+    while want < used + TILE:
+        want *= 2
+    assert arena.capacity_rows == admitted[-1] == want
+    assert arena.fits(arena.capacity_rows, arena._pw_cap)
+
+
+@pytest.mark.parametrize("name", sorted(EDGE_SEQUENCES))
+def test_edge_appends_keep_every_earlier_row(name):
+    """A write near the capacity goes in pieces that stay inside it:
+    dynamic_update_slice would clamp a start that does not fit and
+    overwrite earlier rows in silence. Every row reads back as written,
+    and every row past the used mark is inert (docid -1)."""
+    from yacy_search_server_tpu.index.devstore import DeviceArena
+    from yacy_search_server_tpu.utils import histogram
+    blocks, writes = EDGE_SEQUENCES[name]
+    arena = DeviceArena()
+    h = histogram.histogram("arena.append")
+    before = h.snapshot()["count"]
+    _append_admitted(arena, blocks)
+    assert h.snapshot()["count"] - before == writes
+    feats16, flags, docids = (np.asarray(a) for a in arena.arrays())
+    base = 0
+    for i, n in enumerate(blocks):
+        dd, ff = _rows(n, base, 7 * i)
+        np.testing.assert_array_equal(docids[base:base + n], dd)
+        np.testing.assert_array_equal(flags[base:base + n],
+                                      ff[:, P.F_FLAGS])
+        np.testing.assert_array_equal(feats16[base:base + n, 0], ff[:, 0])
+        base += n
+    assert (docids[base:] == -1).all()
+
+
+def test_a_write_past_the_capacity_raises():
+    """The clamp trap, pinned where it would spring: a write whose rows
+    do not fit is refused, not clamped onto the rows before it."""
+    from yacy_search_server_tpu.index.devstore import DeviceArena
+    arena = DeviceArena()
+    cap = arena.capacity_rows
+    rows = np.zeros(512, np.int32)
+    with pytest.raises(ValueError, match="crosses the capacity"):
+        arena._write_rows(cap - 256, np.zeros((512, P.NF), np.int16),
+                          rows, rows)
+    assert (np.asarray(arena.arrays()[2]) == -1).all()
+
+
+def test_growth_is_a_span_and_a_counted_wall():
+    """A growth is one `arena.grow` span and one `arena_grow` event whose
+    items are the rows it added; the arena's first allocation is an
+    `arena_grow` event too, so their items sum to its capacity. The
+    pads compile before the span: the span times the copies alone."""
+    from yacy_search_server_tpu.index.devstore import DeviceArena
+    from yacy_search_server_tpu.utils import eventtracker, histogram
+    ev = (eventtracker.EClass.INDEX, "arena_grow")
+    h = histogram.histogram("arena.grow")
+    before, ev0 = h.snapshot()["count"], eventtracker.totals().get(
+        ev, (0, 0, 0.0))
+    arena = DeviceArena()
+    arena.append_block([_chunk(5 * TILE, 0, 1)])
+    assert arena.capacity_rows == 8 * TILE
+    assert h.snapshot()["count"] == before + 1
+    assert h.snapshot()["sum_ms"] > 0.0
+    assert histogram.get("arena.append").snapshot()["count"] >= 1
+    ev1 = eventtracker.totals()[ev]
+    assert (ev1[0] - ev0[0], ev1[1] - ev0[1]) == (2, 8 * TILE)
+    assert ev1[2] > ev0[2]
+
+
+def test_a_refused_run_counts_its_rows_and_keeps_the_event():
+    """A run the budget refuses stays host-served: the store counts its
+    rows in arena_rows_skipped, and the event devstore_skip, which
+    outlives the store in the process's totals, stays."""
+    from yacy_search_server_tpu.utils import eventtracker
+    ev = (eventtracker.EClass.INDEX, "devstore_skip")
+    before = eventtracker.totals().get(ev, (0, 0, 0.0))
+    rng = np.random.default_rng(11)
+    idx = RWIIndex()
+    ds = _store(idx, budget_bytes=8 * TILE * 42)
+    idx.ingest_run({TH: _plist(rng, 2 * TILE)})
+    idx.ingest_run({b"devtermBBBBB": _plist(rng, 5 * TILE)})
+    c = ds.counters()
+    assert c["arena_rows_packed"] == 2 * TILE
+    assert c["arena_rows_skipped"] == 5 * TILE
+    assert c["arena_used_rows"] == 2 * TILE
+    assert c["arena_capacity_rows"] == 4 * TILE
+    after = eventtracker.totals()[ev]
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 5 * TILE)
+    assert ds.rank_term(b"devtermBBBBB", RankingProfile(), k=10) is None
+    ds.close()
+    assert eventtracker.totals()[ev] == after
+
+
+def _tied_plist(rng, n):
+    """A list whose features take three values each: many equal
+    rankings, in a pack (pruning) order that is not document order under
+    any profile but the pack's own."""
+    p = _plist(rng, n)
+    p.feats[:] = rng.integers(0, 3, p.feats.shape)
+    p.feats[:, P.F_LANGUAGE] = P.pack_language("en")
+    return p
+
+
+def test_equal_rankings_come_back_by_ascending_docid():
+    """The pinned tie order on every device answer, its cached sights
+    included: lax.top_k breaks ties by candidate index, and an arena in
+    pruning order (or a join's rare list) is not in document order. The
+    second sight (rank_cache_get, as SearchEvent asks it first) is the
+    first sight, which is the host path's answer."""
+    rng = np.random.default_rng(0)
+    idx = RWIIndex()
+    idx.ingest_run({TH: _tied_plist(rng, 3000),
+                    b"devtermBBBBB": _tied_plist(rng, 3000)})
+    ds = _store(idx)
+    prof = RankingProfile(domlength=0, date=15, hitcount=15)
+    first = ds.rank_term(TH, prof, k=100)
+    ws, wd = _oracle(idx, TH, 100, prof)
+    assert len(np.unique(ws)) < len(ws)          # the answer holds ties
+    np.testing.assert_array_equal(first[0], ws)
+    np.testing.assert_array_equal(first[1], wd)
+    hits = ds.counters()["rank_cache_hits"]
+    again = ds.rank_cache_get(TH, prof, k=100)
+    assert ds.counters()["rank_cache_hits"] == hits + 1
+    np.testing.assert_array_equal(again[0], first[0])
+    np.testing.assert_array_equal(again[1], first[1])
+    s, d, _ = ds.rank_join([TH, b"devtermBBBBB"], [], prof, k=100)
+    assert len(np.unique(s)) < len(s)
+    ties = s[1:] == s[:-1]
+    assert (s[1:] <= s[:-1]).all() and (d[1:][ties] > d[:-1][ties]).all()
+    ds.close()
+
+
+# -- the pack path's host work: each shortcut equals what it replaces --------
+
+@pytest.mark.parametrize("n", (0, 1, 2, 7, 4096, 70_001))
+@pytest.mark.parametrize("hi", (1, 3, 1 << 20, 1 << 32, 1 << 40))
+def test_proxy_order_is_the_stable_descending_argsort(n, hi):
+    from yacy_search_server_tpu.index.devstore import proxy_order
+    proxy = np.random.default_rng(n + hi).integers(0, hi, n).astype(np.int64)
+    np.testing.assert_array_equal(proxy_order(proxy),
+                                  np.argsort(-proxy, kind="stable"))
+
+
+@pytest.mark.parametrize("ascending", (True, False))
+def test_docid_view_is_the_sorted_packed_view(ascending):
+    from yacy_search_server_tpu.index.devstore import docid_view
+    rng = np.random.default_rng(5)
+    docids = np.cumsum(rng.integers(1, 9, 5000)).astype(np.int32)
+    if not ascending:
+        docids = rng.permutation(docids)
+    order = rng.permutation(len(docids))
+    packed = docids[order]
+    jorder = np.argsort(packed, kind="stable")
+    got_dd, got_j = docid_view(docids, order)
+    np.testing.assert_array_equal(got_dd, packed[jorder])
+    np.testing.assert_array_equal(got_j, jorder)
+    assert got_dd.dtype == np.int32
+
+
+@pytest.mark.filterwarnings("ignore::RuntimeWarning")   # the twin's 0 / 0
+@pytest.mark.parametrize("shape", ("rows", "clipped", "tf_flat", "one"))
+def test_pack_proxy_by_the_native_scorer_equals_the_numpy_twin(shape):
+    """The prune layout's proxy worked out from the int32 rows by the
+    native scorer is the NumPy twin's, score for score (the layout, and
+    with it every pruned answer, is the same either way)."""
+    from yacy_search_server_tpu.index.devstore import pack_prune_stats
+    from yacy_search_server_tpu.ops.ranking import compact_feats
+    rng = np.random.default_rng(len(shape))
+    feats = _plist(rng, 1 if shape == "one" else 3000).feats.copy()
+    if shape == "clipped":
+        feats[::5] = rng.integers(-100_000, 100_000, feats[::5].shape)
+    elif shape == "tf_flat":
+        feats[:, P.F_HITCOUNT] = 0
+    f16, fl = compact_feats(feats)
+    stats, twin = pack_prune_stats(f16, fl)
+    stats_n, native_proxy = pack_prune_stats(f16, fl, feats)
+    np.testing.assert_array_equal(native_proxy, twin)
+    assert native_proxy.dtype == np.int64
+    for k in stats:
+        np.testing.assert_array_equal(stats_n[k], stats[k])
+
+
+@pytest.mark.parametrize("name", sorted(EDGE_SEQUENCES))
+def test_a_block_in_many_chunks_fills_the_rows_of_one(name):
+    """A block streamed as several chunks fills the same rows as the one
+    chunk, across chunk and write boundaries alike."""
+    from yacy_search_server_tpu.index.devstore import DeviceArena
+    blocks, _writes = EDGE_SEQUENCES[name]
+    arenas = DeviceArena(), DeviceArena()
+    base = 0
+    for i, n in enumerate(blocks):
+        whole = _chunk(n, base, 3 * i)
+        cuts = [0, min(n, 1), n // 3, n // 3 + 1, n]
+        parts = [tuple(a[lo:hi] for a in whole)
+                 for lo, hi in zip(cuts, cuts[1:])]
+        assert arenas[0].append_block([whole]) == \
+            arenas[1].append_block(parts) == base
+        base += n
+    for a, b in zip(arenas[0].arrays(), arenas[1].arrays()):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert arenas[0].capacity_rows == arenas[1].capacity_rows
+
+
+def test_a_prewarm_pass_waits_for_the_arena_to_be_still(monkeypatch):
+    """Packs that keep coming (a bulk load) hold the prewarm back until
+    PREWARM_QUIET_S after the last one; then one pass runs."""
+    import time
+    rng = np.random.default_rng(9)
+    idx = RWIIndex()
+    idx.add_many(TH, _plist(rng, 300))
+    idx.flush()
+    ds = _store(idx)
+    passes = []
+    monkeypatch.setattr(ds, "prewarm_kernels",
+                        lambda: passes.append(time.monotonic()))
+    monkeypatch.setattr(DeviceSegmentStore, "PREWARM_QUIET_S", 0.3)
+    ds._prewarm_on = True
+    ds._maybe_prewarm()
+    for _ in range(4):
+        time.sleep(0.1)
+        ds._maybe_prewarm()
+    last = time.monotonic()
+    assert ds.prewarm_wait(5.0)
+    assert len(passes) == 1 and passes[0] >= last + 0.25

@@ -50,6 +50,25 @@ def test_sort_dedupe_parity_last_wins():
                    for i, k in enumerate(exp_ids))
 
 
+@pytest.mark.parametrize("n", (2, 3, 1000))
+def test_sort_dedupe_takes_an_ascending_list_as_it_is(n):
+    """A list already ascending without repeats (a bulk import's) skips
+    the sort: the same rows, in new arrays the caller's do not alias."""
+    rng = np.random.default_rng(n)
+    d = np.cumsum(rng.integers(1, 5, n)).astype(np.int64)
+    f = rng.integers(0, 100, (n, P.NF)).astype(np.int32)
+    pl = P.sort_dedupe(d, f)
+    assert pl.docids.dtype == np.int32 and pl.feats.dtype == np.int32
+    np.testing.assert_array_equal(pl.docids, d)
+    np.testing.assert_array_equal(pl.feats, f)
+    f[0, 0] += 1
+    assert pl.feats[0, 0] != f[0, 0]
+    # a repeat takes the sort and keeps the last row
+    d[1] = d[0]
+    pl = P.sort_dedupe(d, f)
+    assert len(pl) == n - 1 and pl.feats[0, 0] == f[1, 0]
+
+
 def test_intersect_parity():
     rng = np.random.default_rng(11)
     for na, nb in ((100, 100), (1000, 500), (64, 4096)):

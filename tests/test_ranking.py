@@ -319,3 +319,65 @@ def test_host_twin_f32_tf_matches_device_across_seeds():
         np.testing.assert_array_equal(
             np.asarray(s_host, dtype=np.int64),
             np.asarray(s_dev, dtype=np.int64), err_msg=f"seed {seed}")
+
+
+# -- the device's divisions, held to IEEE float32 -----------------------------
+
+# a quotient an ulp off the correctly rounded one, as a device's division
+# may give it (the v5e gave the largest normalised tf 255.99998 for 256)
+QUOTIENTS = {
+    "exact": lambda q: q,
+    "an_ulp_high": lambda q: np.nextafter(q, np.float32(np.inf)),
+    "an_ulp_low": lambda q: np.nextafter(q, np.float32(0)),
+}
+
+
+@pytest.mark.parametrize("off", sorted(QUOTIENTS))
+def test_term_frequency_division_is_ieee(off):
+    """hitcount / words as IEEE float32 rounds it, whatever ulp the
+    device's own division lands on: every hit count up to 64 against
+    every word count up to 4,096, and a random draw over the int16
+    columns' whole range."""
+    import jax
+    h = np.arange(0, 65, dtype=np.float32)
+    w = np.arange(1, 4097, dtype=np.float32)
+    H, W = (a.ravel() for a in np.meshgrid(h, w, indexing="ij"))
+    rng = np.random.default_rng(42)
+    H = np.concatenate([H, rng.integers(0, 32768, 200_000).astype(
+        np.float32)])
+    W = np.concatenate([W, rng.integers(1, 65536, 200_000).astype(
+        np.float32)])
+    want = H / W
+    got = np.asarray(jax.jit(R._ieee_div_nearest)(
+        H, W, QUOTIENTS[off](want).astype(np.float32)))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("off", sorted(QUOTIENTS))
+def test_tf_normalisation_truncates_as_ieee(off):
+    """trunc((tf - tf_min) * 256 / span) as IEEE division gives it, from
+    a quotient an ulp off: the largest tf reads 256, and every quotient
+    within a few ulps of a whole number floors as IEEE's does."""
+    import jax
+    rng = np.random.default_rng(7)
+    n = 1_000_000
+    d = (rng.uniform(1e-4, 1.0, n)
+         * 10.0 ** rng.integers(-3, 1, n)).astype(np.float32)
+    k = rng.integers(0, 257, n).astype(np.float32)
+    num = (k * d).astype(np.float32)
+    step = rng.integers(-3, 4, n).astype(np.int32)
+    num = np.maximum(num.view(np.int32) + step, 0).astype(
+        np.int32).view(np.float32)
+    num = np.minimum(num, (np.float32(256) * d).astype(np.float32))
+    want = (num / d).astype(np.int32)
+    got = np.asarray(jax.jit(R._ieee_trunc_div)(
+        num, d, QUOTIENTS[off](num / d).astype(np.float32)))
+    np.testing.assert_array_equal(got, want)
+    # the largest term frequency of a candidate set: exactly 256
+    tf = (np.arange(1, 33, dtype=np.float32)
+          / np.float32(301)).astype(np.float32)
+    span = tf.max() - tf.min()
+    top = (tf.max() - tf.min()) * np.float32(256.0)
+    assert int(R._ieee_trunc_div(top, span, QUOTIENTS[off](
+        np.float32(top / span)))) == 256
+    assert np.asarray(R._tf_norm(tf, tf.min(), tf.max()))[-1] == 256

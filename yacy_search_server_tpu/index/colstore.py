@@ -164,7 +164,9 @@ def write_segment(path: str, n: int,
                   arrays: dict[str, np.ndarray],
                   texts: dict[str, list[str]],
                   meta: dict | None = None) -> None:
-    """Write a frozen segment atomically (tmp + rename)."""
+    """Write a frozen segment atomically (tmp + rename). A text column
+    is a list of str, or (offsets, blob) as SegmentReader.text_raw gives
+    it."""
     header: dict = {"n": int(n), "arrays": {}, "texts": {},
                     "meta": meta or {}}
     blobs: list[bytes] = []
@@ -187,17 +189,21 @@ def write_segment(path: str, n: int,
             "dtype": arr.dtype.str, "shape": list(arr.shape),
             "off": add_blob(raw), "crc": integrity.crc32(raw)}
     for name, col in texts.items():
-        if len(col) != n:
-            raise ValueError(f"text column {name}: {len(col)} rows != {n}")
-        offsets = np.zeros(n + 1, np.uint64)
-        parts = []
-        pos = 0
-        for i, s in enumerate(col):
-            b = (s or "").encode("utf-8")
-            parts.append(b)
-            pos += len(b)
-            offsets[i + 1] = pos
-        blob = b"".join(parts)
+        if isinstance(col, tuple):          # (offsets, blob) as encoded
+            offsets, blob = col
+            offsets = np.ascontiguousarray(offsets, np.uint64)
+            if len(offsets) != n + 1 or int(offsets[-1]) != len(blob):
+                raise ValueError(f"text column {name}: offsets do not "
+                                 f"cover {n} rows of the blob")
+        else:
+            if len(col) != n:
+                raise ValueError(
+                    f"text column {name}: {len(col)} rows != {n}")
+            parts = [(s or "").encode("utf-8") for s in col]
+            offsets = np.zeros(n + 1, np.uint64)
+            np.cumsum(np.fromiter(map(len, parts), np.uint64, n),
+                      out=offsets[1:])
+            blob = b"".join(parts)
         oraw = offsets.tobytes()
         header["texts"][name] = {
             "ioff": add_blob(oraw),
@@ -378,6 +384,15 @@ class SegmentReader:
         if lo == hi:
             return ""
         return str(blob[lo:hi], "utf-8", "replace")
+
+    def text_raw(self, name: str) -> tuple[np.ndarray, bytes]:
+        """A whole text column as encoded: (uint64 offsets [n + 1], the
+        blob), what write_segment takes back (compaction path)."""
+        if not self.has_text(name):
+            return np.zeros(self.n + 1, np.uint64), b""
+        offsets, blob = self._text_maps(name)
+        offs = np.array(offsets, np.uint64)
+        return offs, bytes(blob[:int(offs[-1])])
 
     def text_column(self, name: str) -> list[str]:
         """Materialize a whole text column (compaction path)."""

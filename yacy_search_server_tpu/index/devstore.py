@@ -70,7 +70,8 @@ from ..ingest import slo as ingest_slo
 from ..ops import packed as PK
 from ..ops.ranking import (_ACTIVE_COLS, RankingProfile,
                            cardinal_from_stats, cardinal_from_stats_host,
-                           compact_feats, local_stats, pack_stats_host)
+                           cardinal_scores_block, compact_feats,
+                           local_stats, pack_stats_host)
 from ..ops.streaming import merge_stats
 from ..utils.eventtracker import EClass, update as track
 from ..utils.profiler import PROFILER
@@ -208,14 +209,46 @@ _PROXY_PROFILE = RankingProfile()          # the pack-time ordering profile
 _PROXY_SHIFTS = _signal_shift_vector(_PROXY_PROFILE)
 
 
-def pack_prune_stats(f16, fl):
+def pack_prune_stats(f16, fl, feats=None):
     """(frozen pack stats, proxy scores) — the prune layout's scoring
     oracle, shared by the single-device and mesh pack paths so the
-    bound-safety subtleties live in ONE place."""
+    bound-safety subtleties live in ONE place. `feats`, the int32 rows
+    `f16`/`fl` were compacted from, lets the native scorer work the
+    proxy out in one pass (the NumPy twin's scores bit for bit)."""
     stats = _pack_stats_np(f16, fl)
-    proxy = _cardinal_np(f16, fl, stats, _PROXY_PROFILE,
-                         P.pack_language("en"))
+    lang = P.pack_language("en")
+    proxy = (None if feats is None
+             else cardinal_scores_block(feats, _PROXY_PROFILE, lang))
+    if proxy is None:
+        proxy = _cardinal_np(f16, fl, stats, _PROXY_PROFILE, lang)
     return stats, proxy
+
+
+def proxy_order(proxy: np.ndarray) -> np.ndarray:
+    """Rows by proxy descending, equal proxies in row order: what
+    np.argsort(-proxy, kind="stable") gives, by one sort of distinct
+    keys (proxy, row) where the proxies fit 32 bits (a stable sort of
+    int64 is several times slower)."""
+    n = len(proxy)
+    if n == 0 or n >= 1 << 31 or proxy.min() < 0 or proxy.max() >> 32:
+        return np.argsort(-proxy, kind="stable")
+    key = (((1 << 32) - 1 - proxy.astype(np.int64)) << 31) \
+        | np.arange(n, dtype=np.int64)
+    key.sort()
+    return key & ((1 << 31) - 1)
+
+
+def docid_view(docids: np.ndarray, order: np.ndarray):
+    """(docid-sorted docids, their positions in the packed order) of a
+    span packed as `docids[order]`: where the docids ascend strictly, as
+    a frozen run's do, the inverse of `order` without a sort."""
+    if len(docids) > 1 and not bool(np.all(docids[1:] > docids[:-1])):
+        packed = docids[order]
+        jorder = np.argsort(packed, kind="stable")
+        return packed[jorder].astype(np.int32), jorder
+    jorder = np.empty(len(order), np.int64)
+    jorder[order] = np.arange(len(order))
+    return docids.astype(np.int32), jorder
 
 
 def prune_bound_consts(profile):
@@ -1429,6 +1462,15 @@ def _bucket_rows_join(n: int) -> int:
 # may hold the previous buffer mid-dispatch, and donation would invalidate
 # it under that thread — the copy-on-write costs one device-side arena copy
 # per flush (rare), readers keep a consistent old or new buffer either way.
+# lint: costmodel-ok(arena maintenance copy — the growth of a row
+# buffer, not a query-path kernel; its cost is the copy XLA itself
+# reports)
+@partial(jax.jit, static_argnums=(1, 2))
+def _pad_rows(buf, rows: int, fill: int):
+    return jnp.pad(buf, ((0, rows),) + ((0, 0),) * (buf.ndim - 1),
+                   constant_values=fill)
+
+
 # lint: costmodel-ok(arena maintenance write — a device-side
 # copy, not a query-path kernel; its cost is the copy XLA
 # itself reports)
@@ -1488,6 +1530,10 @@ class DeviceArena:
         self._feats16 = self._dev(np.zeros((self._cap, P.NF), np.int16))
         self._flags = self._dev(np.zeros(self._cap, np.int32))
         self._docids = self._dev(np.full(self._cap, -1, np.int32))
+        # rows allocated to arenas since the process started: the first
+        # allocation here, each growth in _grow_to (their sum is the
+        # capacity of a process's one arena)
+        track(EClass.INDEX, "arena_grow", self._cap)
         self._doc_cap = 1 << 16
         self._dead = self._dev(np.zeros(self._doc_cap, bool))
         self._pending_dead: list[int] = []
@@ -1571,12 +1617,21 @@ class DeviceArena:
         return (int(self._cap * self.device_row_bytes) + self._doc_cap
                 + self._pw_cap * 4)
 
-    def would_fit(self, rows: int) -> bool:
+    def cap_for(self, rows: int) -> int:
+        """The capacity an append of `rows` grows the arena to: the rows
+        in use, the append, and one TILE past its end (a span's last tile
+        is read whole), doubled from the capacity there is. The one
+        sizing rule: admission (would_fit) budgets this capacity and
+        growth (append_block) grows to it and no further, its padded
+        writes included."""
         need = self._used + rows + TILE
         new_cap = self._cap
-        while new_cap < need:          # growth doubles: budget the real cap
+        while new_cap < need:
             new_cap *= 2
-        return self.fits(new_cap, self._pw_cap)
+        return new_cap
+
+    def would_fit(self, rows: int) -> bool:
+        return self.fits(self.cap_for(rows), self._pw_cap)
 
     def packed_would_fit(self, words: int) -> bool:
         """Budget check for a packed-block append (the hot-tier admission
@@ -1618,51 +1673,96 @@ class DeviceArena:
         like bytes_used — the budget is charged for the allocation)."""
         return self._pw_cap * 4
 
-    def _grow_to(self, rows: int) -> None:
-        new_cap = self._cap
-        while new_cap < rows:
-            new_cap *= 2
-        if new_cap == self._cap:
+    def _grow_to(self, new_cap: int) -> None:
+        """Pad the row buffers to `new_cap` rows (a cap_for result). The
+        pads compile first; the copies, to the device's last byte, are
+        the `arena.grow` span and the `arena_grow` event's wall. The old
+        and the new copy are live together until the pad is done."""
+        if new_cap <= self._cap:
             return
         pad = new_cap - self._cap
-        self._feats16 = jnp.pad(self._feats16, ((0, pad), (0, 0)))
-        self._flags = jnp.pad(self._flags, (0, pad))
-        self._docids = jnp.pad(self._docids, (0, pad), constant_values=-1)
+        bufs = (self._feats16, self._flags, self._docids)
+        grow = [_pad_rows.lower(b, pad, fill).compile()
+                for b, fill in zip(bufs, (0, 0, -1))]
+        t0 = time.perf_counter()
+        with tracing.timed("arena.grow", from_rows=self._cap,
+                           to_rows=new_cap,
+                           bytes=int(new_cap * self.device_row_bytes)):
+            bufs = jax.block_until_ready(
+                [g(b) for g, b in zip(grow, bufs)])
+        self._feats16, self._flags, self._docids = bufs
+        track(EClass.INDEX, "arena_grow", pad,
+              (time.perf_counter() - t0) * 1e3)
         self._cap = new_cap
 
-    def append_block(self, chunks) -> int:
-        """Pack a flat block streamed as (docids, feats) numpy chunks;
-        returns the block's base row.
+    def _write_piece(self, off: int, rows: int) -> tuple[int, int]:
+        """(rows, padded rows) of the next write at row `off`, with
+        `rows` left to write. The write pads to a size bucket (a bounded
+        set of compiled shapes) unless the bucket would cross the
+        capacity; then a power-of-two head goes first and the rest
+        follows. cap_for leaves a TILE past the append's end, so the
+        last piece's bucket fits."""
+        pad = _bucket_rows(rows)
+        if off + pad <= self._cap:
+            return rows, pad
+        head = 1 << (rows.bit_length() - 1)
+        return head, head
 
-        The whole block is assembled in HOST buffers first (a transient
-        spike of the block's size) and written with ONE device update per
-        array: every `dynamic_update_slice` without donation copies the
-        entire arena, so per-chunk writes would cost O(arena) each — the
-        round-1 10M pack spent minutes there. Buffers pad to size buckets
-        (bounded compile count); pad rows carry docid -1 and are either
-        overwritten by the next append or left inert past the used mark."""
-        parts_d, parts_f = [], []
-        for docids, feats in chunks:
-            if len(docids):
-                parts_d.append(np.asarray(docids))
-                parts_f.append(np.asarray(feats))
+    def _write_rows(self, off: int, f16, fl, dd) -> None:
+        """One device update per array at row `off`. dynamic_update_slice
+        CLAMPS a start whose update does not fit, which would overwrite
+        earlier rows in silence: a write past the capacity raises."""
+        if off + len(dd) > self._cap:
+            raise ValueError(f"arena write of {len(dd)} rows at {off} "
+                             f"crosses the capacity {self._cap}")
+        at = np.int32(off)
+        self._feats16 = _write_rows2(self._feats16, self._dev(f16), at)
+        self._flags = _write_rows1(self._flags, self._dev(fl), at)
+        self._docids = _write_rows1(self._docids, self._dev(dd), at)
+
+    def append_block(self, chunks) -> int:
+        """Pack a flat block streamed as numpy chunks (docids, f16,
+        flags), the rows as compact_feats gives them; returns the block's
+        base row.
+
+        Each write's HOST buffers are filled from the chunks in place (a
+        transient spike of the write's size) and written with ONE device
+        update per array: every `dynamic_update_slice` without donation
+        copies the entire arena, so per-chunk writes would cost O(arena)
+        each — the round-1 10M pack spent minutes there. Buffers pad to
+        size buckets (bounded compile count); pad rows carry docid -1 and
+        are either overwritten by the next append or left inert past the
+        used mark. The arena grows to cap_for(n), what would_fit
+        admitted, and no write's padding crosses it (_write_piece): near
+        the capacity a block goes in two writes or more."""
+        parts = [c for c in chunks if len(c[0])]
         base = self._used
-        if not parts_d:
+        if not parts:
             return base
-        dd = np.concatenate(parts_d) if len(parts_d) > 1 else parts_d[0]
-        ff = np.concatenate(parts_f) if len(parts_f) > 1 else parts_f[0]
-        n = len(dd)
-        pad = _bucket_rows(n)
-        f16 = np.zeros((pad, P.NF), np.int16)
-        fl = np.zeros(pad, np.int32)
-        dpad = np.full(pad, -1, np.int32)
-        cf, cfl = compact_feats(np.ascontiguousarray(ff, dtype=np.int32))
-        f16[:n], fl[:n], dpad[:n] = cf, cfl, dd
-        self._grow_to(self._used + pad + TILE)
-        off = np.int32(self._used)
-        self._feats16 = _write_rows2(self._feats16, self._dev(f16), off)
-        self._flags = _write_rows1(self._flags, self._dev(fl), off)
-        self._docids = _write_rows1(self._docids, self._dev(dpad), off)
+        n = sum(len(c[0]) for c in parts)
+        self._grow_to(self.cap_for(n))
+        at = k = k_at = 0       # rows written; the chunk, its rows written
+        while at < n:
+            rows, pad = self._write_piece(base + at, n - at)
+            f16 = np.zeros((pad, P.NF), np.int16)
+            fl = np.zeros(pad, np.int32)
+            dpad = np.full(pad, -1, np.int32)
+            got = 0
+            while got < rows:
+                dd, cf, cfl = parts[k]
+                take = min(rows - got, len(dd) - k_at)
+                f16[got:got + take] = cf[k_at:k_at + take]
+                fl[got:got + take] = cfl[k_at:k_at + take]
+                dpad[got:got + take] = dd[k_at:k_at + take]
+                got += take
+                k_at += take
+                if k_at == len(dd):
+                    k, k_at = k + 1, 0
+            # one whole-arena copy at a time (the write is not donated)
+            with tracing.timed("arena.append", rows=rows, pad=pad):
+                self._write_rows(base + at, f16, fl, dpad)
+                jax.block_until_ready(self._feats16)
+            at += rows
         self._used += n
         return base
 
@@ -1776,7 +1876,10 @@ class DeviceArena:
                 continue
             words = (sorted_docids >> 5).astype(np.int64)
             bits = (np.uint32(1) << (sorted_docids & 31).astype(np.uint32))
-            uw, starts = np.unique(words, return_index=True)
+            # the segment ascends: a word's bits are one run of rows
+            starts = np.flatnonzero(np.concatenate(
+                ([True], words[1:] != words[:-1])))
+            uw = words[starts]
             bm = np.zeros(self._bm_nwords, np.uint32)
             bm[uw] = np.bitwise_or.reduceat(bits, starts)
             pc = self._POPC8[bm.view(np.uint8)].reshape(-1, 4).sum(1)
@@ -1805,6 +1908,8 @@ class DeviceArena:
                 self._bmtab = _write_rows3(self._bmtab, chunk,
                                            np.int32(self._bm_used))
             self._bm_used += len(bufs)
+            # join-bitmap slots handed out since the process started
+            track(EClass.INDEX, "join_bitmap_slots", len(bufs))
         return out
 
     def mark_dead(self, docid: int) -> None:
@@ -3273,10 +3378,6 @@ class _QueryBatcher:
                                exc_ms=exc_ms):
                         bucket = _join_bucket(r, n_inc, n_exc)
                         s, d, survivors = _split_join_out(host, bucket)
-                        if bucket:
-                            store._count_survivors(
-                                bucket, [it["qargs"][1] for it in chunk],
-                                survivors[:len(chunk)])
                         wall = time.perf_counter() - t0k
                         for it in chunk:
                             it["kernel_ms"] = wall * 1000.0
@@ -3290,8 +3391,21 @@ class _QueryBatcher:
                                       // max(len(windows), 1))}),
                             n_inc=n_inc, n_exc=n_exc, bs=len(chunk),
                             k=kk, bucket=bucket)
-                        for i, it in enumerate(chunk):
-                            _deliver(it, ("ok", s[i], d[i]))
+                        # a query's survivors count before its ev.set(),
+                        # and not at all where its waiter abandoned it:
+                        # that query is served, and counted, solo (the
+                        # store lock, then the item's, as every finish
+                        # takes them)
+                        with store._lock:
+                            for i, it in enumerate(chunk):
+                                with it["lk"]:
+                                    if it.get("abandoned"):
+                                        continue
+                                    if bucket:
+                                        store._count_survivors(
+                                            bucket, [it["qargs"][1]],
+                                            survivors[i:i + 1])
+                                    _deliver(it, ("ok", s[i], d[i]))
 
                     self._submit_completion(out, finish, chunk, kname,
                                             t0k, issue_ms)
@@ -3306,6 +3420,18 @@ class _QueryBatcher:
                 for it in its:
                     if id(it) not in issued and not it["ev"].is_set():
                         _deliver(it)
+
+
+def _ties_by_docid(s, d):
+    """A device answer's (scores, docids), best first, with equal
+    rankings by ascending document id: the order the host path and the
+    reference pin. lax.top_k breaks ties by candidate index, and an
+    arena in pruning order is not in document order. Applied where an
+    answer is made, before its [:k] cut and its top-k cache entry, so a
+    cached sight answers in the order of the first."""
+    s, d = np.asarray(s), np.asarray(d)
+    order = np.lexsort((d, -s.astype(np.int64)))
+    return s[order], d[order]
 
 
 class DeviceSegmentStore:
@@ -3384,6 +3510,11 @@ class DeviceSegmentStore:
         self._garbage_rows = 0
         self.queries_served = 0
         self.fallbacks = 0
+        # rows of frozen runs packed into the arena, and of runs the
+        # budget refused (those stay host-served: the one way a node
+        # serves part of its index off the device without failing)
+        self.arena_rows_packed = 0
+        self.arena_rows_skipped = 0
         # -- device-loss recovery (ISSUE 10 tentpole c) -----------------
         # device_fetch classifies every transfer: a fetch that fails
         # through its whole retry ladder is a FAILED transfer; a streak
@@ -3485,6 +3616,7 @@ class DeviceSegmentStore:
         self._prewarm_on = False        # set by enable_batching
         self._prewarm_key = None        # arena shapes last prewarmed
         self._prewarm_running = False
+        self._arena_moved_t = 0.0       # last pack or growth (monotonic)
         # prewarm outcome, visible in counters() and /metrics: a shape
         # the compiler refuses (or an aborted pass) is a bring-up
         # failure the first live query would otherwise discover
@@ -3747,6 +3879,11 @@ class DeviceSegmentStore:
                 # over budget: run stays host-served (spans_for -> None for
                 # its terms); merges may later shrink the index back in
                 track(EClass.INDEX, "devstore_skip", rows)
+                self.arena_rows_skipped += rows
+                log.warning("device arena: a run of %d rows would grow "
+                            "%d rows past the budget; it stays "
+                            "host-served", rows,
+                            self.arena.cap_for(rows))
                 return
             base = self.arena.used_rows
             meta: list[tuple] = []   # (th, rel_off, n, rel_toff, n_tiles,
@@ -3763,16 +3900,14 @@ class DeviceSegmentStore:
                 if p is None or len(p) == 0:
                     continue
                 f16, fl = compact_feats(p.feats)
-                stats, proxy = pack_prune_stats(f16, fl)
-                order = np.argsort(-proxy, kind="stable")
+                stats, proxy = pack_prune_stats(f16, fl, p.feats)
+                order = proxy_order(proxy)
                 n = len(p)
                 n_tiles = (n + TILE - 1) // TILE
                 pmax_parts.append(pmax_table(proxy[order]))
-                packed_dd = p.docids[order]
                 # docid-sorted view of the packed rows: the device
                 # conjunction's binary-search table (absolute arena rows)
-                jorder = np.argsort(packed_dd, kind="stable")
-                sorted_dd = packed_dd[jorder].astype(np.int32)
+                sorted_dd, jorder = docid_view(p.docids, order)
                 join_dd_parts.append(sorted_dd)
                 join_pos_parts.append(
                     (base + off + jorder).astype(np.int32))
@@ -3783,7 +3918,7 @@ class DeviceSegmentStore:
                 off += n
                 toff += n_tiles
                 joff += n
-                pending.append((packed_dd, p.feats[order]))
+                pending.append((p.docids[order], f16[order], fl[order]))
             if pending:
                 # one arena write for the whole run (transient host buffer
                 # of the run's size; see append_block)
@@ -3808,6 +3943,7 @@ class DeviceSegmentStore:
                 if nt > self._max_tcount:
                     self._max_tcount = nt
             track(EClass.INDEX, "devstore_pack", rows)
+            self.arena_rows_packed += rows
         # crawl-to-searchable `ingest.device` tier (ISSUE 13a): the run
         # is arena-resident — its fresh docs now serve from the device
         # (no-op for runs without stamps: merges, startup re-packs)
@@ -3820,8 +3956,8 @@ class DeviceSegmentStore:
         the same frozen stats + pmax bound rows) the int16 pack uses —
         parity with the int16 scorer path is by construction."""
         f16, fl = compact_feats(p.feats)
-        stats, proxy = pack_prune_stats(f16, fl)
-        order = np.argsort(-proxy, kind="stable")
+        stats, proxy = pack_prune_stats(f16, fl, p.feats)
+        order = proxy_order(proxy)
         block = PK.pack_block(f16[order], fl[order],
                               p.docids[order].astype(np.int32))
         return {"block": block, "stats": stats,
@@ -3863,8 +3999,8 @@ class DeviceSegmentStore:
         prep = []
         for th, p in plist:
             f16, fl = compact_feats(p.feats)
-            stats, proxy = pack_prune_stats(f16, fl)
-            order = np.argsort(-proxy, kind="stable")
+            stats, proxy = pack_prune_stats(f16, fl, p.feats)
+            order = proxy_order(proxy)
             prep.append((th, p, f16[order], fl[order],
                          p.docids[order].astype(np.int32), stats,
                          pmax_table(proxy[order])))
@@ -4375,14 +4511,23 @@ class DeviceSegmentStore:
             self._prewarm_on = bool(prewarm)
             self._maybe_prewarm()
 
+    # a prewarm pass starts once the arena has been still this long: a
+    # bulk load packs run after run and doubles the buffers as it goes,
+    # and a pass under it compiles shapes no query will use while it
+    # holds the interpreter from the load's own work
+    PREWARM_QUIET_S = 2.0
+
     def _maybe_prewarm(self) -> None:
         """Schedule a background prewarm when the compile-relevant arena
         shapes changed since the last one (growth doubles the buffers,
         which re-keys every kernel compile). At most one prewarm thread
-        runs; it loops until the shapes it warmed are still current."""
+        runs; each pass waits until no pack or growth has come for
+        PREWARM_QUIET_S, and it loops until the shapes it warmed are
+        still current."""
         if not getattr(self, "_prewarm_on", False):
             return
         with self._lock:
+            self._arena_moved_t = time.monotonic()
             key = self._prewarm_shape_key()
             if self._prewarm_running or key == self._prewarm_key:
                 return
@@ -4391,6 +4536,13 @@ class DeviceSegmentStore:
         def run():
             try:
                 while True:
+                    while True:
+                        with self._lock:
+                            wait = (self._arena_moved_t + self.PREWARM_QUIET_S
+                                    - time.monotonic())
+                        if wait <= 0:
+                            break
+                        time.sleep(wait)
                     with self._lock:
                         key = self._prewarm_shape_key()
                     self.prewarm_kernels()
@@ -4668,11 +4820,21 @@ class DeviceSegmentStore:
         finally:
             self._lock.release()
 
+    def arena_figures(self) -> dict:
+        """The int16 arena's rows: packed and refused (counters), its
+        capacity and the rows in use (gauges of the serving arena). The
+        seconds it spent growing are the span family `arena.grow`."""
+        return {"arena_rows_packed": self.arena_rows_packed,
+                "arena_rows_skipped": self.arena_rows_skipped,
+                "arena_capacity_rows": self.arena.capacity_rows,
+                "arena_used_rows": self.arena.used_rows}
+
     def _counters_locked(self, b, tb) -> dict:
         return {
             "dispatch_rt_ms": self.dispatch_rt_ms,
             "queries_served": self.queries_served,
             "fallbacks": self.fallbacks,
+            **self.arena_figures(),
             # device-loss recovery (ISSUE 10c): 0/1 lost flag, declared
             # losses, completed rebuilds, host-fallback answers while
             # lost, and the transfer classifier's failure/retry counts
@@ -5165,7 +5327,8 @@ class DeviceSegmentStore:
             if not all_bm:
                 self.join_sm_served += 1
             self.arena.join_shapes.add(statics)
-        return s[keep][:k], d[keep][:k], considered
+        s, d = _ties_by_docid(s[keep], d[keep])
+        return s[:k], d[:k], considered
 
     def _count_survivors(self, bucket: int, considered, survivors) -> None:
         """Count compacting joins of one bucket size: their rare rows
@@ -5895,11 +6058,10 @@ class DeviceSegmentStore:
                 (int(lang_filter), int(flag_bit), from_days, to_days),
                 consts, kk)
         keep = (d >= 0) & (s > NEG_INF32)
-        s, d = s[keep], d[keep]
+        s, d = _ties_by_docid(s[keep], d[keep])
         with self._lock:
             self.queries_served += 1
         if cacheable:
-            s, d = np.asarray(s), np.asarray(d)
             self._topk_cache.put(
                 (termhash, profile.to_external_string(), language, kk),
                 epoch0, s, d, considered)
@@ -6244,7 +6406,7 @@ class DeviceSegmentStore:
                         (cmin, cmax, np.float32(tfmin),
                          np.float32(tfmax)))
         keep = (d >= 0) & (s > NEG_INF32)
-        s, d = s[keep], d[keep]
+        s, d = _ties_by_docid(s[keep], d[keep])
         # cross-run duplicate docids are possible after raw transfer
         # re-pushes (rwi.get folds them host-side; here both rows scored):
         # keep the best-scored instance of each docid
@@ -6258,7 +6420,6 @@ class DeviceSegmentStore:
             # insert the FINAL (post keep/dedup) answer under the
             # snapshot's epoch: a flush/merge/repack since then leaves
             # the entry born-stale, which the lookup detects
-            s, d = np.asarray(s), np.asarray(d)
             self._topk_cache.put(
                 (termhash, profile.to_external_string(), language, kk),
                 epoch0, s, d, considered)

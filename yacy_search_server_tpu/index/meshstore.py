@@ -82,7 +82,8 @@ from .devstore import (_PRUNE_B, DAYS_NONE_HI, DAYS_NONE_LO,
                        DeviceTransferError, _TopkCache, _bucket_delta,
                        _bucket_rows, _constraint_valid, _emit_rt_spans,
                        _pruned_span_topk, _tile_valid, measure_row_bytes,
-                       pack_prune_stats, pmax_table, prune_bound_consts)
+                       pack_prune_stats, pmax_table, prune_bound_consts,
+                       proxy_order)
 
 INT32_MAX = 2 ** 31 - 1
 
@@ -729,7 +730,7 @@ class MeshSegmentStore:
                 # GLOBAL frozen stats + proxy scores over the WHOLE term:
                 # all cells prune/score in one normalized space, and the
                 # per-device tail bound stays a true upper bound
-                gstats, proxy = pack_prune_stats(f16, fl)
+                gstats, proxy = pack_prune_stats(f16, fl, p.feats)
                 t = term_shard(th, self.n_term)
                 d_shard = dd % self.n_doc
                 starts = np.zeros(self.n_cells, np.int32)
@@ -745,7 +746,7 @@ class MeshSegmentStore:
                     cell = self._cell_of(t, d)
                     buf = self._cells[cell]
                     # rows pack PROXY-SORTED (block-max prune layout)
-                    order = np.argsort(-proxy[sel], kind="stable")
+                    order = proxy_order(proxy[sel])
                     cell_dd = dd[sel][order]
                     start = buf.append(f16[sel][order], fl[sel][order],
                                        cell_dd)

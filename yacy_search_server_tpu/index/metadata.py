@@ -372,6 +372,84 @@ FACET_FIELDS = ("host_s", "url_file_ext_s", "url_protocol_s")
 MAX_SEGMENTS = 16
 
 
+def _write_rows_segment(path: str, n: int, hashes, ints: dict,
+                        doubles: dict, facets: dict, texts: dict) -> None:
+    """Write `n` rows as one frozen segment: their url hashes, the
+    numeric and text columns given (a column absent or all default is
+    omitted), and per facet field {value: [row, ...]}."""
+    hashes = np.asarray(hashes, dtype="S12")
+    order = np.argsort(hashes, kind="stable")
+    arrays: dict[str, np.ndarray] = {
+        "urlhashes": hashes,
+        "uh_sorted": hashes[order],
+        "uh_order": order.astype(np.int64),
+    }
+    # ALL-DEFAULT columns are omitted: readers fall back to ""/0 for
+    # absent names (has_text/has_array), and a 10M-row segment whose
+    # ~100 sparse schema columns each carry an 80 MB offsets array
+    # would be ~15 GB of zeros (r4 disk-full incident)
+    for cols, dtype in ((ints, np.int64), (doubles, np.float64)):
+        for f, vals in cols.items():
+            col = np.asarray(vals, dtype=dtype)
+            if col.any():
+                arrays[f] = col
+    facets_meta: dict = {}
+    for f in FACET_FIELDS:
+        values, starts, counts, rows = [], [], [], []
+        pos = 0
+        for v, local in sorted(facets.get(f, {}).items()):
+            if not local:
+                continue
+            values.append(v)
+            starts.append(pos)
+            counts.append(len(local))
+            rows.extend(local)
+            pos += len(local)
+        facets_meta[f] = {"values": values, "starts": starts,
+                          "counts": counts}
+        arrays[f"facet_rows:{f}"] = np.asarray(rows, dtype=np.int32)
+    texts = {f: col for f, col in texts.items() if any(col)}
+    write_segment(path, n, arrays, texts, meta={"facets": facets_meta})
+
+
+def _joined_texts_facets(a: SegmentReader, b: SegmentReader, arrays: dict):
+    """Segment `a`'s rows followed by `b`'s, as stored: the text columns
+    encoded, and per facet field each value's rows from both tables (b's
+    shifted by a.n; facet rows into `arrays`): (texts, facets_meta)."""
+    texts = {}
+    for f in TEXT_FIELDS:
+        if a.has_text(f) or b.has_text(f):
+            ao, ablob = a.text_raw(f)
+            bo, bblob = b.text_raw(f)
+            texts[f] = (np.concatenate([ao, bo[1:] + ao[-1]]),
+                        ablob + bblob)
+    facets_meta = {}
+    for f in FACET_FIELDS:
+        byval: dict[str, list[np.ndarray]] = {}
+        for seg, off in ((a, 0), (b, a.n)):
+            fmeta = seg.meta.get("facets", {}).get(f)
+            if not fmeta:
+                continue
+            rows = np.asarray(seg.array(f"facet_rows:{f}"), np.int32)
+            for v, start, cnt in zip(fmeta["values"], fmeta["starts"],
+                                     fmeta["counts"]):
+                byval.setdefault(v, []).append(rows[start:start + cnt] + off)
+        values, starts, counts, parts = [], [], [], []
+        pos = 0
+        for v, got in sorted(byval.items()):
+            cnt = sum(len(g) for g in got)
+            values.append(v)
+            starts.append(pos)
+            counts.append(cnt)
+            parts += got
+            pos += cnt
+        facets_meta[f] = {"values": values, "starts": starts,
+                          "counts": counts}
+        arrays[f"facet_rows:{f}"] = (np.concatenate(parts) if parts
+                                     else np.empty(0, np.int32))
+    return texts, facets_meta
+
+
 class MetadataStore:
     """docid-addressed columnar store with urlhash identity index."""
 
@@ -542,6 +620,9 @@ class MetadataStore:
         with self._lock:
             self.facet_version += 1
             base = self._frozen_n + len(self._tail_hashes)
+            if self.data_dir and n and not self._tail_hashes:
+                self._freeze_batch_locked(urlhashes, columns)
+                return base
             self._tail_map.update(
                 (uh, base + i) for i, uh in enumerate(urlhashes))
             self._tail_hashes.extend(urlhashes)
@@ -560,6 +641,35 @@ class MetadataStore:
                         if v:
                             idx.setdefault(v, []).append(base + i)
             return base
+
+    def _freeze_batch_locked(self, urlhashes: list[bytes],
+                             columns: dict) -> None:
+        """A bulk load into an empty tail, written as the next frozen
+        segment at once: what bulk_load + snapshot() leave behind, with
+        no tail lists for the ~180 columns the batch leaves at their
+        defaults (an all-default column is omitted from a segment)."""
+        n = len(urlhashes)
+        facets = {}
+        for f in FACET_FIELDS:
+            byval: dict[str, list[int]] = {}
+            for i, v in enumerate(columns.get(f) or ()):
+                v = str(v or "").lower()
+                if v:
+                    byval.setdefault(v, []).append(i)
+            facets[f] = byval
+        segname = f"metadata.{self._seg_seq:06d}.seg"
+        self._seg_seq += 1
+        _write_rows_segment(
+            self._path(segname), n, urlhashes,
+            {f: columns[f] for f in INT_FIELDS if columns.get(f)},
+            {f: columns[f] for f in DOUBLE_FIELDS if columns.get(f)},
+            facets, {f: columns[f] for f in TEXT_FIELDS if columns.get(f)})
+        self._seg_bases.append(self._frozen_n)
+        self._segs.append(SegmentReader(self._path(segname)))
+        self._frozen_n += n
+        if len(self._segs) > MAX_SEGMENTS:
+            self._merge_smallest_locked()
+        self._persist_state_locked()
 
     def set_field(self, docid: int, field: str, value) -> None:
         """Postprocessing update (e.g. references_i from the citation index)."""
@@ -976,51 +1086,15 @@ class MetadataStore:
             self._persist_state_locked()
 
     def _write_tail_segment_locked(self, path: str, n: int) -> None:
-        hashes = np.asarray(self._tail_hashes, dtype="S12")
-        order = np.argsort(hashes, kind="stable")
-        arrays: dict[str, np.ndarray] = {
-            "urlhashes": hashes,
-            "uh_sorted": hashes[order],
-            "uh_order": order.astype(np.int64),
-        }
-        # ALL-DEFAULT columns are omitted: readers fall back to ""/0 for
-        # absent names (has_text/has_array), and a 10M-row segment whose
-        # ~100 sparse schema columns each carry an 80 MB offsets array
-        # would be ~15 GB of zeros (r4 disk-full incident)
-        for f in INT_FIELDS:
-            col = np.asarray(self._ints[f], dtype=np.int64)
-            if col.any():
-                arrays[f] = col
-        for f in DOUBLE_FIELDS:
-            col = np.asarray(self._doubles[f], dtype=np.float64)
-            if col.any():
-                arrays[f] = col
-        facets_meta: dict = {}
-        for f in FACET_FIELDS:
-            values, starts, counts, rows = [], [], [], []
-            pos = 0
-            for v, docs in sorted(self._facets[f].items()):
-                # tail facet lists may also carry override additions for
-                # FROZEN docids — those stay in the live maps, only tail
-                # rows freeze into the segment table
-                local = [d - self._frozen_n for d in docs
-                         if d >= self._frozen_n]
-                if not local:
-                    continue
-                values.append(v)
-                starts.append(pos)
-                counts.append(len(local))
-                rows.extend(local)
-                pos += len(local)
-            facets_meta[f] = {"values": values, "starts": starts,
-                              "counts": counts}
-            arrays[f"facet_rows:{f}"] = np.asarray(rows, dtype=np.int32)
-        texts = {}
-        for f in TEXT_FIELDS:
-            col = self._text[f]
-            if any(col):        # all-empty columns are omitted (see above)
-                texts[f] = col
-        write_segment(path, n, arrays, texts, meta={"facets": facets_meta})
+        # tail facet lists may also carry override additions for FROZEN
+        # docids — those stay in the live maps, only tail rows freeze
+        # into the segment table
+        facets = {f: {v: [d - self._frozen_n for d in docs
+                          if d >= self._frozen_n]
+                      for v, docs in self._facets[f].items()}
+                  for f in FACET_FIELDS}
+        _write_rows_segment(path, n, self._tail_hashes, self._ints,
+                            self._doubles, facets, self._text)
 
     def _merge_smallest_locked(self) -> None:
         """Merge the two smallest ADJACENT segments into one (bounded
@@ -1033,7 +1107,6 @@ class MetadataStore:
         base = self._seg_bases[i]
         n = a.n + b.n
         arrays: dict[str, np.ndarray] = {}
-        texts: dict[str, list[str]] = {}
         hashes = np.concatenate([np.asarray(a.array("urlhashes")),
                                  np.asarray(b.array("urlhashes"))])
         order = np.argsort(hashes, kind="stable")
@@ -1062,6 +1135,44 @@ class MetadataStore:
             col = merged_numeric(f, np.float64)
             if col.any():
                 arrays[f] = col
+        if self._rows_untouched_locked(base, base + n):
+            # no row of the two is deleted, has a text override left
+            # (numeric ones are folded above) or is suppressed in a facet:
+            # the text columns and facet tables join as stored
+            texts, facets_meta = _joined_texts_facets(a, b, arrays)
+        else:
+            texts, facets_meta = self._folded_texts_facets_locked(
+                a, b, base, n, arrays)
+        segname = f"metadata.{self._seg_seq:06d}.seg"
+        self._seg_seq += 1
+        write_segment(self._path(segname), n, arrays, texts,
+                      meta={"facets": facets_meta})
+        old_a, old_b = a.path, b.path
+        a.close()
+        b.close()
+        self._segs[i:i + 2] = [SegmentReader(self._path(segname))]
+        self._seg_bases[:] = np.concatenate(
+            [[0], np.cumsum([s.n for s in self._segs])[:-1]]).tolist()
+        # victims are deleted only AFTER the manifest stops referencing
+        # them (_persist_state) — a crash in between must leave a
+        # manifest whose every segment file still exists
+        self._pending_remove += [old_a, old_b]
+
+    def _rows_untouched_locked(self, lo: int, hi: int) -> bool:
+        """No docid in [lo, hi) is deleted, overridden, or suppressed in
+        a frozen facet table."""
+        def hit(docids):
+            return any(lo <= d < hi for d in docids)
+        return not (hit(self._deleted)
+                    or any(hit(ov) for ov in self._overrides.values())
+                    or any(hit(s) for s in self._facet_removed.values()))
+
+    def _folded_texts_facets_locked(self, a, b, base: int, n: int,
+                                    arrays: dict):
+        """The merged text columns with overrides folded in and deleted
+        rows blanked, and facet tables rebuilt from them (facet rows into
+        `arrays`): (texts, facets_meta)."""
+        texts: dict[str, list[str]] = {}
         for f in TEXT_FIELDS:
             col = (a.text_column(f) if a.has_text(f) else [""] * a.n) + \
                   (b.text_column(f) if b.has_text(f) else [""] * b.n)
@@ -1109,21 +1220,7 @@ class MetadataStore:
             facets_meta[f] = {"values": values, "starts": starts,
                               "counts": counts}
             arrays[f"facet_rows:{f}"] = np.asarray(rows, dtype=np.int32)
-
-        segname = f"metadata.{self._seg_seq:06d}.seg"
-        self._seg_seq += 1
-        write_segment(self._path(segname), n, arrays, texts,
-                      meta={"facets": facets_meta})
-        old_a, old_b = a.path, b.path
-        a.close()
-        b.close()
-        self._segs[i:i + 2] = [SegmentReader(self._path(segname))]
-        self._seg_bases[:] = np.concatenate(
-            [[0], np.cumsum([s.n for s in self._segs])[:-1]]).tolist()
-        # victims are deleted only AFTER the manifest stops referencing
-        # them (_persist_state) — a crash in between must leave a
-        # manifest whose every segment file still exists
-        self._pending_remove += [old_a, old_b]
+        return texts, facets_meta
 
     def _persist_state_locked(self) -> None:
         import io

@@ -182,19 +182,17 @@ class PagedRun:
             start = 0
             for th in order:
                 index[th] = (start, len(terms[th]))
-                dbytes = np.ascontiguousarray(
-                    terms[th].docids, dtype="<i4").tobytes()
-                f.write(dbytes)
+                darr = np.ascontiguousarray(terms[th].docids, dtype="<i4")
+                f.write(darr)
                 # span checksum: docid row bytes then feat row bytes —
                 # exactly what get() re-reads off the mmap
+                farr = np.ascontiguousarray(terms[th].feats, dtype="<i4")
                 crcs[th] = integrity.crc32(
-                    np.ascontiguousarray(
-                        terms[th].feats, dtype="<i4").tobytes(),
-                    integrity.crc32(dbytes))
+                    farr.reshape(-1).view(np.uint8),
+                    integrity.crc32(darr.view(np.uint8)))
                 start += len(terms[th])
             for th in order:
-                f.write(np.ascontiguousarray(
-                    terms[th].feats, dtype="<i4").tobytes())
+                f.write(np.ascontiguousarray(terms[th].feats, dtype="<i4"))
             f.flush()
             os.fsync(f.fileno())
         body = [f"{_MAGIC} {total} {dead_seq}"]

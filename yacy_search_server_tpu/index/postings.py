@@ -95,6 +95,11 @@ class PostingsList:
 def sort_dedupe(docids: np.ndarray, feats: np.ndarray) -> PostingsList:
     """Sort by docid; on duplicates the *last* row wins (newest write)."""
     from ..utils import native
+    if len(docids) > 1 and bool(np.all(docids[1:] > docids[:-1])):
+        # already ascending without a repeat (a bulk import's lists): the
+        # order is the identity
+        return PostingsList(np.array(docids, np.int32),
+                            np.array(feats, np.int32))
     order = native.sort_dedupe_order(docids)
     if order is not None:
         return PostingsList(docids[order].astype(np.int32, copy=False),

@@ -218,13 +218,92 @@ def local_stats(feats: jnp.ndarray, valid: jnp.ndarray, hostids: jnp.ndarray,
             "tf_min": tf_min, "tf_max": tf_max, "host_counts": host_counts}
 
 
+def _f32_bits(x: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _split12(x: jnp.ndarray):
+    """x = hi + lo exactly, hi its upper 12 significant bits, lo >= 0 the
+    rest (at most 12 bits): products of two such halves are exact."""
+    hi = jax.lax.bitcast_convert_type(_f32_bits(x) & ~0xFFF, jnp.float32)
+    return hi, x - hi
+
+
+def _rem(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
+    """a - c * b exactly, for c within a few ulps of a / b (a >= 1): the
+    product as p + e (Dekker's product from exact half products), a - p
+    exact (Sterbenz), and (a - p) - e spans fewer than 24 bits."""
+    b_hi, b_lo = _split12(b)
+    c_hi, c_lo = _split12(c)
+    p = c * b
+    e = (((c_hi * b_hi - p) + c_hi * b_lo) + c_lo * b_hi) + c_lo * b_lo
+    return (a - p) - e
+
+
+def _ieee_div_nearest(a: jnp.ndarray, b: jnp.ndarray,
+                      q: jnp.ndarray) -> jnp.ndarray:
+    """The correctly rounded float32 quotient a / b of whole numbers
+    (0 <= a < 2^16, 1 <= b < 2^17), given `q`, a quotient a few ulps off
+    it at most, as a device's division may be (the v5e's is off by more
+    than one ulp in about one case in 200). One Newton step with the
+    exact remainder brings q within an ulp; a / b is never a tie between
+    two floats (its odd part would need 25 bits), so it then rounds to
+    the upper of two neighbours exactly when a - lo * b >= (hi - lo) / 2
+    * b."""
+    def above_mid(lo, hi):
+        return _rem(a, b, lo) >= (0.5 * (hi - lo)) * b
+
+    q = q + _rem(a, b, q) / b
+    up = jnp.nextafter(q, jnp.float32(jnp.inf))
+    down = jnp.nextafter(q, jnp.float32(0))
+    q = jnp.where(above_mid(q, up), up,
+                  jnp.where(above_mid(down, q), q, down))
+    return jnp.where(a > 0, q, 0.0)
+
+
+def _ieee_trunc_div(n: jnp.ndarray, d: jnp.ndarray,
+                    q: jnp.ndarray) -> jnp.ndarray:
+    """trunc of the correctly rounded float32 quotient n / d (n >= 0,
+    d > 0, quotient < 2^23), given `q`, a quotient that may be an ulp off
+    it, as a device's division may be: the v5e truncated the largest
+    normalised term frequency, whose quotient is exactly 256, to 255.
+
+    rnd(n / d) >= k exactly when r = n - k * d >= -(u/2) * d, u the ulp
+    under k (a tie rounds to k, whose mantissa is even). k * d is split
+    exactly into k * d_hi + k * d_lo (d_hi: d's upper 12 significant
+    bits, d_lo >= 0 the rest; k has at most 10), and each subtraction is
+    exact wherever it can decide (Sterbenz; elsewhere r is far from the
+    bound), so r is exact where it matters; two such tests from
+    trunc(q) - 1 give the floor that IEEE division gives."""
+    d_hi, d_lo = _split12(d)
+    t = jnp.maximum(q.astype(jnp.int32) - 1, 0)
+    for _ in range(2):
+        k = (t + 1).astype(jnp.float32)
+        u = k - jax.lax.bitcast_convert_type(_f32_bits(k) - 1, jnp.float32)
+        r = (n - k * d_hi) - k * d_lo
+        t = t + (r >= -(0.5 * u * d)).astype(jnp.int32)
+    return t
+
+
+def _tf_norm(tf: jnp.ndarray, tf_min, tf_max) -> jnp.ndarray:
+    """(tf - tf_min) * 256 / (tf_max - tf_min), truncated as the host's
+    IEEE division truncates it; 0 where the span is 0."""
+    span = tf_max - tf_min
+    n = (tf - tf_min) * 256.0
+    q = n / jnp.maximum(span, 1e-9)
+    return jnp.where(span > 0,
+                     _ieee_trunc_div(n, jnp.maximum(span, 1e-9), q), 0)
+
+
 def _term_frequency(feats: jnp.ndarray) -> jnp.ndarray:
     """hitcount / (wordsintext + wordsintitle + 1)
-    (WordReferenceVars.termFrequency semantics)."""
-    return feats[:, P.F_HITCOUNT].astype(jnp.float32) / (
-        feats[:, P.F_WORDS_IN_TEXT].astype(jnp.int32)
-        + feats[:, P.F_WORDS_IN_TITLE].astype(jnp.int32) + 1
-    ).astype(jnp.float32)
+    (WordReferenceVars.termFrequency semantics), rounded as IEEE
+    float32 division rounds it (the host's twins divide so)."""
+    h = feats[:, P.F_HITCOUNT].astype(jnp.float32)
+    w = (feats[:, P.F_WORDS_IN_TEXT].astype(jnp.int32)
+         + feats[:, P.F_WORDS_IN_TITLE].astype(jnp.int32) + 1
+         ).astype(jnp.float32)
+    return _ieee_div_nearest(h, w, h / w)
 
 
 def _norm_div_exact_fast(prod: jnp.ndarray, safe_span: jnp.ndarray) -> jnp.ndarray:
@@ -287,12 +366,8 @@ def cardinal_from_stats(feats: jnp.ndarray, valid: jnp.ndarray,
 
     # term frequency: hitcount / (wordsintext + wordsintitle + 1), min/max
     # normalized to 0..255 (WordReferenceVars.termFrequency semantics)
-    tf = _term_frequency(feats)
-    tf_min, tf_max = stats["tf_min"], stats["tf_max"]
-    tf_span = tf_max - tf_min
-    tf_norm = jnp.where(
-        tf_span > 0, ((tf - tf_min) * 256.0 / jnp.maximum(tf_span, 1e-9)),
-        0.0).astype(jnp.int32)
+    tf_norm = _tf_norm(_term_frequency(feats), stats["tf_min"],
+                       stats["tf_max"])
     score = score + (tf_norm << tf_coeff)
 
     # language preference match: 255 << coeff
@@ -479,16 +554,31 @@ _ACTIVE_COLS = ~np.isin(
     np.arange(P.NF), [P.F_FLAGS, P.F_DOCTYPE, P.F_LANGUAGE, P.F_DOMLENGTH])
 
 
+def _column_reduce(ufunc, a: np.ndarray, rows: int = 256) -> np.ndarray:
+    """ufunc.reduce(a, axis=0) of a C-ordered [n, c] block, `rows` rows
+    to a line (a reduction along axis 0 of c narrow columns runs an
+    inner loop of c elements per row, several times slower)."""
+    n, c = a.shape
+    m = n // rows * rows
+    if m == 0:
+        return ufunc.reduce(a, axis=0)
+    out = ufunc.reduce(ufunc.reduce(np.ascontiguousarray(a[:m]).reshape(
+        m // rows, rows * c), axis=0).reshape(rows, c), axis=0)
+    return ufunc(out, ufunc.reduce(a[m:], axis=0)) if m < n else out
+
+
 def pack_stats_host(feats16: np.ndarray, flags: np.ndarray) -> dict:
     """Normalization stats over a compact block (numpy twin of
     local_stats, all rows valid) — float32 tf to match the kernel."""
-    f = feats16.astype(np.int32)
-    tf = f[:, P.F_HITCOUNT].astype(np.float32) / (
-        f[:, P.F_WORDS_IN_TEXT] + f[:, P.F_WORDS_IN_TITLE] + 1
+    def col(c):
+        return feats16[:, c].astype(np.int32)
+
+    tf = col(P.F_HITCOUNT).astype(np.float32) / (
+        col(P.F_WORDS_IN_TEXT) + col(P.F_WORDS_IN_TITLE) + 1
     ).astype(np.float32)
     return {
-        "col_min": f.min(axis=0).astype(np.int32),
-        "col_max": f.max(axis=0).astype(np.int32),
+        "col_min": _column_reduce(np.minimum, feats16).astype(np.int32),
+        "col_max": _column_reduce(np.maximum, feats16).astype(np.int32),
         "tf_min": np.float32(tf.min()),
         "tf_max": np.float32(tf.max()),
     }
@@ -534,6 +624,18 @@ def cardinal_from_stats_host(feats16: np.ndarray, flags: np.ndarray,
         auth = (counts[hostids].astype(np.int64) << 8) // (1 + counts.max())
         score += auth << prof.authority
     return score.astype(np.int64)
+
+
+def cardinal_scores_block(feats: np.ndarray, profile: "RankingProfile",
+                          language_pref: int) -> np.ndarray | None:
+    """cardinal_scores_host's scores of a whole int32 block (no host
+    counts) in one native call that lets go of the interpreter lock: the
+    pack-time proxy of lists of millions of rows. None where the library
+    or a shift outside 0..15 leaves the block to the NumPy twin."""
+    consts = _native_consts(profile)
+    if consts is None:
+        return None
+    return native.cardinal_scores(feats, consts, language_pref)
 
 
 def cardinal_scores_host(feats: np.ndarray, profile: "RankingProfile",

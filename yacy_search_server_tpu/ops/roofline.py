@@ -168,32 +168,36 @@ def roofline_point(kernel: str, cost: Cost, wall_s: float,
 # regardless of trip count, so their cross-check compares the unit-trip
 # cost (tests pass the one-step shape).
 
+# Every scorer computes the term frequency and its normalisation as IEEE
+# float32 division gives them (ops/ranking._ieee_div_nearest,
+# _ieee_trunc_div: exact remainders from split products), ~150 flops a
+# row in a stats pass and ~105 in a score pass; the fits below hold them.
 # cardinal scorer over a compact block (ops/ranking.cardinal_scores16):
 # stats + normalize + shifted sum + tf + flags. XLA (no-authority trace):
-# 529 flops/row, constant over n in [4k, 131k]
-_CARDINAL_FLOPS_ROW = 529.0
-# + fused lax.top_k (score_topk16): 544 per row at serving k's
-_TOPK16_FLOPS_ROW = 544.0
-# int32 twin (score_topk): 456 flops/row (no int16 widening ops)
-_TOPK32_FLOPS_ROW = 456.0
+# 635 flops/row, constant over n in [4k, 131k]
+_CARDINAL_FLOPS_ROW = 635.0
+# + fused lax.top_k (score_topk16): 647-652 per row at serving k's
+_TOPK16_FLOPS_ROW = 650.0
+# int32 twin (score_topk): 560 flops/row (no int16 widening ops)
+_TOPK32_FLOPS_ROW = 560.0
 # scan_score_topk loop body (stats precomputed; score + merge per tile)
-_SCAN_FLOPS_ROW = 439.0
+_SCAN_FLOPS_ROW = 544.0
 # streaming stats pass (ops/ranking.local_stats, no host counts)
-_STATS_FLOPS_ROW = 113.0
+_STATS_FLOPS_ROW = 261.0
 # devstore streamed spans kernel: stats + score passes per tile plus the
-# constraint mask; each span's fori body counts once: 673 flops per
+# constraint mask; each span's fori body counts once: 927 flops per
 # (span, TILE-row)
-_SPANS_FLOPS_ROW = 673.0
+_SPANS_FLOPS_ROW = 927.0
 # b=1 vmapped pruned kernel: one scored tile per slot; vmap (unlike
-# lax.map) scales the count with bs: 453 flops/row
-_PRUNED1_FLOPS_ROW = 453.0
+# lax.map) scales the count with bs: 559 flops/row
+_PRUNED1_FLOPS_ROW = 559.0
 # pruned escalation kernel body (lax.map slot × fori tile, counted once)
-_PRUNEDB_FLOPS_ROW = 449.0
+_PRUNEDB_FLOPS_ROW = 555.0
 # sort-merge join: fit over (r, m) at n_inc=1/n_exc=0, bs=1:
-# flops = 560·r + 34·m
-_JOIN_FLOPS_R, _JOIN_FLOPS_M = 560.0, 34.0
-# bitmap-membership join: 607 flops/row·slot
-_JOINBM_FLOPS_ROW = 607.0
+# flops = 656·r + 42·m
+_JOIN_FLOPS_R, _JOIN_FLOPS_M = 656.0, 42.0
+# bitmap-membership join: 710 flops/row·slot
+_JOINBM_FLOPS_ROW = 710.0
 # a join that compacts (index/devstore._join_bucket gives the caller its
 # bucket) runs its later memberships over a bucket of the first one's
 # survivors: the sort that packs them and the fold of what they found
@@ -246,7 +250,7 @@ def _c_rank_spans(rows: int, n_spans: int = 8, k: int = 16,
     tiles). The cross-check shape is rows = n_spans × TILE (one fori
     step per unrolled span slot). `with_stats_pass=False` models the
     cached-ext-stats twin: pass 1 skipped, half the streamed reads
-    (673 = 113 stats + 560 score per row — the coefficients compose)."""
+    (927 = 261 stats + 666 score per row — the coefficients compose)."""
     if with_stats_pass:
         flops, passes = _SPANS_FLOPS_ROW, 2
     else:
@@ -379,8 +383,8 @@ def _c_rerank_fwd_batch(bs: int = 16, nb: int = 128,
 # that follows arena capacity and the host's XLA build, not the work.
 # Lowered, the gather's charge is 1 per arena word (0.2-0.5% of the
 # pinned shapes) and is left out.
-_PRUNED1_BP_FLOPS_ROW = 890.0
-_SCAN_BP_FLOPS_ROW = 1491.0
+_PRUNED1_BP_FLOPS_ROW = 1018.0
+_SCAN_BP_FLOPS_ROW = 1713.0
 
 
 def _c_rank_pruned_batch1_bp(bs: int, tile: int = 32_768, maxt: int = 64,

@@ -485,6 +485,10 @@ def prometheus_text(sb, include_buckets: bool = True,
                 "join_partners", "join_multi_served", "join_compacted",
                 "join_survivors", "join_compact_rows",
                 "join_compact_overflow", "join_fallbacks",
+                # rows of frozen runs packed into the arena, and of runs
+                # its budget refused (host-served: must stay 0 where the
+                # index is meant to be resident)
+                "arena_rows_packed", "arena_rows_skipped",
                 "batch_dispatches", "batch_exceptions",
                 "batch_ineligible", "prune_rounds",
                 # kernel shapes the start-up prewarm could not compile
@@ -519,6 +523,12 @@ def prometheus_text(sb, include_buckets: bool = True,
              "membership modes) dispatched since the serving arena was "
              "built: a compile family each")
     p.sample("yacy_devstore_join_shapes", c.get("join_shapes", 0))
+    p.family("yacy_devstore_arena_rows", "gauge",
+             "rows of the serving arena: its capacity (growth doubles "
+             "it) and the rows in use")
+    for state in ("capacity", "used"):
+        p.sample("yacy_devstore_arena_rows",
+                 c.get(f"arena_{state}_rows", 0), {"state": state})
     # HBM accounting for the fleet (ISSUE 8 satellite): per-tier byte
     # occupancy and the promotion/demotion flow — always emitted (zeros
     # without a devstore) so the fleet digest's tier fields and any

@@ -638,6 +638,8 @@ def device_store(header, post, sb):
             ("join_bitmap_refused", c["join_bitmap_refused"]),
             ("arena_rows_used", ds.arena.used_rows),
             ("arena_rows_capacity", ds.arena.capacity_rows),
+            ("arena_rows_packed", c["arena_rows_packed"]),
+            ("arena_rows_skipped", c["arena_rows_skipped"]),
             ("arena_bytes", ds.arena.bytes_used()),
             ("live_rows", ds.live_rows()),
             ("prune_rounds", ds.prune_rounds),

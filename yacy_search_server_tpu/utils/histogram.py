@@ -440,12 +440,13 @@ WRAPPER_FAMILIES = frozenset({"switchboard.search", "servlet.serving",
 # trace-root / segment-root families (they cover their children)
 ROOT_PREFIXES = ("servlet.", "peer.", "pipeline.")
 # background-workload families (crawl fetches, DHT shipping, per-doc
-# indexing): real walls, but they must never decide a SERVING latency
-# verdict — the trace-ring summary they replace only ever saw serving
+# indexing, the device arena's growth and writes): real walls, but they
+# must never decide a SERVING latency verdict — the trace-ring summary
+# they replace only ever saw serving
 # traces, and a multi-second crawl fetch would otherwise headline the
 # Performance_Trace_p stage table of a node that merely crawls
 BACKGROUND_PREFIXES = ("index.", "pipeline.", "crawler.", "crawl.",
-                       "dht.", "ingest.")
+                       "dht.", "ingest.", "arena.")
 
 
 def stage_table(exclude_prefixes: tuple = BACKGROUND_PREFIXES) -> dict:

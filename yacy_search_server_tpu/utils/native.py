@@ -247,6 +247,26 @@ def alive_mask(docids: np.ndarray, dead_sorted: np.ndarray) -> np.ndarray | None
     return out.view(bool)
 
 
+def cardinal_scores(feats: np.ndarray, consts: np.ndarray,
+                    language_pref: int) -> np.ndarray | None:
+    """int64 [n] scores of a block, as cardinal_topk scores it, with no
+    order; the call lets go of the interpreter lock (a pack-time block of
+    millions of rows, where other threads have work meanwhile). None when
+    the library is unavailable."""
+    lib = load()
+    if lib is None:
+        return None
+    f = _as_i32(feats)
+    n, nf = f.shape
+    if len(consts) != 10 + 2 * nf + 2 * int(consts[9]):
+        return None
+    scores = np.empty(n, dtype=np.int64)
+    rc = lib.ytn_cardinal_scores(
+        f.ctypes.data_as(_i32p), n, nf, consts.ctypes.data_as(_i32p),
+        language_pref, 0, scores.ctypes.data_as(_i64p), None)
+    return scores if rc == 0 else None
+
+
 def cardinal_topk(feats: np.ndarray, consts: np.ndarray, language_pref: int,
                   k: int) -> tuple[np.ndarray, np.ndarray] | None:
     """(int64 [n] scores, int64 [min(k, n)] best-first row order) of a
