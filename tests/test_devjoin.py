@@ -412,7 +412,8 @@ def _compact_deltas(ds, before):
     c = ds.counters()
     return {k: c[k] - before[k] for k in (
         "join_compacted", "join_survivors", "join_compact_rows",
-        "join_compact_overflow", "join_multi_served")}
+        "join_compact_overflow", "join_multi_served",
+        "join_onegather_served")}
 
 
 PROGRESSIVE = [
@@ -451,7 +452,8 @@ def test_progressive_join_parity(seg_many, inc, exc):
         "join_compacted": int(bool(bucket)), "join_survivors": n,
         "join_compact_rows": RARE_ROWS if bucket else 0,
         "join_compact_overflow": int(bool(bucket) and n > bucket),
-        "join_multi_served": int(len(inc) >= 3)}
+        "join_multi_served": int(len(inc) >= 3),
+        "join_onegather_served": int(len(inc) >= 3)}
 
 
 def test_an_abandoned_wave_counts_its_join_once(monkeypatch):
@@ -563,3 +565,255 @@ def test_a_wave_of_four_with_unequal_survivors(seg_many, monkeypatch):
         assert n1.tolist() == [solo[i]]
         np.testing.assert_array_equal(s[i], s1[0])
         np.testing.assert_array_equal(d[i], d1[0])
+
+
+# -- the payload read: a program of two include partners or more reads a
+# partner's position, hit count and flags in ONE gather of its payload
+# row (DeviceArena's jpay, beside the join side table) -----------------------
+#
+# Each answer is held to the program as it was before the payload read
+# and the compaction: every membership over the whole window, each
+# partner read through jpos and the arena's columns (the program a join
+# of one partner still runs), traced afresh here with both rules turned
+# off. Scores and docids are compared as the program returns them, pad
+# lanes included.
+
+
+def _hashes(words):
+    return [word2hash(t) for t in words]
+
+
+@pytest.fixture()
+def spied(monkeypatch):
+    """[(program, args, kw, output)] of every packed join program the
+    test runs."""
+    from yacy_search_server_tpu.index import devstore as DS
+    calls = []
+    for name in ("_rank_join_bm_batch_packed_kernel",
+                 "_rank_join_batch_packed_kernel"):
+        def spy(*args, real=getattr(DS, name), **kw):
+            out = real(*args, **kw)
+            calls.append((real, args, kw, np.asarray(out)))
+            return out
+        monkeypatch.setattr(DS, name, spy)
+    return calls
+
+
+def _one_phase_rows(args, kw, jpos):
+    """(scores, docids) a row of the wave `args` / `kw` (a packed join
+    program's call) as the one-phase program over jpos answers it."""
+    import jax
+    from yacy_search_server_tpu.index import devstore as DS
+    bm = "inc_bm" in kw
+    arrays = args[:5] + (jpos,)
+    bmtab = args[6] if bm else None
+    q = args[7] if bm else args[6]
+    consts = args[8:] if bm else args[7:]
+
+    def run(arrays, bmtab, q, consts):
+        return jax.vmap(lambda one: DS._join_topk(
+            *arrays, one, *consts, **kw, bmtab=bmtab))(q)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(DS, "_join_bucket", lambda r, n_inc, n_exc: 0)
+        mp.setattr(DS, "_join_partner_read", lambda n_inc: "rows")
+        s, d = jax.jit(run)(arrays, bmtab, q, consts)
+    return np.asarray(s), np.asarray(d)
+
+
+def _assert_as_one_phase(ds, call, wave=None):
+    """The program's answer to `call` (or to the rows `wave` stacked
+    into its qargs) equals the one-phase program's over jpos; returns
+    the survivors, None where the program does not compact."""
+    from yacy_search_server_tpu.index import devstore as DS
+    real, args, kw, out = call
+    _jd, jpos, jpay = ds.arena.join_arrays()
+    assert args[5] is jpay
+    if wave is not None:
+        qpos = 7 if "inc_bm" in kw else 6
+        args = args[:qpos] + (wave,) + args[qpos + 1:]
+        out = np.asarray(real(*args, **kw))
+    bucket = DS._join_bucket(kw["r"], kw["n_inc"], kw["n_exc"])
+    s, d, survivors = DS._split_join_out(out, bucket)
+    rs, rd = _one_phase_rows(args, kw, jpos)
+    np.testing.assert_array_equal(s, rs)
+    np.testing.assert_array_equal(d, rd)
+    return survivors
+
+
+PAYLOAD = [
+    # (includes in word order, excludes, language filter): 2 to 6
+    # include partners
+    (["p50", "rr", "p12"], [], None),
+    (["rr", "p90", "p80"], ["x30"], None),
+    (["p80", "rr", "p90", "p50"], [], "de"),
+    (["p25", "p90", "rr", "p80", "p50"], ["p12"], None),
+    (["p90", "p50", "p80", "p25", "p12", "rr"], ["x30"], "en"),
+    (["p95", "p90", "p50", "p80", "p25", "p12", "rr"], [], None),
+    (["p50", "dz", "rr", "p12"], [], None),      # 0 live after the first
+]
+
+
+@pytest.mark.parametrize("inc,exc,lang", PAYLOAD,
+                         ids=["-".join(i) + ("~" + "-".join(e) if e else "")
+                              + (f"@{g}" if g else "")
+                              for i, e, g in PAYLOAD])
+def test_payload_read_parity(seg_many, spied, monkeypatch, inc, exc, lang):
+    """Solo, on bitmaps, on the sort-merge and both, with and without
+    tombstones (the fixture's six), excludes and a language filter."""
+    seg, _docids, _dead = seg_many
+    ds = seg.devstore
+    monkeypatch.setattr(ds, "MAX_JOIN_TERMS", 7)     # six partners
+    c0 = ds.counters()
+    if lang is None:
+        _assert_join_matches(seg, _hashes(inc), _hashes(exc))
+    else:
+        assert ds.rank_join(_hashes(inc), _hashes(exc), RankingProfile(),
+                            "en", k=50,
+                            lang_filter=P.pack_language(lang)) is not None
+    assert len(spied) == 1 and spied[0][2]["n_inc"] == len(inc) - 1
+    _assert_as_one_phase(ds, spied[0])
+    c = ds.counters()
+    assert (c["join_onegather_served"] - c0["join_onegather_served"]
+            == c["join_multi_served"] - c0["join_multi_served"] == 1)
+
+
+WAVES = {
+    # one compile shape a wave (all-bitmap: inc_ms are all 0)
+    "2x2": [["rr", "p12", "p50"], ["rr", "p80", "p90"]],
+    "2x3": WAVE[:2],
+    "4x3": WAVE,            # ~500, ~3,200, 0 and ~1,000 live
+}
+
+
+@pytest.mark.parametrize("seg_many", [("bitmap", False), ("bitmap", True)],
+                         indirect=True, ids=["bitmap", "bitmap-dead"])
+@pytest.mark.parametrize("wave", list(WAVES))
+def test_payload_wave_parity(seg_many, spied, wave):
+    """Waves of two and four under vmap, each row over its own number
+    of buckets where the program compacts."""
+    seg, docids, dead = seg_many
+    ds = seg.devstore
+    for inc in WAVES[wave]:
+        _assert_join_matches(seg, _hashes(inc), [])
+    assert len(spied) == len(WAVES[wave])
+    kw = spied[0][2]
+    assert all(call[2] == kw for call in spied)
+    qpos = 7
+    rows = np.concatenate([call[1][qpos] for call in spied])
+    survivors = _assert_as_one_phase(ds, spied[0], wave=rows)
+    if survivors is not None:
+        want = [_first_survivors(docids, inc, [], dead)
+                for inc in WAVES[wave]]
+        assert survivors.tolist() == want and len(set(want)) == len(want)
+
+
+@pytest.mark.parametrize("inc,exc,side", [
+    (["p80", "rr"], [], "jpos"), (["rr"], ["x30", "p12"], "jpos"),
+    (["rr", "p90"], ["p12"], "jpos"), (["rr", "p80", "p90"], [], "jpay")])
+def test_a_program_takes_the_side_table_its_partner_count_names(
+        seg_many, spied, inc, exc, side):
+    """A one-partner program (or none: excludes only) takes jpos, a
+    program of two partners jpay, and either refuses the other."""
+    from yacy_search_server_tpu.index import devstore as DS
+    seg, _docids, _dead = seg_many
+    ds = seg.devstore
+    _assert_join_matches(seg, _hashes(inc), _hashes(exc))
+    real, args, kw, _out = spied[-1]
+    _jd, jpos, jpay = ds.arena.join_arrays()
+    assert DS._join_partner_read(kw["n_inc"]) == (
+        "payload" if side == "jpay" else "rows")
+    assert args[5] is (jpay if side == "jpay" else jpos)
+    wrong = jpos if side == "jpay" else jpay
+    with pytest.raises(ValueError, match="include partners takes"):
+        real(*args[:5], wrong, *args[6:], **kw)
+
+
+def _assert_payload_rows(ds):
+    """The invariant the payload read rests on, on the host copy:
+    jpay[p] is (position, hit count, flags) of arena row jpos[p] at
+    every position the side table has handed out."""
+    from yacy_search_server_tpu.index import devstore as DS
+    arena = ds.arena
+    n = arena._jused
+    _jd, jpos, jpay = (np.asarray(a)[:n] for a in arena.join_arrays())
+    f16, fl, _dd = (np.asarray(a) for a in arena.arrays())
+    pos, hit, flags = DS._unpack_join_payload(jpay)
+    assert n > 0 and jpay.shape == (n, 2)
+    np.testing.assert_array_equal(pos, f16[jpos, P.F_POSINTEXT])
+    np.testing.assert_array_equal(hit, f16[jpos, P.F_HITCOUNT])
+    np.testing.assert_array_equal(flags, fl[jpos])
+
+
+def _extreme_plist(rng, n, id_pool):
+    """_plist with the payload's extremes among its rows: the largest
+    position and hit count (and past them, which the arena clips), the
+    smallest, and flags with every bit."""
+    pl = _plist(rng, n, id_pool)
+    f = pl.feats
+    f[:8, P.F_POSINTEXT] = [32767, 40000, 0, 1, 32767, 2, 32766, 0]
+    f[:8, P.F_HITCOUNT] = [32767, 1, 70000, 0, 32767, 32766, 1, 0]
+    f[:33, P.F_FLAGS] = [-1] + [np.int32(np.uint32(1 << b).view(np.int32))
+                                for b in range(32)]
+    return pl
+
+
+def test_payload_rows_after_growth_and_repack(monkeypatch, spied):
+    """Runs packed one after another grow the side table (a pad and a
+    write of each of its three arrays); a repack rebuilds it. After
+    each, jpay agrees with the arena row by row, and joins of bitmap and
+    sort-merge partners from both runs answer as the host join does and
+    as the one-phase program over jpos does."""
+    from yacy_search_server_tpu.index.devstore import DeviceSegmentStore
+    monkeypatch.setattr(DeviceSegmentStore, "JOIN_BITMAP_MIN", 15_000)
+    seg = Segment(max_ram_postings=10)
+    rng = np.random.default_rng(43)
+    pool = np.arange(40_000)
+    seg.rwi.ingest_run({word2hash("rr"): _extreme_plist(rng, 4_000, pool),
+                        word2hash("p50"): _plist(rng, 20_000, pool)})
+    seg.enable_device_serving()
+    ds = seg.devstore
+    try:
+        cap0 = ds.arena._jcap
+        seg.rwi.ingest_run({word2hash("p80"): _extreme_plist(rng, 32_000,
+                                                             pool),
+                            word2hash("p12"): _plist(rng, 5_000, pool)})
+        assert ds.arena._jcap > cap0
+        for step in ("grown", "repacked"):
+            if step == "repacked":
+                ds.repack()
+            _assert_payload_rows(ds)
+            # two bitmap partners; then a sort-merge one beside them
+            for inc, modes in ((["rr", "p50", "p80"], {True}),
+                               (["p12", "rr", "p80", "p50"], {True, False})):
+                spied.clear()
+                _assert_join_matches(seg, _hashes(inc), [])
+                assert len(spied) == 1
+                assert set(spied[0][2]["inc_bm"]) == modes, step
+                _assert_as_one_phase(ds, spied[0])
+    finally:
+        seg.close()
+
+
+def test_the_payload_pair_is_exact_at_its_extremes():
+    """Every int16 extreme of position and hit count beside every flag
+    bit packs and unpacks to the integers the arena's columns widen to,
+    on the host and on the device."""
+    import itertools
+
+    import jax.numpy as jnp
+    from yacy_search_server_tpu.index import devstore as DS
+    edge = (-32768, -32767, -1, 0, 1, 32766, 32767)
+    flag = [0, -1] + [int(np.uint32(1 << b).view(np.int32))
+                      for b in range(32)]
+    pos, hit, flags = (np.asarray(c) for c in zip(
+        *itertools.product(edge, edge, flag)))
+    pos16, hit16 = pos.astype(np.int16), hit.astype(np.int16)
+    pay = DS._pack_join_payload(pos16, hit16, flags.astype(np.int32))
+    assert pay.dtype == np.int32 and pay.shape == (len(pos), 2)
+    for got in (DS._unpack_join_payload(pay),
+                DS._unpack_join_payload(jnp.asarray(pay))):
+        got = [np.asarray(g) for g in got]
+        assert all(g.dtype == np.int32 for g in got)
+        np.testing.assert_array_equal(got[0], pos16.astype(np.int32))
+        np.testing.assert_array_equal(got[1], hit16.astype(np.int32))
+        np.testing.assert_array_equal(got[2], flags)

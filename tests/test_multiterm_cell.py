@@ -128,6 +128,8 @@ def test_a_class_equals_the_reference(node, cls):
         assert d["join_served"] == len(queries)
         assert d["join_partners"] == partners * len(queries)
         assert d["join_multi_served"] == len(queries)
+        # two partners or more: each read in one gather of its payload
+        assert d["join_onegather_served"] == len(queries)
         assert d["join_sm_served"] == (
             len(queries) if mode == "sortmerge" else 0)
         # three partners or more: every later membership over the first
@@ -208,10 +210,12 @@ def test_a_device_join_says_how_many_partners_it_had(node, cls, partners):
     assert batch.attrs["partners"] == partners
     assert batch.attrs["membership"] == (
         "bitmap" if mode == "bitmap" else "sortmerge")
+    assert batch.attrs["partner_read"] == "payload"
     kernel = "kernel." + batch.attrs["kernel"]
     for name in (kernel, "kernel.issue", "kernel.fetch", "kernel.device",
                  "kernel.join_multi"):
         assert by[name][0].attrs["partners"] == partners, name
+        assert by[name][0].attrs["partner_read"] == "payload", name
     # the family of its own beside kernel.<name>: one observation a join
     assert histogram.get("kernel.join_multi").windowed_count() == 1
     assert by["kernel.join_multi"][0].dur_ms == by[kernel][0].dur_ms
@@ -235,6 +239,7 @@ def test_a_device_join_says_how_many_partners_it_had(node, cls, partners):
     compacted = ds.counters()["join_compacted"]
     _rec, by = _trace_of(sb, "zm0 zm4")
     assert by["devstore.batch"][0].attrs["partners"] == 1
+    assert by["devstore.batch"][0].attrs["partner_read"] == "rows"
     assert ds.counters()["join_compacted"] == compacted
     assert "kernel.join_multi" not in by
     assert histogram.get("kernel.join_multi").windowed_count() == 0
@@ -290,19 +295,21 @@ def test_the_partner_counters_are_scraped(node):
     from yacy_search_server_tpu.utils.health import parse_exposition
     c = sb.index.devstore.counters()
     assert c["join_multi_served"] > 0 and c["join_shapes"] > 0
+    assert c["join_onegather_served"] == c["join_multi_served"]
     assert c["join_partners"] >= 2 * c["join_multi_served"]
     samples = parse_exposition(prometheus_text(sb))
     assert 0 < c["join_compacted"] <= c["join_multi_served"]
     assert c["join_survivors"] > 0 and c["join_compact_rows"] > 0
     compact = ("join_compacted", "join_survivors", "join_compact_rows",
                "join_compact_overflow")
-    for key in ("join_partners", "join_multi_served") + compact:
+    for key in ("join_partners", "join_multi_served",
+                "join_onegather_served") + compact:
         assert samples[
             f'yacy_device_serving_total{{counter="{key}"}}'] == c[key]
     assert samples["yacy_devstore_join_shapes"] == c["join_shapes"]
     prop = device_store({}, ServerObjects(), sb)
     rows = {prop.get(f"rows_{i}_key"): prop.get(f"rows_{i}_value")
             for i in range(int(prop.get("rows", 0)))}
-    for key in ("join_partners", "join_multi_served", "join_shapes") \
-            + compact:
+    for key in ("join_partners", "join_multi_served",
+                "join_onegather_served", "join_shapes") + compact:
         assert str(rows[key]) == str(c[key])

@@ -299,6 +299,7 @@ def arena():
         "pmax": jnp.zeros(1 << 12, jnp.int32),
         "jd": jnp.full(1 << 17, 2 ** 31 - 1, jnp.int32),
         "jp": jnp.zeros(1 << 17, jnp.int32),
+        "jy": jnp.zeros((1 << 17, 2), jnp.int32),
         "bmtab": jnp.zeros((2, 1 << 15, 2), jnp.int32),
     }
 
@@ -404,6 +405,42 @@ def test_xla_rank_join_bm(arena, r, bs):
     _close(c.flops, flops, f"join_bm[{r},{bs}] flops")
 
 
+def _join_side(arena, n_inc):
+    """The side table the join program of `n_inc` include partners
+    takes, and whether it is the payload table."""
+    from yacy_search_server_tpu.index import devstore as DS
+    payload = DS._join_partner_read(n_inc) == "payload"
+    return arena["jy"] if payload else arena["jp"], payload
+
+
+# a join of two include partners reads each partner's payload row: one
+# 8 B gather a lane in place of jpos and three columns (12 B)
+@pytest.mark.parametrize("r,bs,n_inc,n_exc", ((65536, 1, 2, 0),
+                                              (131072, 1, 2, 0),
+                                              (65536, 4, 2, 0),
+                                              (65536, 1, 2, 1)))
+def test_xla_rank_join_bm_payload(arena, r, bs, n_inc, n_exc):
+    from yacy_search_server_tpu.index import devstore as DS
+    n = n_inc + n_exc
+    side, payload = _join_side(arena, n_inc)
+    assert payload and side.ndim == 2
+    assert DS._join_bucket(r, n_inc, n_exc) == 0
+    qargs = np.zeros((bs, 6 + 3 * n), np.int32)
+    flops = _xla(DS._rank_join_bm_batch_kernel, arena["f16"],
+                 arena["fl"], arena["dd"], arena["dead"],
+                 arena["jd"], side, arena["bmtab"], qargs,
+                 *_consts(), k=16, n_inc=n_inc, n_exc=n_exc, r=r,
+                 inc_ms=(0,) * n_inc, exc_ms=(0,) * n_exc,
+                 inc_bm=(True,) * n_inc, exc_bm=(True,) * n_exc)
+    c = RF.cost("_rank_join_bm_batch_kernel", r=r, n_inc=n_inc,
+                n_exc=n_exc, bs=bs, k=16, payload=True)
+    _close(c.flops, flops, f"join_bm payload[{r},{bs},{n_inc},{n_exc}] "
+                           "flops")
+    rows = RF.cost("_rank_join_bm_batch_kernel", r=r, n_inc=n_inc,
+                   n_exc=n_exc, bs=bs, k=16)
+    assert rows.bytes - c.bytes == bs * 4 * n * r
+
+
 # a join that compacts: the later memberships run over a bucket of r/4
 # lanes inside a loop whose body XLA counts once — the one-bucket cost
 @pytest.mark.parametrize("r,bs,n_inc,n_exc", ((65536, 1, 3, 0),
@@ -414,22 +451,26 @@ def test_xla_rank_join_bm(arena, r, bs):
 def test_xla_rank_join_bm_compacted(arena, r, bs, n_inc, n_exc):
     from yacy_search_server_tpu.index import devstore as DS
     n = n_inc + n_exc
+    side, payload = _join_side(arena, n_inc)
+    assert payload
     qargs = np.zeros((bs, 6 + 3 * n), np.int32)
     flops = _xla(DS._rank_join_bm_batch_kernel, arena["f16"],
                  arena["fl"], arena["dd"], arena["dead"],
-                 arena["jd"], arena["jp"], arena["bmtab"], qargs,
+                 arena["jd"], side, arena["bmtab"], qargs,
                  *_consts(), k=16, n_inc=n_inc, n_exc=n_exc, r=r,
                  inc_ms=(0,) * n_inc, exc_ms=(0,) * n_exc,
                  inc_bm=(True,) * n_inc, exc_bm=(True,) * n_exc)
     bucket = DS._join_bucket(r, n_inc, n_exc)
     assert bucket == r // 4
     c = RF.cost("_rank_join_bm_batch_kernel", r=r, n_inc=n_inc,
-                n_exc=n_exc, bs=bs, k=16, bucket=bucket)
+                n_exc=n_exc, bs=bs, k=16, bucket=bucket, payload=payload)
     _close(c.flops, flops, f"join_bm[{r},{bs},{n_inc},{n_exc}] flops")
     # the later memberships' bytes are the bucket's, not the window's;
-    # the sort that packs it reads and writes the window's keys
+    # the sort that packs it reads and writes the window's keys; a
+    # partner's lane is its bitmap row and its payload row (16 B) where
+    # the one-partner program reads 20
     one = RF.cost("_rank_join_bm_batch_kernel", r=r, n_inc=1, bs=bs, k=16)
-    assert c.bytes - one.bytes == bs * (16 * r + (20 * (n - 1) + 28)
+    assert c.bytes - one.bytes == bs * (12 * r + (16 * (n - 1) + 28)
                                         * bucket)
 
 
@@ -437,15 +478,23 @@ def test_xla_rank_join_bm_compacted(arena, r, bs, n_inc, n_exc):
                                        (131072, 65536, 4)))
 def test_xla_rank_join_compacted(arena, r, m, n_inc):
     from yacy_search_server_tpu.index import devstore as DS
+    side, payload = _join_side(arena, n_inc)
+    bucket = DS._join_bucket(r, n_inc, 0)
     qargs = np.zeros((1, 6 + 3 * n_inc), np.int32)
     flops = _xla(DS._rank_join_batch_kernel, arena["f16"],
                  arena["fl"], arena["dd"], arena["dead"],
-                 arena["jd"], arena["jp"], qargs, *_consts(),
+                 arena["jd"], side, qargs, *_consts(),
                  k=16, n_inc=n_inc, n_exc=0, r=r, inc_ms=(m,) * n_inc,
                  exc_ms=())
     c = RF.cost("_rank_join_batch_kernel", r=r, m=m, n_inc=n_inc, n_exc=0,
-                bs=1, k=16, bucket=DS._join_bucket(r, n_inc, 0))
+                bs=1, k=16, bucket=bucket, payload=payload)
     _close(c.flops, flops, f"join[{r},{m},{n_inc}] compacted flops")
+    # the sort carries the side-table position it makes: its segment
+    # streams docids alone, and a lane gathers one 8 B payload row
+    rows = RF.cost("_rank_join_batch_kernel", r=r, m=m, n_inc=n_inc,
+                   n_exc=0, bs=1, k=16, bucket=bucket)
+    assert rows.bytes - c.bytes == (4 * r + 4 * (n_inc - 1) * bucket
+                                    + 4 * n_inc * m)
 
 
 @pytest.mark.parametrize("k", (16, 128))

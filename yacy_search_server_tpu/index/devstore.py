@@ -288,7 +288,7 @@ def _pmax_window(max_tcount: int) -> int:
 
 def _emit_rt_spans(issue_ms: float, fetch_ms: float,
                    device_ms: float = 0.0,
-                   kernel: str | None = None) -> None:
+                   kernel: str | None = None, **attrs) -> None:
     """Record the issue/device/fetch round-trip decomposition of a SOLO
     dispatch: child spans under the active trace, the families alone
     outside one (`tracing.record` does either) — the kernel-stage
@@ -298,13 +298,14 @@ def _emit_rt_spans(issue_ms: float, fetch_ms: float,
     path stamps a real in-flight window (see _QueryBatcher._complete).
     `kernel` (the mesh store's solo SPMD programs): the dispatch's whole
     wall, issue to fetched, also lands in the family `kernel.<kernel>`,
-    so a reader can tell one program's walls from another's."""
-    tracing.record("kernel.issue", issue_ms)
-    tracing.record("kernel.device", device_ms)
-    tracing.record("kernel.fetch", fetch_ms)
+    so a reader can tell one program's walls from another's. `attrs`
+    (a conjunction's `partners`, `partner_read`) go on every span."""
+    tracing.record("kernel.issue", issue_ms, **attrs)
+    tracing.record("kernel.device", device_ms, **attrs)
+    tracing.record("kernel.fetch", fetch_ms, **attrs)
     if kernel is not None:
         tracing.record(f"kernel.{kernel}",
-                       issue_ms + device_ms + fetch_ms)
+                       issue_ms + device_ms + fetch_ms, **attrs)
 
 
 # the wakes a batched query rode, set as attrs on its `devstore.batch`
@@ -625,11 +626,14 @@ def _membership_sorted(jdocids, jpos, lo, m, targets, a_valid,
     Tag trick: sort keys docid*2 for targets (A) and docid*2+1 for the
     segment (B); ties order A immediately before its matching B, so a
     shifted equality compare yields membership and the co-sorted payload
-    carries the partner's arena row. Results scatter back to A order.
+    carries the partner's arena row (jpos), or with jpos None its
+    position in the side table, where its payload row is. Results
+    scatter back to A order.
     Returns (found[r] bool, partner_row[r] int32)."""
     r = targets.shape[0]
     bd = lax.dynamic_slice(jdocids, (lo,), (m,))
-    bp = lax.dynamic_slice(jpos, (lo,), (m,))
+    bp = (lo + jnp.arange(m, dtype=jnp.int32) if jpos is None
+          else lax.dynamic_slice(jpos, (lo,), (m,)))
     # mask rows past the segment's true length: the static window may
     # overrun into the NEXT term's sorted segment (append padding is per
     # run, not per term), and those rows hold real docids
@@ -666,14 +670,15 @@ def _popc32(x):
             * jnp.uint32(0x01010101) >> 24).astype(jnp.int32)
 
 
-def _membership_bitmap(bmtab, slot, jpos, jstart, targets):
-    """Membership + partner-row lookup via the term's docid bitmap: 2
-    gathers per lane (one interleaved (word, prefix) row, one jpos row)
-    instead of a sort over the partner's whole segment. The sort-merge
-    pays O(r + m); this pays O(r) — the size-adaptive join direction
-    (the reference picks the small side to iterate at
-    ReferenceContainer.java:397-489; here the small side is always the
-    rare span and the big side is a precomputed bitmap).
+def _membership_bitmap(bmtab, slot, jside, jstart, targets):
+    """Membership + partner lookup via the term's docid bitmap: one
+    gather per lane of an interleaved (word, prefix) row, then one of the
+    side table `jside` at the rank it gives, instead of a sort over the
+    partner's whole segment. The sort-merge pays O(r + m); this pays
+    O(r) — the size-adaptive join direction (the reference picks the
+    small side to iterate at ReferenceContainer.java:397-489; here the
+    small side is always the rare span and the big side is a precomputed
+    bitmap).
 
     Rank recovery: prefix[word] (set bits before this word in the
     term's segment) + popcount(word & below-bit mask) is the target's
@@ -681,7 +686,11 @@ def _membership_bitmap(bmtab, slot, jpos, jstart, targets):
     same absolute arena row the sort-merge path returns — bit-parity by
     construction. Docids past the bitmap's coverage cannot be in the
     segment (coverage >= the segment's max docid at build time), so
-    out-of-range lanes are correctly "not found"."""
+    out-of-range lanes are correctly "not found".
+
+    `jside` is jpos (returns the arena row, 0 where not found) or the
+    payload table jpay (returns the side-table position itself: the
+    payload row is the one gather left)."""
     nbits = bmtab.shape[1] * 32
     t = jnp.clip(targets, 0, nbits - 1)
     row = lax.dynamic_index_in_dim(bmtab, slot, axis=0, keepdims=False)
@@ -691,8 +700,10 @@ def _membership_bitmap(bmtab, slot, jpos, jstart, targets):
     found = (((w >> sh) & 1) == 1) & (targets >= 0) & (targets < nbits)
     below = w & ((jnp.uint32(1) << sh) - jnp.uint32(1))
     rank = wp[:, 1] + _popc32(below)
-    p = jnp.clip(jstart + rank, 0, jpos.shape[0] - 1)
-    prow = jnp.where(found, jpos[p], 0)
+    p = jnp.clip(jstart + rank, 0, jside.shape[0] - 1)
+    if jside.ndim == 2:
+        return found, p
+    prow = jnp.where(found, jside[p], 0)
     return found, prow
 
 
@@ -710,7 +721,43 @@ def _join_bucket(r: int, n_inc: int, n_exc: int) -> int:
     return max(r // 4, 1) if n_inc >= 3 else 0
 
 
-def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
+def _join_partner_read(n_inc: int) -> str:
+    """How a join program of `n_inc` include partners reads a partner's
+    three merge columns (position, hit count, flags) once its membership
+    found it. "payload" where it has two include partners or more: ONE
+    gather a lane of the partner's payload row (DeviceArena's jpay,
+    co-indexed with the join side table) at the side-table position the
+    membership gives. "rows" for the rest: the partner's arena row from
+    jpos, then its three columns, two of them from whole-column views.
+    Both give the same integers, so the answer is the same bit for bit;
+    a one-partner program keeps the rows read, and its text, because
+    relieving those programs moved wiki.tasks' median onto the host
+    (PERF.md 7.21(d)). Its call site hands the program jpay in jpos's
+    place for the payload read."""
+    return "payload" if n_inc >= 2 else "rows"
+
+
+def _pack_join_payload(pos16, hit16, flags) -> np.ndarray:
+    """Payload rows of a join side table, (n, 2) int32: a partner row's
+    F_POSINTEXT in the low half of the first word and its F_HITCOUNT in
+    the high half (both int16, so the pair is exact), its flags in the
+    second word. Interleaved as bmtab is, so one row gather serves all
+    three; _unpack_join_payload reads it back."""
+    out = np.empty((len(pos16), 2), np.int32)
+    out[:, 0] = ((np.asarray(pos16).astype(np.int32) & 0xFFFF)
+                 | (np.asarray(hit16).astype(np.int32) << 16))
+    out[:, 1] = flags
+    return out
+
+
+def _unpack_join_payload(pay):
+    """(position, hit count, flags) of gathered payload rows, int32: the
+    int16 halves sign-extended as the arena's columns widen them."""
+    w = pay[:, 0]
+    return (w << 16) >> 16, w >> 16, pay[:, 1]
+
+
+def _join_topk(feats16, flags, docids, dead, jdocids, jside,
                qargs,
                norm_coeffs, flag_bits, flag_shifts,
                domlength_coeff, tf_coeff, language_coeff,
@@ -749,7 +796,17 @@ def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
     membership mode is static (`inc_bm`/`exc_bm`): True rides the
     bitmap (2 gathers/lane, r-bounded), False the sort-merge
     (r+m sort) — the TPU form of the reference's size-adaptive join.
+
+    `jside` is the join side table's jpos, or where the program reads
+    its partners' payload (_join_partner_read: two include partners or
+    more) its jpay: a partner's position, hit count and flags then come
+    from one payload row at the side-table position its membership
+    found, and no arena column is read for it.
     """
+    payload = _join_partner_read(n_inc) == "payload"
+    if jside.ndim != (2 if payload else 1):
+        raise ValueError(f"a join of {n_inc} include partners takes "
+                         f"{'jpay' if payload else 'jpos'}")
     start, count = qargs[0], qargs[1]
     lang_filter, flag_bit = qargs[2], qargs[3]
     from_days, to_days = qargs[4], qargs[5]
@@ -766,12 +823,14 @@ def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
     pos_max = f[:, P.F_POSINTEXT]
     hit_min = f[:, P.F_HITCOUNT]
     flags_or = fl
-    # merge uses exactly TWO partner feature columns; gathering them from
-    # column views instead of whole (NF,) rows cuts the random-HBM
-    # payload per lane ~4x (34 B -> 8 B incl. flags) — the join is
-    # gather-bandwidth-bound at 1M-lane rare spans (r5 mix profile)
-    pos_col = feats16[:, P.F_POSINTEXT]
-    hit_col = feats16[:, P.F_HITCOUNT]
+    if not payload:
+        # merge uses exactly TWO partner feature columns; gathering them
+        # from column views instead of whole (NF,) rows cuts the
+        # random-HBM payload per lane ~4x (34 B -> 8 B incl. flags) —
+        # the join is gather-bandwidth-bound at 1M-lane rare spans (r5
+        # mix profile). Each view is a copy of a whole arena column
+        pos_col = feats16[:, P.F_POSINTEXT]
+        hit_col = feats16[:, P.F_HITCOUNT]
 
     def member(j, targets, acc):
         """Membership j (the includes, then the excludes) of `targets`,
@@ -784,13 +843,21 @@ def _join_topk(feats16, flags, docids, dead, jdocids, jpos,
         cnt = qargs[b + n + i]
         if bm[i]:
             slot = qargs[b + 2 * n + i]
-            found, prow = _membership_bitmap(bmtab, slot, jpos, lo, targets)
+            found, prow = _membership_bitmap(bmtab, slot, jside, lo,
+                                             targets)
         else:
-            found, prow = _membership_sorted(jdocids, jpos, lo, ms[i],
-                                             targets, v, cnt)
+            found, prow = _membership_sorted(
+                jdocids, None if payload else jside, lo, ms[i], targets,
+                v, cnt)
         if not inc:
             return v & ~found, pos_min, pos_max, hit_min, flags_or
         v &= found
+        if payload:
+            # prow is the side-table position: one gather, three columns
+            pp, hh, ff = _unpack_join_payload(jside[prow])
+            return (v, jnp.minimum(pos_min, pp), jnp.maximum(pos_max, pp),
+                    jnp.minimum(hit_min, hh),
+                    flags_or | jnp.where(found, ff, 0))
         pp = pos_col[prow].astype(jnp.int32)
         pos_min = jnp.minimum(pos_min, pp)
         pos_max = jnp.maximum(pos_max, pp)
@@ -873,7 +940,7 @@ def _join_later_memberships(acc, dd, member, later, bucket: int):
 
 @partial(jax.jit, static_argnames=("k", "n_inc", "n_exc", "r",
                                    "inc_ms", "exc_ms"))
-def _rank_join_batch_kernel(feats16, flags, docids, dead, jdocids, jpos,
+def _rank_join_batch_kernel(feats16, flags, docids, dead, jdocids, jside,
                             qargs_batch,
                             norm_coeffs, flag_bits, flag_shifts,
                             domlength_coeff, tf_coeff, language_coeff,
@@ -887,10 +954,11 @@ def _rank_join_batch_kernel(feats16, flags, docids, dead, jdocids, jpos,
     bucketed compile shape). vmapped, NOT lax.map: slots run in
     parallel instead of serially on device (device time: not measured
     on the current chip). Transient sort memory is ×bs but bounded by
-    the batch cap (MAX_JOIN_BATCH)."""
+    the batch cap (MAX_JOIN_BATCH). `jside`: jpos, or the payload
+    table jpay where _join_partner_read(n_inc) reads payload rows."""
     def one(q):
         return _join_topk(
-            feats16, flags, docids, dead, jdocids, jpos, q,
+            feats16, flags, docids, dead, jdocids, jside, q,
             norm_coeffs, flag_bits, flag_shifts, domlength_coeff, tf_coeff,
             language_coeff, authority_coeff, language_pref,
             k=k, n_inc=n_inc, n_exc=n_exc, r=r,
@@ -901,7 +969,7 @@ def _rank_join_batch_kernel(feats16, flags, docids, dead, jdocids, jpos,
 
 @partial(jax.jit, static_argnames=("k", "n_inc", "n_exc", "r",
                                    "inc_ms", "exc_ms", "inc_bm", "exc_bm"))
-def _rank_join_bm_batch_kernel(feats16, flags, docids, dead, jdocids, jpos,
+def _rank_join_bm_batch_kernel(feats16, flags, docids, dead, jdocids, jside,
                                bmtab, qargs_batch,
                                norm_coeffs, flag_bits, flag_shifts,
                                domlength_coeff, tf_coeff, language_coeff,
@@ -915,10 +983,10 @@ def _rank_join_bm_batch_kernel(feats16, flags, docids, dead, jdocids, jpos,
     serial slots). When EVERY membership is bitmap-mode the body is pure
     gathers + elementwise work, so the batch vmaps: all slots gather in
     parallel. A mixed batch (some partner too small for a bitmap) also
-    vmaps, like _rank_join_batch_kernel."""
+    vmaps, like _rank_join_batch_kernel; `jside` as there."""
     def one(q):
         return _join_topk(
-            feats16, flags, docids, dead, jdocids, jpos, q,
+            feats16, flags, docids, dead, jdocids, jside, q,
             norm_coeffs, flag_bits, flag_shifts, domlength_coeff, tf_coeff,
             language_coeff, authority_coeff, language_pref,
             k=k, n_inc=n_inc, n_exc=n_exc, r=r,
@@ -1176,7 +1244,7 @@ def _rank_scan_batch_packed_kernel(feats16, flags, docids, dead, qi,
 @partial(jax.jit, static_argnames=("k", "n_inc", "n_exc", "r",
                                    "inc_ms", "exc_ms"))
 def _rank_join_batch_packed_kernel(feats16, flags, docids, dead, jdocids,
-                                   jpos, qargs_batch,
+                                   jside, qargs_batch,
                                    norm_coeffs, flag_bits, flag_shifts,
                                    domlength_coeff, tf_coeff,
                                    language_coeff, authority_coeff,
@@ -1186,7 +1254,7 @@ def _rank_join_batch_packed_kernel(feats16, flags, docids, dead, jdocids,
     """_rank_join_batch_kernel with a packed [bs, 2*min(k,r)] output,
     and one column more, the survivors, where the join compacts."""
     out = _rank_join_batch_kernel(
-        feats16, flags, docids, dead, jdocids, jpos, qargs_batch,
+        feats16, flags, docids, dead, jdocids, jside, qargs_batch,
         norm_coeffs, flag_bits, flag_shifts, domlength_coeff, tf_coeff,
         language_coeff, authority_coeff, language_pref,
         k=k, n_inc=n_inc, n_exc=n_exc, r=r, inc_ms=inc_ms, exc_ms=exc_ms)
@@ -1214,7 +1282,7 @@ def _split_join_out(host, bucket: int):
 @partial(jax.jit, static_argnames=("k", "n_inc", "n_exc", "r",
                                    "inc_ms", "exc_ms", "inc_bm", "exc_bm"))
 def _rank_join_bm_batch_packed_kernel(feats16, flags, docids, dead,
-                                      jdocids, jpos, bmtab, qargs_batch,
+                                      jdocids, jside, bmtab, qargs_batch,
                                       norm_coeffs, flag_bits, flag_shifts,
                                       domlength_coeff, tf_coeff,
                                       language_coeff, authority_coeff,
@@ -1228,7 +1296,7 @@ def _rank_join_bm_batch_packed_kernel(feats16, flags, docids, dead,
     """_rank_join_bm_batch_kernel with the output of
     _rank_join_batch_packed_kernel."""
     out = _rank_join_bm_batch_kernel(
-        feats16, flags, docids, dead, jdocids, jpos, bmtab, qargs_batch,
+        feats16, flags, docids, dead, jdocids, jside, bmtab, qargs_batch,
         norm_coeffs, flag_bits, flag_shifts, domlength_coeff, tf_coeff,
         language_coeff, authority_coeff, language_pref,
         k=k, n_inc=n_inc, n_exc=n_exc, r=r, inc_ms=inc_ms, exc_ms=exc_ms,
@@ -1544,10 +1612,15 @@ class DeviceArena:
         # join side-table: per-span docid-SORTED views (docid + the arena
         # row it lives at) — the device conjunction's lookup structure.
         # Pad slots hold INT32_MAX so binary search stays monotone.
+        # Beside them, co-indexed, the payload rows jpay (that arena
+        # row's position, hit count and flags: _pack_join_payload), which
+        # the programs of two include partners or more read in jpos's
+        # place: 16 B a resident posting in all
         self._jcap = 1 << 12
         self._jused = 0
         self._jdocids = self._dev(np.full(self._jcap, INT32_MAX, np.int32))
         self._jpos = self._dev(np.zeros(self._jcap, np.int32))
+        self._jpay = self._dev(np.zeros((self._jcap, 2), np.int32))
         # join-bitmap side-table: per-BIG-term docid bitmap + rank
         # prefix, interleaved (word, prefix) so ONE row gather serves
         # both (VERDICT r4 #1 — membership in 2 gathers/lane instead of
@@ -1772,18 +1845,23 @@ class DeviceArena:
 
     def _sidetable_write(self, arrays, bufs, used, cap_attr):
         """Shared side-table growth + write (pmax and join tables use the
-        same pad-doubling allocation); returns (new_arrays, start)."""
+        same pad-doubling allocation; a table's rows may be pairs);
+        returns (new_arrays, start). One table at a time, so the copies
+        of a growth and its write are one table's on top of the old."""
         b = len(bufs[0])
-        cap = getattr(self, cap_attr)
+        old = cap = getattr(self, cap_attr)
         while cap < used + b:
-            arrays = [jnp.pad(a, (0, cap), constant_values=f)
-                      for a, f in zip(arrays, self._sidetable_fills)]
             cap *= 2
         setattr(self, cap_attr, cap)
         off = np.int32(used)
-        arrays = [_write_rows1(a, self._dev(buf), off)
-                  for a, buf in zip(arrays, bufs)]
-        return arrays, used
+        out = []
+        for a, buf, fill in zip(arrays, bufs, self._sidetable_fills):
+            if cap != old:
+                a = jnp.pad(a, ((0, cap - old),) + ((0, 0),) * (a.ndim - 1),
+                            constant_values=fill)
+            write = _write_rows1 if a.ndim == 1 else _write_rows2
+            out.append(write(a, self._dev(buf), off))
+        return out, used
 
     def append_pmax(self, pmax: np.ndarray) -> int:
         """Add a span's per-tile bound row to the side-table; returns its
@@ -1800,28 +1878,35 @@ class DeviceArena:
         return start
 
     def append_join_index(self, sorted_docids: np.ndarray,
-                          sorted_pos: np.ndarray) -> int:
-        """Add spans' docid-sorted (docid, arena-row) views; returns the
-        start offset. Caller concatenates per-term segments — each term's
-        segment is internally sorted; offsets address the segments. Pad
-        slots hold INT32_MAX docids (monotone; masked by segment counts
-        on the read side)."""
+                          sorted_pos: np.ndarray,
+                          sorted_pay: np.ndarray) -> int:
+        """Add spans' docid-sorted (docid, arena-row, payload) views;
+        returns the start offset. Caller concatenates per-term segments —
+        each term's segment is internally sorted; offsets address the
+        segments. `sorted_pay[i]` is _pack_join_payload of arena row
+        `sorted_pos[i]`, the invariant the payload read rests on (rows
+        are never rewritten in place, and a repack rebuilds all three).
+        Pad slots hold INT32_MAX docids (monotone; masked by segment
+        counts on the read side)."""
         n = len(sorted_docids)
         if n == 0:
             return self._jused
         b = self._sidetable_bucket(n)
         dbuf = np.full(b, INT32_MAX, np.int32)
         pbuf = np.zeros(b, np.int32)
-        dbuf[:n], pbuf[:n] = sorted_docids, sorted_pos
-        self._sidetable_fills = (INT32_MAX, 0)
-        (self._jdocids, self._jpos), start = self._sidetable_write(
-            [self._jdocids, self._jpos], [dbuf, pbuf], self._jused,
-            "_jcap")
+        ybuf = np.zeros((b, 2), np.int32)
+        dbuf[:n], pbuf[:n], ybuf[:n] = sorted_docids, sorted_pos, sorted_pay
+        self._sidetable_fills = (INT32_MAX, 0, 0)
+        (self._jdocids, self._jpos, self._jpay), start = \
+            self._sidetable_write(
+                [self._jdocids, self._jpos, self._jpay], [dbuf, pbuf, ybuf],
+                self._jused, "_jcap")
         self._jused += n
         return start
 
     def join_arrays(self):
-        return self._jdocids, self._jpos
+        """(jdocids, jpos, jpay): the join side table."""
+        return self._jdocids, self._jpos, self._jpay
 
     # bitmap budget: slots are (nwords, 2) int32 rows; cap total bytes so
     # a long-tailed index cannot swallow HBM in bitmaps
@@ -2159,9 +2244,12 @@ class _QueryBatcher:
         re-emitted here as child spans."""
         sp = tracing.timed("devstore.batch", kind=item.get("kind", "term"))
         with sp:
-            if "membership" in item:        # a conjunction: which join,
-                sp.set(membership=item["membership"],   # how many partners
-                       partners=item["partners"])
+            if "membership" in item:
+                # a conjunction: which join, how many partners, and how
+                # it read them
+                sp.set(membership=item["membership"],
+                       partners=item["partners"],
+                       partner_read=item["partner_read"])
             # one (epoch, perf_counter) pair places the batcher's
             # perf_counter stamps on the waterfall's clock
             epoch0 = time.time() - time.perf_counter()
@@ -2175,8 +2263,10 @@ class _QueryBatcher:
                 # the span record
                 shape = {"kernel": item.get("kernel_name", "?"),
                          "batch_n": item.get("batch_n", 0)}
-                # a conjunction's walls say how many partners it joined
-                joined = ({"partners": item["partners"]}
+                # a conjunction's walls say how many partners it joined,
+                # and how it read them
+                joined = ({"partners": item["partners"],
+                           "partner_read": item["partner_read"]}
                           if "partners" in item else {})
                 t_issue = item.get("issue_t0", 0.0)
                 t_fetch = item.get("fetch_t0", 0.0)
@@ -2366,6 +2456,7 @@ class _QueryBatcher:
                             else self.MAX_JOIN_BATCH),
                 "membership": "bitmap" if all_bm else "sortmerge",
                 "partners": n_inc,
+                "partner_read": _join_partner_read(n_inc),
                 "profile": profile, "lang": language,
                 "ev": threading.Event(), "res": ("ineligible",),
                 "lk": threading.Lock(), "taken": False}
@@ -3390,7 +3481,8 @@ class _QueryBatcher:
                                {"m": (sum(windows)
                                       // max(len(windows), 1))}),
                             n_inc=n_inc, n_exc=n_exc, bs=len(chunk),
-                            k=kk, bucket=bucket)
+                            k=kk, bucket=bucket,
+                            payload=_join_partner_read(n_inc) == "payload")
                         # a query's survivors count before its ev.set(),
                         # and not at all where its waiter abandoned it:
                         # that query is served, and counted, solo (the
@@ -3559,6 +3651,9 @@ class DeviceSegmentStore:
         #   membership (a partner without a join bitmap)
         self.join_partners = 0    # include partners, summed over served joins
         self.join_multi_served = 0   # of join_served: >= 2 include partners
+        # of join_served: answered by a program that read its partners'
+        # payload rows (_join_partner_read)
+        self.join_onegather_served = 0
         # joins of two memberships or more run the later ones over the
         # first one's survivors (_join_topk): how many, the lanes live
         # after the first membership and the rare rows they considered
@@ -3891,6 +3986,7 @@ class DeviceSegmentStore:
             pmax_parts: list[np.ndarray] = []
             join_dd_parts: list[np.ndarray] = []
             join_pos_parts: list[np.ndarray] = []
+            join_pay_parts: list[np.ndarray] = []
             bm_segs: list[np.ndarray] = []     # big terms' sorted docids
             bm_at: list[int] = []              # their index into meta
             pending: list[tuple[np.ndarray, np.ndarray]] = []
@@ -3911,6 +4007,11 @@ class DeviceSegmentStore:
                 join_dd_parts.append(sorted_dd)
                 join_pos_parts.append(
                     (base + off + jorder).astype(np.int32))
+                # what those arena rows hold: row jorder[j] of the block
+                # is row order[jorder[j]] of the list
+                at = order[jorder]
+                join_pay_parts.append(_pack_join_payload(
+                    f16[at, P.F_POSINTEXT], f16[at, P.F_HITCOUNT], fl[at]))
                 if n >= self.JOIN_BITMAP_MIN:
                     bm_segs.append(sorted_dd)
                     bm_at.append(len(meta))
@@ -3930,7 +4031,9 @@ class DeviceSegmentStore:
                 np.concatenate(join_dd_parts) if join_dd_parts
                 else np.empty(0, np.int32),
                 np.concatenate(join_pos_parts) if join_pos_parts
-                else np.empty(0, np.int32))
+                else np.empty(0, np.int32),
+                np.concatenate(join_pay_parts) if join_pay_parts
+                else np.empty((0, 2), np.int32))
             slots = dict(zip(bm_at,
                              self.arena.append_join_bitmaps(bm_segs)
                              if bm_segs else []))
@@ -4872,6 +4975,7 @@ class DeviceSegmentStore:
             "join_sm_served": self.join_sm_served,
             "join_partners": self.join_partners,
             "join_multi_served": self.join_multi_served,
+            "join_onegather_served": self.join_onegather_served,
             "join_compacted": self.join_compacted,
             "join_survivors": self.join_survivors,
             "join_compact_rows": self.join_compact_rows,
@@ -5181,7 +5285,7 @@ class DeviceSegmentStore:
                 if spans:
                     exc_spans.append(spans[0])
             feats16, flags, docids = self.arena.arrays()
-            jdocids, jpos = self.arena.join_arrays()
+            jdocids, jpos, jpay = self.arena.join_arrays()
             bmtab = self.arena.bitmap_array()
             dead = self.arena.dead_array()
         # RAM deltas are not joinable on device (unsorted, host-side);
@@ -5264,6 +5368,9 @@ class DeviceSegmentStore:
         all_bm = all(inc_bm + exc_bm)
         statics = (kk, len(partners), len(exc_spans), r, inc_ms, exc_ms,
                    inc_bm, exc_bm)
+        # a program of two partners or more reads their payload rows
+        jside = (jpay if _join_partner_read(len(partners)) == "payload"
+                 else jpos)
         s = d = None
         # batched dispatch: concurrent conjunctions sharing this compile
         # shape and arena snapshot ride one device round trip
@@ -5274,13 +5381,13 @@ class DeviceSegmentStore:
             # otherwise land a compile mid-traffic and convoy the
             # watchdog (the r4 config-8 collapse).
             self._prewarm_join_shapes(
-                (feats16, flags, docids), (jdocids, jpos, bmtab), dead,
+                (feats16, flags, docids), (jdocids, jside, bmtab), dead,
                 statics, profile, language, len(qargs))
         if (self._batcher is not None and threading.current_thread()
                 not in self._batcher._threads):
             res = self._batcher.submit_join(
                 (feats16, flags, docids),
-                (jdocids, jpos) + ((bmtab,) if any_bm else ()),
+                (jdocids, jside) + ((bmtab,) if any_bm else ()),
                 dead, qargs, statics, profile, language)
             if res[0] == "ok":
                 s, d = res[1], res[2]
@@ -5294,14 +5401,14 @@ class DeviceSegmentStore:
             t0j = time.perf_counter()
             if any_bm:
                 out = _rank_join_bm_batch_packed_kernel(
-                    feats16, flags, docids, dead, jdocids, jpos, bmtab,
+                    feats16, flags, docids, dead, jdocids, jside, bmtab,
                     qargs[None, :],
                     *consts, k=kk, n_inc=len(partners),
                     n_exc=len(exc_spans), r=r, inc_ms=inc_ms,
                     exc_ms=exc_ms, inc_bm=inc_bm, exc_bm=exc_bm)
             else:
                 out = _rank_join_batch_packed_kernel(
-                    feats16, flags, docids, dead, jdocids, jpos,
+                    feats16, flags, docids, dead, jdocids, jside,
                     qargs[None, :],
                     *consts, k=kk, n_inc=len(partners),
                     n_exc=len(exc_spans), r=r, inc_ms=inc_ms,
@@ -5312,7 +5419,8 @@ class DeviceSegmentStore:
             _emit_rt_spans((t1j - t0j) * 1e3,
                            (time.perf_counter() - t1j) * 1e3,
                            kernel="join_multi" if len(partners) >= 2
-                           else None)
+                           else None, partners=len(partners),
+                           partner_read=_join_partner_read(len(partners)))
             bucket = _join_bucket(r, len(partners), len(exc_spans))
             s, d, survivors = _split_join_out(host, bucket)
             if bucket:
@@ -5324,6 +5432,8 @@ class DeviceSegmentStore:
             self.join_partners += len(partners)
             if len(partners) >= 2:
                 self.join_multi_served += 1
+            if _join_partner_read(len(partners)) == "payload":
+                self.join_onegather_served += 1
             if not all_bm:
                 self.join_sm_served += 1
             self.arena.join_shapes.add(statics)
@@ -5395,20 +5505,20 @@ class DeviceSegmentStore:
         t0 = time.perf_counter()
         any_bm = any(inc_bm) or any(exc_bm)
         consts = self._profile_consts(profile, language)
-        jdocids, jpos = join[0], join[1]
+        jdocids, jside = join[0], join[1]
         for bs in sorted(caps):
             qb = np.zeros((bs, qlen), np.int32)
 
             def one_bucket(qb=qb):
                 if any_bm:
                     return _rank_join_bm_batch_packed_kernel(
-                        *arrays, dead, jdocids, jpos, join[2],
+                        *arrays, dead, jdocids, jside, join[2],
                         qb, *consts, k=kk, n_inc=n_inc,
                         n_exc=n_exc, r=r,
                         inc_ms=inc_ms, exc_ms=exc_ms,
                         inc_bm=inc_bm, exc_bm=exc_bm)
                 return _rank_join_batch_packed_kernel(
-                    *arrays, dead, jdocids, jpos, qb,
+                    *arrays, dead, jdocids, jside, qb,
                     *consts, k=kk, n_inc=n_inc, n_exc=n_exc,
                     r=r, inc_ms=inc_ms, exc_ms=exc_ms)
 

@@ -211,6 +211,17 @@ _JOIN_COMPACT_FLOPS_ROW = 47.0
 _JOINBM_LATER_FLOPS_LANE = 70.0
 _JOIN_LATER_FLOPS_LANE, _JOIN_LATER_FLOPS_M = 30.0, 45.0
 _JOIN_COMPACT_BYTES_ROW, _JOIN_COMPACT_BYTES_LANE = 16, 28
+# a join that reads its partners' payload rows (index/devstore.
+# _join_partner_read: two include partners or more) gathers one 8 B row
+# a lane where the rows read gathers jpos and three columns (12 B), and
+# copies no arena column: a bitmap membership after the first costs 20
+# flops a window lane, a later one 40 a bucket lane (fit at r 65,536 and
+# 131,072, one to four later memberships, bs 1 and 4); a sort-merge
+# membership sorts the side-table position it makes, so its segment
+# streams docids alone
+_JOINBM_PAYLOAD_FLOPS_LANE = 20.0
+_JOINBM_PAYLOAD_LATER_FLOPS_LANE = 40.0
+_JOIN_PAYLOAD_BYTES_LANE, _JOIN_ROWS_BYTES_LANE = 8, 12
 
 
 def _c_cardinal_scores16(n: int) -> Cost:
@@ -278,20 +289,25 @@ def _c_rank_pruned(b: int, tile: int = 32_768, bs: int = 1,
 
 
 def _c_rank_join(r: int, m: int = 0, n_inc: int = 1, n_exc: int = 0,
-                 bs: int = 1, k: int = 16, bucket: int = 0) -> Cost:
+                 bs: int = 1, k: int = 16, bucket: int = 0,
+                 payload: bool = False) -> Cost:
     """Sort-merge device conjunction: rare span of `r` rows, one (r+m)
     sort-merge membership per partner segment of `m` rows (`n_inc` +
     `n_exc` partner memberships, the kernel statics' counts); with a
     `bucket`, every membership after the first over (bucket+m) keys
-    (one bucket: the survivors fit it)."""
+    (one bucket: the survivors fit it); `payload`: the partners' payload
+    rows read in place of their arena rows."""
     partners = max(n_inc + n_exc, 1)
+    # per partner the gathered columns a lane, and the segment streamed
+    # for the sort a key: (docid, pos), or the docid alone
+    lane = _JOIN_PAYLOAD_BYTES_LANE if payload else _JOIN_ROWS_BYTES_LANE
+    seg = 4 if payload else 8
     if not bucket:
         flops = bs * r * (_JOIN_FLOPS_R + 146.0 * (partners - 1)) \
             + bs * _JOIN_FLOPS_M * m * partners
-        # compulsory: rare rows once; per partner 12 B of gathered
-        # columns per lane + the (docid, pos) segment streamed for the
-        # sort
-        comp = bs * (ROW_BYTES_DEAD * r + partners * (12 * r + 8 * m)
+        # compulsory: rare rows once; per partner its lanes' columns and
+        # its segment
+        comp = bs * (ROW_BYTES_DEAD * r + partners * (lane * r + seg * m)
                      + 8 * k)
         return Cost(flops=flops, bytes=comp)
     later = partners - 1
@@ -299,27 +315,36 @@ def _c_rank_join(r: int, m: int = 0, n_inc: int = 1, n_exc: int = 0,
              + _JOIN_FLOPS_M * m
              + later * (_JOIN_LATER_FLOPS_LANE * bucket
                         + _JOIN_LATER_FLOPS_M * m))
-    comp = ((ROW_BYTES_DEAD + 12 + _JOIN_COMPACT_BYTES_ROW) * r
-            + (12 * later + _JOIN_COMPACT_BYTES_LANE) * bucket
-            + partners * 8 * m + 8 * k)
+    comp = ((ROW_BYTES_DEAD + lane + _JOIN_COMPACT_BYTES_ROW) * r
+            + (lane * later + _JOIN_COMPACT_BYTES_LANE) * bucket
+            + partners * seg * m + 8 * k)
     return Cost(flops=bs * flops, bytes=bs * comp)
 
 
 def _c_rank_join_bm(r: int, n_inc: int = 1, n_exc: int = 0, bs: int = 1,
-                    k: int = 16, bucket: int = 0) -> Cost:
+                    k: int = 16, bucket: int = 0,
+                    payload: bool = False) -> Cost:
     """Bitmap-membership conjunction: 2 gathers per lane per partner
     instead of the (r+m) sort — O(r) regardless of partner size; with a
-    `bucket`, every membership after the first over the bucket."""
+    `bucket`, every membership after the first over the bucket. A lane
+    of a partner reads its bitmap row (8 B), then its arena row from
+    jpos and three columns of it (12 B), or with `payload` its payload
+    row (8 B)."""
     partners = max(n_inc + n_exc, 1)
+    per = 8 + (_JOIN_PAYLOAD_BYTES_LANE if payload
+               else _JOIN_ROWS_BYTES_LANE)
     if not bucket:
-        flops = bs * r * (_JOINBM_FLOPS_ROW + 160.0 * (partners - 1))
-        comp = bs * (ROW_BYTES_DEAD * r + partners * 20 * r + 8 * k)
+        more = _JOINBM_PAYLOAD_FLOPS_LANE if payload else 160.0
+        flops = bs * r * (_JOINBM_FLOPS_ROW + more * (partners - 1))
+        comp = bs * (ROW_BYTES_DEAD * r + partners * per * r + 8 * k)
         return Cost(flops=flops, bytes=comp)
     later = partners - 1
+    per_lane = (_JOINBM_PAYLOAD_LATER_FLOPS_LANE if payload
+                else _JOINBM_LATER_FLOPS_LANE)
     flops = ((_JOINBM_FLOPS_ROW + _JOIN_COMPACT_FLOPS_ROW) * r
-             + later * _JOINBM_LATER_FLOPS_LANE * bucket)
-    comp = ((ROW_BYTES_DEAD + 20 + _JOIN_COMPACT_BYTES_ROW) * r
-            + (20 * later + _JOIN_COMPACT_BYTES_LANE) * bucket + 8 * k)
+             + later * per_lane * bucket)
+    comp = ((ROW_BYTES_DEAD + per + _JOIN_COMPACT_BYTES_ROW) * r
+            + (per * later + _JOIN_COMPACT_BYTES_LANE) * bucket + 8 * k)
     return Cost(flops=bs * flops, bytes=bs * comp)
 
 

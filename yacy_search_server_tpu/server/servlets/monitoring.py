@@ -482,7 +482,8 @@ def prometheus_text(sb, include_buckets: bool = True,
              "device store serving counters")
     for key in ("queries_served", "fallbacks", "stream_scans",
                 "filtered_served", "join_served", "join_sm_served",
-                "join_partners", "join_multi_served", "join_compacted",
+                "join_partners", "join_multi_served",
+                "join_onegather_served", "join_compacted",
                 "join_survivors", "join_compact_rows",
                 "join_compact_overflow", "join_fallbacks",
                 # rows of frozen runs packed into the arena, and of runs

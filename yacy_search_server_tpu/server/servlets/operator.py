@@ -629,6 +629,7 @@ def device_store(header, post, sb):
             ("join_sm_served", c["join_sm_served"]),
             ("join_partners", c["join_partners"]),
             ("join_multi_served", c["join_multi_served"]),
+            ("join_onegather_served", c["join_onegather_served"]),
             ("join_compacted", c["join_compacted"]),
             ("join_survivors", c["join_survivors"]),
             ("join_compact_rows", c["join_compact_rows"]),
